@@ -427,14 +427,14 @@ def run_pipeline_bench(*, M: int = 8192, nnz_per_row: int = 8,
     entry["oracle_ok"] = bool(np.allclose(execute(prog, x), ref,
                                           atol=1e-4, rtol=1e-5))
 
-    try:
-        import jax
-        from repro.launch.mesh import auto_axis_types
-        n_dev = jax.device_count()
-    except Exception:
-        n_dev = 0
+    import jax
+    from jax.sharding import AxisType
+    n_dev = jax.device_count()
+    entry["device_count"] = n_dev
     if n_dev >= shards:
-        mesh = jax.make_mesh((shards,), ("model",), **auto_axis_types(1))
+        mesh = jax.make_mesh((shards,), ("model",),
+                             axis_types=(AxisType.Auto,),
+                             devices=jax.devices()[:shards])
         y_pipe = execute(prog, x, backend="shard_map", mesh=mesh)
         y_ser = execute(prog, x, backend="shard_map", mesh=mesh,
                         pipeline=False)
@@ -442,17 +442,16 @@ def run_pipeline_bench(*, M: int = 8192, nnz_per_row: int = 8,
             np.array_equal(np.asarray(y_pipe), np.asarray(y_ser)))
         entry["device_oracle_ok"] = bool(
             np.allclose(np.asarray(y_pipe), ref, atol=2e-4, rtol=1e-4))
-        from repro.core.program import make_program_spmv_fn
-        xs = prog.x_to_device(np.asarray(x, dtype=np.float32))
+        from repro.core.program import make_program_spmv_fn, scatter_x
+        xs = scatter_x(prog, x)
         for key, flag in (("pipelined", True), ("serial", False)):
             fn = make_program_spmv_fn(prog, mesh, pipeline=flag)
-            with mesh:
-                jax.block_until_ready(fn(xs))   # compile outside the clock
-                fn_t = []
-                for _ in range(5):
-                    t0 = time.perf_counter()
-                    jax.block_until_ready(fn(xs))
-                    fn_t.append(time.perf_counter() - t0)
+            jax.block_until_ready(fn(xs))       # compile outside the clock
+            fn_t = []
+            for _ in range(5):
+                t0 = time.perf_counter()
+                jax.block_until_ready(fn(xs))
+                fn_t.append(time.perf_counter() - t0)
             entry.setdefault("device_host_us_per_spmv", {})[key] = \
                 round(float(np.median(fn_t)) * 1e6, 1)
     return entry
@@ -463,14 +462,15 @@ def check_pipeline(entry: dict, *, fast: bool = False) -> bool:
     pipelined device-path latency beats the best-achievable serial one by
     >= 1.15x on the recorded full run (a strict win suffices at CI-smoke
     scale), the pipelined plan's program reproduces the oracle, and —
-    when enough devices were visible to run the real shard_map path —
-    the two schedules are bitwise-equal."""
+    whenever enough devices were visible for the real shard_map phase to
+    run — its two schedules are bitwise-equal and match the oracle."""
     bar = 1.0 if fast else 1.15
     sp = entry.get("model_device_cycles", {}).get("speedup", 0.0)
+    device_ran = entry.get("device_count", 0) >= entry["shards"]
     return ((sp > bar if fast else sp >= bar) and
             entry.get("oracle_ok", False) and
-            entry.get("device_bitwise_ok", True) and
-            entry.get("device_oracle_ok", True))
+            (not device_ran or (entry.get("device_bitwise_ok", False) and
+                                entry.get("device_oracle_ok", False))))
 
 
 def _probe_arg(s: str):
@@ -511,6 +511,8 @@ def main() -> int:
     ap.add_argument("--json", action="store_true",
                     help="print the entry as JSON only")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     t0 = time.perf_counter()
     if args.workload == "pipeline":

@@ -1,8 +1,10 @@
 """Kernel micro-bench: Pallas-oracle parity cost on CPU (interpret mode is
 a correctness vehicle; real perf numbers come from the TPU dry-run).
 Reports us/call of the jnp oracle paths that the models actually execute."""
+import jax
 import jax.numpy as jnp
 import numpy as np
+from repro.core.program import kernel_interpret
 from repro.core.sparse_matrix import csr_from_coo, csr_to_ell
 from repro.data.matrices import blocked_band, powerlaw, powerlaw_tail
 from repro.kernels import ops
@@ -10,6 +12,8 @@ from .common import emit, us
 
 
 def run():
+    # Pallas kernels run interpreted on the CPU and compiled on the chip.
+    interp = kernel_interpret(jax.default_backend())
     rng = np.random.default_rng(0)
     rows = []
     for M, N, nnz in ((512, 512, 8000), (2048, 2048, 40000)):
@@ -65,9 +69,9 @@ def run():
                          f"pad={spl.padding_ratio:.2f}"))
         spl = ops.split_from_csr(Q, 8)
         t = us(lambda: ops.split_spmv(spl, xq, use_kernel=True,
-                                      interpret=True).block_until_ready())
+                                      interpret=interp).block_until_ready())
         rows.append((f"split_pallas/{name}/nnz{Q.nnz}/ns{spl.num_splits}",
-                     round(t, 1), "interpret=True"))
+                     round(t, 1), f"interpret={interp}"))
     # Bitmask-tiled family: its win case is block-structured data (dense
     # (8, 128) tiles, fill -> 1.0); the scattered powerlaw row above it
     # shows the loss case (fill -> 0, every tile mostly padding).  Oracle
@@ -81,11 +85,13 @@ def run():
                      f"tiles={tm.num_tiles};fill={tm.fill_ratio:.2f}"))
     tm = ops.tile_from_csr(B)
     t = us(lambda: ops.tile_spmv(tm, xb, use_kernel=True,
-                                 interpret=True).block_until_ready())
+                                 interpret=interp).block_until_ready())
     rows.append((f"tile_pallas/blocked2048/nnz{B.nnz}", round(t, 1),
-                 "interpret=True"))
+                 f"interpret={interp}"))
     emit(rows, ("name", "us_per_call", "derived"))
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()

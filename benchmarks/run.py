@@ -12,6 +12,9 @@ import traceback
 def main() -> None:
     import json
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from . import (autotune_bench, bottleneck_bench, fig3_layout,
                    fig6_distribution, fig7_cv, fig8_residency, fig10_reorder,
                    fig12_cache, hetero_bench, kernels_bench)

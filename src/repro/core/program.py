@@ -61,8 +61,8 @@ from .spmv import PLAN_KERNELS, SpmvPlan
 from repro.kernels import ops as kops
 
 __all__ = ["ShardStage", "SpmvProgram", "lower", "relower", "execute",
-           "make_program_spmv_fn", "probe_program", "gather_b",
-           "PROGRAM_KERNELS"]
+           "make_program_spmv_fn", "build_program_step", "kernel_interpret",
+           "probe_program", "scatter_x", "gather_b", "PROGRAM_KERNELS"]
 
 #: Kernels a shard stage may select — alias of the single definition in
 #: ``spmv.PLAN_KERNELS`` (tie-break preference order; the ``lax.switch``
@@ -174,6 +174,8 @@ class SpmvProgram:
         return tuple(st.kernel for st in self.stages)
 
     def x_to_device(self, x: np.ndarray) -> np.ndarray:
+        """x in the program's (reordered) index order -> layout shards;
+        :func:`scatter_x` takes x in the caller's order."""
         return self.x_layout.to_sharded(x)
 
     def b_from_device(self, b_shards: np.ndarray) -> np.ndarray:
@@ -790,44 +792,52 @@ _OPERAND_KEYS = (("kid",)
                  + ("send_idx", "row_remote"))
 
 
-def make_program_spmv_fn(program: SpmvProgram, mesh, axis: str = "model", *,
-                         use_kernel: bool = False, interpret: bool = True,
-                         pipeline: bool = True):
-    """THE device executor: one shard_map function for any lowered program.
+def kernel_interpret(platform: str, interpret: bool | None = None) -> bool:
+    """Pallas interpret mode for the devices of ``platform``.
 
-    Returns ``f(x_shards) -> y_shards`` with ``x_shards`` of shape
-    (S, per_shard) or batched (S, per_shard, B) in layout order, and
-    ``y_shards`` of shape (S, rows_pad[, B]) (slice each shard to its true
-    ``rows_per_shard``, or use :func:`gather_b`).  The exchange prologue
-    follows ``plan.resolved_shard_exchanges()``: uniform all-gather when
-    every shard picks ``allgather``, otherwise one all-to-all whose
-    per-reader payload is the exact halo (``halo`` shards) or the full
-    replication (``allgather`` shards).  Each shard dispatches to its
-    stage's kernel (``ell`` / ``seg`` / ``hyb`` / ``split`` / ``tile``)
-    through a ``lax.switch`` — one SPMD program, heterogeneous per-shard
-    execution.
+    Interpret mode is the CPU stand-in for the chip and nothing else: it
+    is on exactly when the platform is ``"cpu"``.  ``None`` derives it;
+    an explicit value that disagrees raises, so that a kernel meant for
+    the chip never falls back to the interpreter in silence."""
+    want = platform == "cpu"
+    if interpret is None or interpret == want:
+        return want
+    raise ValueError(
+        f"interpret={interpret} on platform {platform!r}: Pallas kernels run "
+        f"interpreted only on cpu and compiled everywhere else")
 
-    The schedule is **pipelined** (the ROADMAP item-4 executor): each
-    shard's kernel work is pre-split by row into a local slice whose
-    pass reads only ``x_local`` — issuable while the collective is in
-    flight — and a remote slice whose pass waits for the exchange
-    buffer; ``row_remote`` selects per row which pass owns the result.
-    ``pipeline=False`` runs the *same* two passes behind an
-    ``optimization_barrier`` that ties the local pass's input to the
-    completed exchange — the pre-pipeline serial order, bitwise-equal
-    output by construction (identical operands and combine, scheduling
-    freedom removed).
 
-    ``use_kernel=True`` runs the Pallas kernels (``interpret=True`` on
-    CPU); the default runs the pure-jnp oracles, same as the old
-    ``make_*_spmv_fn`` triplet this function replaces.
+def _mesh_platform(mesh) -> str:
+    return mesh.devices.flat[0].platform
+
+
+def build_program_step(program: SpmvProgram, mesh, axis: str = "model", *,
+                       use_kernel: bool = False,
+                       interpret: bool | None = None,
+                       pipeline: bool = True):
+    """The device executor's jitted step and its host operands.
+
+    Returns ``(step, operands)``: ``step(*operands, x_shards)`` is the one
+    jitted ``shard_map`` program, and ``operands`` are the host arrays in
+    ``_OPERAND_KEYS`` order, each with the shard axis first.  Nothing is
+    placed on a device here, so the step can be lowered from shapes alone
+    (``step.lower(*shape_structs)``) for a mesh of described devices.
+    :func:`make_program_spmv_fn` is the serving wrapper around it.
     """
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from .spmv import _shard_map_norep
-
+    S = program.plan.num_shards
+    if axis not in mesh.shape:
+        raise ValueError(f"mesh has no axis {axis!r} (axes "
+                         f"{tuple(mesh.shape)})")
+    if mesh.shape[axis] != S:
+        raise ValueError(
+            f"the program has {S} shards but the mesh has "
+            f"{mesh.shape[axis]} devices along {axis!r}: lower the plan "
+            f"with num_shards={mesh.shape[axis]}")
+    interpret = kernel_interpret(_mesh_platform(mesh), interpret)
     ops = _device_operands(program)
     R = ops["R"]
     NS_loc, NS_rem = ops["NS_loc"], ops["NS_rem"]
@@ -835,8 +845,7 @@ def make_program_spmv_fn(program: SpmvProgram, mesh, axis: str = "model", *,
     use_a2a = any(e == "halo" for e in policies)
     kind = program.x_layout.kind
     if use_kernel:
-        ell_op = partial(kops.ell_spmv, interpret=interpret,
-                         tile_m=ELL_SUBLANE, tile_w=ELL_LANE)
+        ell_op = partial(kops.ell_spmv, interpret=interpret)
     else:
         ell_op = kops.ell_spmv_ref
 
@@ -846,9 +855,20 @@ def make_program_spmv_fn(program: SpmvProgram, mesh, axis: str = "model", *,
             return x_all.reshape((-1,) + x_all.shape[2:])
         return jnp.swapaxes(x_all, 0, 1).reshape((-1,) + x_all.shape[2:])
 
-    def kernel_pass(kid, ed, ec, orow, ocol, oval, sv, sc, sr, sp,
+    def kernel_pass(*args):
+        """One slice's kernel dispatch against its own x buffer.
+
+        A multi-RHS buffer (n, B) runs one vector at a time in a device
+        loop: a batched gather would put B on the lanes, which the chip
+        pads to 128, so an (R, W, B) temporary would take 128 / B times
+        its size."""
+        *slabs, xv = args
+        if xv.ndim == 2:
+            return jax.lax.map(partial(vector_pass, *slabs), xv.T).T
+        return vector_pass(*slabs, xv)
+
+    def vector_pass(kid, ed, ec, orow, ocol, oval, sv, sc, sr, sp,
                     td, txc, tbr, ns, xv):
-        """One slice's kernel dispatch against its own x buffer."""
 
         def ell_branch(_):
             return ell_op(ed[0], ec[0], xv)
@@ -862,9 +882,7 @@ def make_program_spmv_fn(program: SpmvProgram, mesh, axis: str = "model", *,
 
         def hyb_branch(_):
             y = ell_op(ed[0], ec[0], xv)
-            xs = jnp.take(xv, ocol[0], axis=0)             # (O[, B])
-            v = oval[0][:, None] if xs.ndim == 2 else oval[0]
-            return y.at[orow[0]].add(v * xs)
+            return y.at[orow[0]].add(oval[0] * jnp.take(xv, ocol[0]))
 
         def split_branch(_):
             return kops.split_flat_spmv(
@@ -915,18 +933,79 @@ def make_program_spmv_fn(program: SpmvProgram, mesh, axis: str = "model", *,
         return y[None]
 
     n_ops = len(_OPERAND_KEYS)
-    fn = _shard_map_norep(
-        shard_fn, mesh=mesh,
-        in_specs=(P(axis),) * (n_ops + 1),
-        out_specs=P(axis))
-    jfn = jax.jit(fn)
-    operands = tuple(jnp.asarray(ops[k]) for k in _OPERAND_KEYS)
+    # check_vma off: pallas_call has no replication rule.
+    fn = jax.shard_map(shard_fn, mesh=mesh,
+                       in_specs=(P(axis),) * (n_ops + 1),
+                       out_specs=P(axis), check_vma=False)
+    return jax.jit(fn), tuple(ops[k] for k in _OPERAND_KEYS)
+
+
+def make_program_spmv_fn(program: SpmvProgram, mesh, axis: str = "model", *,
+                         use_kernel: bool = False,
+                         interpret: bool | None = None,
+                         pipeline: bool = True):
+    """THE device executor: one shard_map function for any lowered program.
+
+    Returns ``f(x_shards) -> y_shards`` with ``x_shards`` of shape
+    (S, per_shard) or batched (S, per_shard, B) in layout order (see
+    :meth:`SpmvProgram.x_to_device`), and ``y_shards`` of shape
+    (S, rows_pad[, B]) (slice each shard to its true ``rows_per_shard``,
+    or use :func:`gather_b`).  The mesh must have ``plan.num_shards``
+    devices along ``axis``; the program's operands are placed on them
+    once, split along the shard axis, here.  ``f.program`` is the program
+    it serves, ``f.operands`` its placed device operands and
+    ``f.operand_bytes`` their bytes.
+
+    The exchange prologue
+    follows ``plan.resolved_shard_exchanges()``: uniform all-gather when
+    every shard picks ``allgather``, otherwise one all-to-all whose
+    per-reader payload is the exact halo (``halo`` shards) or the full
+    replication (``allgather`` shards).  Each shard dispatches to its
+    stage's kernel (``ell`` / ``seg`` / ``hyb`` / ``split`` / ``tile``)
+    through a ``lax.switch`` — one SPMD program, heterogeneous per-shard
+    execution.
+
+    The schedule is **pipelined** (the ROADMAP item-4 executor): each
+    shard's kernel work is pre-split by row into a local slice whose
+    pass reads only ``x_local`` — issuable while the collective is in
+    flight — and a remote slice whose pass waits for the exchange
+    buffer; ``row_remote`` selects per row which pass owns the result.
+    ``pipeline=False`` runs the *same* two passes behind an
+    ``optimization_barrier`` that ties the local pass's input to the
+    completed exchange — the pre-pipeline serial order, bitwise-equal
+    output by construction (identical operands and combine, scheduling
+    freedom removed).
+
+    ``use_kernel=True`` runs the Pallas kernels, compiled on the chip and
+    interpreted on the CPU (:func:`kernel_interpret`); the default runs
+    the pure-jnp oracles.
+    """
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    step, host_ops = build_program_step(
+        program, mesh, axis, use_kernel=use_kernel, interpret=interpret,
+        pipeline=pipeline)
+    sharding = NamedSharding(mesh, P(axis))
+    operands = jax.device_put(host_ops, sharding)
 
     def run(x_shards):
-        return jfn(*operands, jnp.asarray(x_shards))
+        return step(*operands, jax.device_put(x_shards, sharding))
 
-    run.rows_out = R
+    run.program = program
+    run.operands = operands
+    run.operand_bytes = int(sum(a.nbytes for a in host_ops))
     return run
+
+
+def scatter_x(program: SpmvProgram, x: np.ndarray) -> np.ndarray:
+    """Caller-order x ((N,) or (N, B)) -> (S, per[, B]) float32 shards in
+    layout order: the device executor's input, and the inverse of
+    :func:`gather_b` (which undoes the reordering on the way out)."""
+    x = np.asarray(x, dtype=np.float32)
+    if program.perm is not None:
+        x = _apply_perm(x, program.perm)
+    return program.x_to_device(x)
 
 
 def gather_b(program: SpmvProgram, y_shards) -> np.ndarray:
@@ -955,7 +1034,7 @@ def probe_program(program: SpmvProgram, *, emu: EmuConfig | None = None,
 
 def execute(program: SpmvProgram, x: np.ndarray | None = None, *,
             backend: str = "numpy", mesh=None, axis: str = "model",
-            use_kernel: bool = False, interpret: bool = True,
+            use_kernel: bool = False, interpret: bool | None = None,
             pipeline: bool = True,
             emu: EmuConfig | None = None, engine: str = "vectorized"):
     """Execute a lowered program — the single entry point for every backend.
@@ -985,9 +1064,6 @@ def execute(program: SpmvProgram, x: np.ndarray | None = None, *,
         fn = make_program_spmv_fn(program, mesh, axis=axis,
                                   use_kernel=use_kernel, interpret=interpret,
                                   pipeline=pipeline)
-        xs = program.x_to_device(np.asarray(x, dtype=np.float32))
-        with mesh:
-            y = fn(xs)
-        return gather_b(program, y)
+        return gather_b(program, fn(scatter_x(program, x)))
     raise ValueError(f"unknown executor backend {backend!r}; expected "
                      f"'numpy', 'shard_map', or 'emu'")

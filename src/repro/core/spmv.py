@@ -32,20 +32,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
-try:                                   # jax >= 0.5 exports it at top level
-    from jax import shard_map as _shard_map
-except ImportError:                    # 0.4.x: experimental home
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def _shard_map_norep(fn, **kw):
-    """shard_map with replication checking off (pallas_call has no rep rule);
-    the flag is ``check_rep`` on 0.4.x and ``check_vma`` on newer jax."""
-    try:
-        return _shard_map(fn, check_rep=False, **kw)
-    except TypeError:
-        return _shard_map(fn, check_vma=False, **kw)
-
 from .sparse_matrix import CSRMatrix
 
 __all__ = ["SpmvPlan", "DistributedSpmv", "build_distributed",
@@ -249,7 +235,7 @@ def local_spmv(dist, x: np.ndarray) -> np.ndarray:
 
 
 def make_spmv_fn(dist, mesh: Mesh, axis: str = "model",
-                 *, use_kernel: bool = False, interpret: bool = True):
+                 *, use_kernel: bool = False, interpret: bool | None = None):
     """Deprecated shim over :func:`repro.core.program.make_program_spmv_fn`.
 
     Returns the old ``f(data, cols, x_shards) -> b_shards`` signature; the
@@ -278,7 +264,8 @@ def make_spmv_fn(dist, mesh: Mesh, axis: str = "model",
 
 
 def make_seg_spmv_fn(dist, mesh: Mesh, axis: str = "model",
-                     *, use_kernel: bool = False, interpret: bool = True):
+                     *, use_kernel: bool = False,
+                     interpret: bool | None = None):
     """Deprecated shim over :func:`repro.core.program.make_program_spmv_fn`
     for uniform-seg programs (old ``f(vals, cols, rows, pieces, x_shards)``
     signature)."""
@@ -384,7 +371,7 @@ def build_halo(dist) -> HaloProgram:
 
 def make_halo_spmv_fn(dist, halo: HaloProgram, mesh: Mesh,
                       axis: str = "model", *, use_kernel: bool = False,
-                      interpret: bool = True):
+                      interpret: bool | None = None):
     """Deprecated shim over :func:`repro.core.program.make_program_spmv_fn`
     (old ``f(data, cols_remap, send_idx, x_shards)`` signature).
 

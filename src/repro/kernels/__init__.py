@@ -27,8 +27,9 @@ Five kernel families, one per sparse format/work-distribution choice:
   survive as warn-once deprecated shims.
 
 Every kernel has the same contract: pure-jnp oracle as the default
-execution path, ``use_kernel=True`` for the Pallas path (TPU), and
-``interpret=True`` to run the Pallas path on CPU.  The public API is
+execution path, ``use_kernel=True`` for the Pallas path (compiled on the
+TPU), and ``interpret=True`` to run the Pallas path on CPU; the device
+executor derives ``interpret`` from its mesh's platform.  The public API is
 re-exported here (from ``ops.py``), so callers write
 ``from repro.kernels import ell_spmv`` without caring which file owns the
 kernel.

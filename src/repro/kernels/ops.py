@@ -1,8 +1,8 @@
-"""Jit'd public wrappers around the Pallas kernels (+ oracle fallbacks).
+"""Jit'd public wrappers around the Pallas kernels (+ jnp oracles).
 
-On TPU the Pallas path is used; on CPU (this container) the kernels run
-under ``interpret=True`` in tests and the pure-jnp oracle is the default
-execution path, so every higher layer works identically on both.
+The pure-jnp oracle is the default execution path; ``use_kernel=True``
+runs the Pallas kernel, compiled on the TPU and under ``interpret=True``
+on the CPU, so every higher layer works identically on both.
 """
 from __future__ import annotations
 

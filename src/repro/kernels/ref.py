@@ -6,7 +6,12 @@ path on backends without Pallas support.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
+
+#: f32 products at full precision: the TPU's default matmul precision
+#: rounds f32 operands to bf16, which would break the oracle contract.
+_EXACT = jax.lax.Precision.HIGHEST
 
 __all__ = ["ell_spmv_ref", "bell_spmv_ref", "coo_spmv_ref", "bell_spmm_ref",
            "seg_spmv_ref", "seg_psum_ref", "split_psum_ref",
@@ -124,7 +129,8 @@ def tile_spmv_ref(data: jnp.ndarray, tile_rows: jnp.ndarray,
     pad = [(0, Nb * bn - n)] + [(0, 0)] * (x.ndim - 1)
     xb = jnp.pad(x, pad).reshape((Nb, bn) + x.shape[1:])
     gathered = jnp.take(xb, tile_cols, axis=0)          # (T, bn[, B])
-    contrib = jnp.einsum("tij,tj...->ti...", data, gathered)
+    contrib = jnp.einsum("tij,tj...->ti...", data, gathered,
+                         precision=_EXACT)
     Mb = max(-(-num_rows // bm), 1)
     out = jnp.zeros((Mb, bm) + x.shape[1:], dtype=contrib.dtype)
     out = out.at[tile_rows].add(contrib)
@@ -144,7 +150,8 @@ def tile_flat_spmv_ref(data: jnp.ndarray, xcols: jnp.ndarray,
     """
     T, bm, bn = data.shape
     gathered = jnp.take(x, xcols, axis=0)               # (T, bn[, B])
-    contrib = jnp.einsum("tij,tj...->ti...", data, gathered)
+    contrib = jnp.einsum("tij,tj...->ti...", data, gathered,
+                         precision=_EXACT)
     Mb = max(-(-num_rows // bm), 1)
     out = jnp.zeros((Mb, bm) + x.shape[1:], dtype=contrib.dtype)
     out = out.at[trows].add(contrib, mode="drop")
@@ -162,7 +169,7 @@ def bell_spmv_ref(blocks: jnp.ndarray, bcols: jnp.ndarray, x: jnp.ndarray) -> jn
     Mb, K, bm, bn = blocks.shape
     xb = x.reshape(-1, bn)                       # (Nb, bn)
     gathered = jnp.take(xb, bcols, axis=0)       # (Mb, K, bn)
-    y = jnp.einsum("mkij,mkj->mi", blocks, gathered)
+    y = jnp.einsum("mkij,mkj->mi", blocks, gathered, precision=_EXACT)
     return y.reshape(Mb * bm)
 
 
@@ -175,5 +182,6 @@ def bell_spmm_ref(blocks: jnp.ndarray, bcols: jnp.ndarray, X: jnp.ndarray) -> jn
     B = X.shape[1]
     Xb = X.reshape(-1, bn, B)                    # (Nb, bn, B)
     gathered = jnp.take(Xb, bcols, axis=0)       # (Mb, K, bn, B)
-    Y = jnp.einsum("mkij,mkjb->mib", blocks, gathered)
+    Y = jnp.einsum("mkij,mkjb->mib", blocks, gathered,
+                   precision=_EXACT)
     return Y.reshape(Mb * bm, B)

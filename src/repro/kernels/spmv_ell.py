@@ -3,18 +3,19 @@
 TPU adaptation of the paper's CSR row loop (docs/ARCHITECTURE.md#design-2):
 a scalar
 CSR walk cannot feed the VPU, so rows are padded to a lane-aligned width W
-and the kernel processes (TM, TW) tiles of the ELL slab against an x vector
-resident in VMEM:
+and the kernel processes (TM, TW) tiles of the ELL slab:
 
-    y[i] += sum_w data[i, w] * x[cols[i, w]]
+    y[i] += sum_w data[i, w] * xg[i, w],    xg[i, w] = x[cols[i, w]]
 
-Grid is (M/TM, W/TW); the W-axis is the reduction, accumulated in the output
-tile (revisited across the w grid dimension, initialised at w == 0).  The
-gather from x is a VMEM dynamic-gather — the TPU analogue of the Emu
-migratory load: x is the *block-layout local shard*, so every gather that
-would have been a migration on Emu is a VMEM hit here, which is exactly why
-the distributed layer (core/spmv.py) reproduces the paper's block-layout
-win on TPU.
+Grid is (M/TM, W/TW); the W-axis is the reduction, accumulated in the
+(TM, 1) output tile (revisited across the w grid dimension, initialised at
+w == 0).  Mosaic has no 1-D dynamic gather, so the gather of x through the
+column ids runs in XLA before the kernel (the same split as the tile
+family's pre-gathered ``xg``); the kernel streams the two aligned slabs and
+does the multiply and the lane reduction.  x is the *block-layout local
+shard* (plus its halo), so every gather that would have been a migration on
+Emu is a local HBM read here — which is why the distributed layer
+(core/program.py) reproduces the paper's block-layout win on TPU.
 """
 from __future__ import annotations
 
@@ -24,21 +25,18 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .tiling import LANE, SUBLANE, fit_tile, pad_axis, round_up
+
 __all__ = ["ell_spmv"]
 
 
-def _ell_kernel(data_ref, cols_ref, x_ref, y_ref):
-    w = pl.program_id(1)
-
-    @pl.when(w == 0)
+def _ell_kernel(data_ref, xg_ref, y_ref):
+    @pl.when(pl.program_id(1) == 0)
     def _init():
         y_ref[...] = jnp.zeros_like(y_ref)
 
-    data = data_ref[...]                       # (TM, TW)
-    cols = cols_ref[...]                       # (TM, TW)
-    x = x_ref[...]                             # (N,) resident in VMEM
-    gathered = jnp.take(x, cols, axis=0)       # VMEM dynamic gather
-    y_ref[...] += jnp.sum(data * gathered, axis=1)
+    # (TM, TW) products, reduced over the lanes into the (TM, 1) tile
+    y_ref[...] += jnp.sum(data_ref[...] * xg_ref[...], axis=1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("tile_m", "tile_w", "interpret"))
@@ -47,25 +45,27 @@ def ell_spmv(data: jnp.ndarray, cols: jnp.ndarray, x: jnp.ndarray,
              interpret: bool = False) -> jnp.ndarray:
     """y = A @ x with A in padded-ELL form.
 
-    data/cols: (M, W) with W % 128 == 0 (lane aligned), M % 8 == 0.
-    x: (N,) — must fit VMEM alongside the tiles (the distributed layer
-    shards x so each local slab sees only its block).
+    data/cols: (M, W); padded slots hold value 0.  The slab is padded to
+    (8, 128) multiples and tiled by the largest aligned divisors not above
+    ``tile_m`` / ``tile_w``.  x: (N,), any length — it is gathered in HBM,
+    never held whole in VMEM.  Returns y: (M,) in x.dtype.
     """
     M, W = data.shape
-    tm = min(tile_m, M)
-    tw = min(tile_w, W)
-    if M % tm or W % tw:
-        raise ValueError(f"tiles must divide slab: {(M, W)} vs {(tm, tw)}")
-    grid = (M // tm, W // tw)
-    return pl.pallas_call(
+    Mp, Wp = round_up(max(M, 1), SUBLANE), round_up(max(W, 1), LANE)
+    tm = fit_tile(Mp, tile_m, SUBLANE)
+    tw = fit_tile(Wp, tile_w, LANE)
+    xg = jnp.take(x, cols, axis=0).astype(x.dtype)         # XLA gather
+    d = pad_axis(pad_axis(data.astype(x.dtype), 0, Mp), 1, Wp)
+    xg = pad_axis(pad_axis(xg, 0, Mp), 1, Wp)
+    y = pl.pallas_call(
         _ell_kernel,
-        grid=grid,
+        grid=(Mp // tm, Wp // tw),
         in_specs=[
             pl.BlockSpec((tm, tw), lambda m, w: (m, w)),       # data tile
-            pl.BlockSpec((tm, tw), lambda m, w: (m, w)),       # cols tile
-            pl.BlockSpec((x.shape[0],), lambda m, w: (0,)),    # full x in VMEM
+            pl.BlockSpec((tm, tw), lambda m, w: (m, w)),       # x[cols] tile
         ],
-        out_specs=pl.BlockSpec((tm,), lambda m, w: (m,)),
-        out_shape=jax.ShapeDtypeStruct((M,), x.dtype),
+        out_specs=pl.BlockSpec((tm, 1), lambda m, w: (m, 0)),
+        out_shape=jax.ShapeDtypeStruct((Mp, 1), x.dtype),
         interpret=interpret,
-    )(data, cols, x)
+    )(d, xg)
+    return y[:M, 0]

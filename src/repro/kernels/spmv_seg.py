@@ -18,6 +18,10 @@ sums one carry per chunk and a chunk holding many short rows yields them
 all from one scan.  The grid is therefore load-balance-aware rather than
 shape-aware — the first kernel in this repo whose work distribution, not
 its operand shape, defines the grid.
+
+Mosaic lowers neither a 1-D dynamic gather nor ``cumsum``: the gather
+``x[cols]`` runs in XLA before the kernel, and the scan is a log-step
+(Hillis-Steele) sum of lane rotations, :func:`lane_scan`.
 """
 from __future__ import annotations
 
@@ -26,16 +30,29 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["seg_psum"]
+from .tiling import LANE, SUBLANE, fit_tile, pad_axis, round_up
+
+__all__ = ["seg_psum", "lane_scan"]
 
 
-def _seg_kernel(vals_ref, cols_ref, x_ref, psum_ref):
-    vals = vals_ref[...]                       # (TC, L)
-    cols = cols_ref[...]                       # (TC, L)
-    x = x_ref[...]                             # (N,) resident in VMEM
-    prod = vals * jnp.take(x, cols, axis=0)    # VMEM dynamic gather
-    psum_ref[...] = jnp.cumsum(prod, axis=1)   # within-chunk inclusive scan
+def lane_scan(v):
+    """Inclusive prefix sum along the last (lane) axis, in-kernel.
+
+    ``log2(L)`` steps; step ``s`` adds the value ``s`` lanes to the left
+    (a lane rotation, masked where it wraps around)."""
+    L = v.shape[-1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, v.shape, v.ndim - 1)
+    s = 1
+    while s < L:
+        v = v + jnp.where(lane >= s, pltpu.roll(v, s, v.ndim - 1), 0.0)
+        s *= 2
+    return v
+
+
+def _seg_kernel(vals_ref, xg_ref, psum_ref):
+    psum_ref[...] = lane_scan(vals_ref[...] * xg_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("tile_c", "interpret"))
@@ -43,25 +60,28 @@ def seg_psum(vals: jnp.ndarray, cols: jnp.ndarray, x: jnp.ndarray,
              *, tile_c: int = 8, interpret: bool = False) -> jnp.ndarray:
     """Per-chunk inclusive prefix sums of ``vals * x[cols]``.
 
-    vals/cols: (C, L) nnz-stream slab with L % 128 == 0, C % 8 == 0.
-    x: (N,) — fits VMEM alongside the tiles (the distributed layer shards
-    x so each local slab sees only its gathered vector).
-    Returns psum: (C, L) in x.dtype.
+    vals/cols: (C, L) nnz-stream slab with L % 128 == 0.  The chunk axis
+    is padded to a multiple of 8 and tiled by the largest multiple of 8
+    dividing it, not above ``max(tile_c, 8)``.  x: (N,), any length — it
+    is gathered in HBM.  Returns psum: (C, L) in x.dtype.
     """
     C, L = vals.shape
-    tc = min(tile_c, C)
-    if C % tc:
-        raise ValueError(f"tile_c must divide chunk count: {C} vs {tc}")
-    grid = (C // tc,)
-    return pl.pallas_call(
+    if L % LANE:
+        raise ValueError(f"chunk length {L} is not a multiple of {LANE}")
+    Cp = round_up(max(C, 1), SUBLANE)
+    tc = fit_tile(Cp, tile_c, SUBLANE)
+    xg = jnp.take(x, cols, axis=0).astype(x.dtype)         # XLA gather
+    v = pad_axis(vals.astype(x.dtype), 0, Cp)
+    xg = pad_axis(xg, 0, Cp)
+    psum = pl.pallas_call(
         _seg_kernel,
-        grid=grid,
+        grid=(Cp // tc,),
         in_specs=[
             pl.BlockSpec((tc, L), lambda c: (c, 0)),           # vals tile
-            pl.BlockSpec((tc, L), lambda c: (c, 0)),           # cols tile
-            pl.BlockSpec((x.shape[0],), lambda c: (0,)),       # full x in VMEM
+            pl.BlockSpec((tc, L), lambda c: (c, 0)),           # x[cols] tile
         ],
         out_specs=pl.BlockSpec((tc, L), lambda c: (c, 0)),
-        out_shape=jax.ShapeDtypeStruct((C, L), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((Cp, L), x.dtype),
         interpret=interpret,
-    )(vals, cols, x)
+    )(v, xg)
+    return psum[:C]

@@ -18,7 +18,8 @@ SNIPPETS.md §2) ported to SpMV:
 
 The split count NS is a planning decision (``plan.split_meta``), driven
 by the row span (chunks of the longest row) and the device core count —
-the ``get_meta_param`` analogue.
+the ``get_meta_param`` analogue.  As in ``spmv_seg``, the gather of x
+runs in XLA before stage 1 and the scan is :func:`~.spmv_seg.lane_scan`.
 """
 from __future__ import annotations
 
@@ -28,15 +29,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .spmv_seg import lane_scan
+from .tiling import LANE, SUBLANE, fit_tile, pad_axis, round_up
+
 __all__ = ["split_psum", "split_combine"]
 
 
-def _split_psum_kernel(vals_ref, cols_ref, x_ref, psum_ref):
-    vals = vals_ref[0]                         # (TC, L) tile of one split
-    cols = cols_ref[0]                         # (TC, L)
-    x = x_ref[...]                             # (N,) resident in VMEM
-    prod = vals * jnp.take(x, cols, axis=0)    # VMEM dynamic gather
-    psum_ref[0] = jnp.cumsum(prod, axis=1)     # within-chunk inclusive scan
+def _split_psum_kernel(vals_ref, xg_ref, psum_ref):
+    psum_ref[...] = lane_scan(vals_ref[...] * xg_ref[...])   # (TC, L)
 
 
 @functools.partial(jax.jit, static_argnames=("tile_c", "interpret"))
@@ -47,45 +47,51 @@ def split_psum(vals: jnp.ndarray, cols: jnp.ndarray, x: jnp.ndarray,
     vals/cols: (NS, Cs, L) nnz-stream slab with L % 128 == 0.  The grid
     is 2-D, (NS, Cs // tc): the split axis keeps every core busy even
     when Cs is tiny (one monster row => C chunks cut into NS splits).
-    x: (N,) gathered vector, fits VMEM alongside the tiles.
-    Returns psum: (NS, Cs, L) in x.dtype.
+    Cs is padded to a multiple of 8 for the tile.  x: (N,), gathered in
+    HBM.  Returns psum: (NS, Cs, L) in x.dtype.
     """
     NS, Cs, L = vals.shape
-    tc = min(tile_c, Cs)
-    while Cs % tc:                 # largest divisor of Cs not above tile_c
-        tc -= 1
-    grid = (NS, Cs // tc)
-    return pl.pallas_call(
+    if L % LANE:
+        raise ValueError(f"chunk length {L} is not a multiple of {LANE}")
+    Cp = round_up(max(Cs, 1), SUBLANE)
+    tc = fit_tile(Cp, tile_c, SUBLANE)
+    xg = jnp.take(x, cols, axis=0).astype(x.dtype)         # XLA gather
+    v = pad_axis(vals.astype(x.dtype), 1, Cp)
+    xg = pad_axis(xg, 1, Cp)
+    psum = pl.pallas_call(
         _split_psum_kernel,
-        grid=grid,
+        grid=(NS, Cp // tc),
         in_specs=[
-            pl.BlockSpec((1, tc, L), lambda s, c: (s, c, 0)),   # vals tile
-            pl.BlockSpec((1, tc, L), lambda s, c: (s, c, 0)),   # cols tile
-            pl.BlockSpec((x.shape[0],), lambda s, c: (0,)),     # full x
+            pl.BlockSpec((None, tc, L), lambda s, c: (s, c, 0)),  # vals
+            pl.BlockSpec((None, tc, L), lambda s, c: (s, c, 0)),  # x[cols]
         ],
-        out_specs=pl.BlockSpec((1, tc, L), lambda s, c: (s, c, 0)),
-        out_shape=jax.ShapeDtypeStruct((NS, Cs, L), x.dtype),
+        out_specs=pl.BlockSpec((None, tc, L), lambda s, c: (s, c, 0)),
+        out_shape=jax.ShapeDtypeStruct((NS, Cp, L), x.dtype),
         interpret=interpret,
-    )(vals, cols, x)
+    )(v, xg)
+    return psum[:, :Cs]
 
 
 def _split_combine_kernel(part_ref, y_ref):
-    y_ref[...] = jnp.sum(part_ref[...], axis=0)   # (NS, TR) -> (TR,)
+    y_ref[...] = jnp.sum(part_ref[...], axis=0, keepdims=True)  # (1, TR)
 
 
 @functools.partial(jax.jit, static_argnames=("tile_r", "interpret"))
-def split_combine(partial: jnp.ndarray, *, tile_r: int = 128,
+def split_combine(partial: jnp.ndarray, *, tile_r: int = 512,
                   interpret: bool = False) -> jnp.ndarray:
-    """Stage 2: reduce the per-split partial row sums, (NS, R) -> (R,)."""
+    """Stage 2: reduce the per-split partial row sums, (NS, R) -> (R,).
+
+    R is padded to a multiple of 128 and tiled by the largest multiple of
+    128 dividing it, not above ``max(tile_r, 128)``."""
     NS, R = partial.shape
-    tr = min(tile_r, R)
-    while R % tr:                  # largest divisor of R not above tile_r
-        tr -= 1
-    return pl.pallas_call(
+    Rp = round_up(max(R, 1), LANE)
+    tr = fit_tile(Rp, tile_r, LANE)
+    y = pl.pallas_call(
         _split_combine_kernel,
-        grid=(R // tr,),
+        grid=(Rp // tr,),
         in_specs=[pl.BlockSpec((NS, tr), lambda r: (0, r))],
-        out_specs=pl.BlockSpec((tr,), lambda r: (r,)),
-        out_shape=jax.ShapeDtypeStruct((R,), partial.dtype),
+        out_specs=pl.BlockSpec((1, tr), lambda r: (0, r)),
+        out_shape=jax.ShapeDtypeStruct((1, Rp), partial.dtype),
         interpret=interpret,
-    )(partial)
+    )(pad_axis(partial, 1, Rp))
+    return y[0, :R]

@@ -9,22 +9,13 @@ since it maps to the slower inter-pod links.
 from __future__ import annotations
 
 import jax
-
-
-def auto_axis_types(n: int) -> dict:
-    """``axis_types`` kwarg for ``jax.make_mesh`` across jax versions.
-
-    ``jax.sharding.AxisType`` only exists on jax >= 0.5; earlier versions
-    (no explicit-sharding mode) take no kwarg and behave as Auto.
-    """
-    at = getattr(jax.sharding, "AxisType", None)
-    return {"axis_types": (at.Auto,) * n} if at is not None else {}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **auto_axis_types(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model_parallel: int | None = None):
@@ -32,4 +23,4 @@ def make_host_mesh(model_parallel: int | None = None):
     n = len(jax.devices())
     mp = model_parallel or (2 if n % 2 == 0 and n > 1 else 1)
     return jax.make_mesh((n // mp, mp), ("data", "model"),
-                         **auto_axis_types(2))
+                         axis_types=(AxisType.Auto,) * 2)

@@ -24,9 +24,15 @@ unchanged from the drift-aware engine this router refactors):
 * **Cross-request micro-batching** (``micro_batch=``): concurrent
   single-vector requests for the same tenant are gathered — leader /
   follower, bounded by ``max_batch``/``max_wait_ms`` — into one
-  multi-RHS ``(N, B)`` execute, whose columns are bitwise-equal to
-  per-vector calls (the batched-numpy invariant the tests pin), then
-  scattered back to each waiter.
+  multi-RHS ``(N, B)`` execute, then scattered back to each waiter.
+
+With a ``mesh=`` (1-D, axis ``"model"``) the engine serves on the device:
+every tenant holds the compiled device function of its program
+(:func:`~repro.core.program.make_program_spmv_fn`, its operands placed
+on the mesh once), built at cold ingest, at warm start and at every
+rebalance swap.  Without a mesh it serves through the float64 NumPy
+reference executor, whose batched columns are bitwise-equal to
+per-vector calls.
 """
 from __future__ import annotations
 
@@ -42,13 +48,18 @@ import numpy as np
 
 from repro.core.artifacts import ArtifactError, load_program, save_program
 from repro.core.plan import PlanCache, PlanChoice, autotune, feature_key
-from repro.core.program import SpmvProgram, execute, lower
+from repro.core.program import SpmvProgram, execute, gather_b, lower, \
+    make_program_spmv_fn, scatter_x
 from repro.core.sparse_matrix import CSRMatrix
 from repro.core.spmv import SpmvPlan
 from repro.serve.rebalance import LoadMonitor, RebalanceConfig, \
     RebalanceEvent, replan
 
-__all__ = ["SparseMatrixEngine", "IngestedMatrix", "MicroBatchConfig"]
+__all__ = ["SparseMatrixEngine", "IngestedMatrix", "MicroBatchConfig",
+           "MESH_AXIS"]
+
+#: The mesh axis the engine shards programs over.
+MESH_AXIS = "model"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -169,6 +180,9 @@ class IngestedMatrix:
     replan_lock: threading.Lock = dataclasses.field(
         default_factory=threading.Lock)
     batcher: _MicroBatcher | None = None
+    # Compiled device function of the served program (``device_fn.program``
+    # is that program); None when the engine has no mesh.
+    device_fn: object | None = None
 
 
 class SparseMatrixEngine:
@@ -187,6 +201,12 @@ class SparseMatrixEngine:
        similar matrix (equal :func:`~repro.core.plan.feature_key`)
        reuses the previously autotuned plan — no autotune, fresh lower.
 
+    ``mesh`` (a 1-D :class:`jax.sharding.Mesh` with axis ``"model"``)
+    puts serving on the device executor, one shard per device; the shard
+    count is then the mesh size, and a ``num_shards`` that disagrees
+    raises.  Without a mesh, ``num_shards`` (default 8) sets the plans
+    and the NumPy reference serves.
+
     ``spmv`` answers y = A @ x requests — ``x`` a single (N,) vector or
     a multi-RHS block (N, B) — in the caller's original index order;
     with ``micro_batch=`` enabled, concurrent single-vector requests for
@@ -200,7 +220,7 @@ class SparseMatrixEngine:
     swap rewrites the tenant's artifact so restarts resume the new plan.
     """
 
-    def __init__(self, *, num_shards: int = 8,
+    def __init__(self, *, mesh=None, num_shards: int | None = None,
                  probe: int | str | None = None,
                  seed: int = 0,
                  rebalance: RebalanceConfig | bool | None = None,
@@ -208,7 +228,18 @@ class SparseMatrixEngine:
                  plan_cache_dir: str | None = None,
                  artifact_dir: str | None = None,
                  micro_batch: MicroBatchConfig | bool | None = None):
-        self.num_shards = num_shards
+        if mesh is not None:
+            if tuple(mesh.axis_names) != (MESH_AXIS,):
+                raise ValueError(f"the engine needs a 1-D mesh with axis "
+                                 f"{MESH_AXIS!r}, got axes "
+                                 f"{tuple(mesh.axis_names)}")
+            n = int(mesh.shape[MESH_AXIS])
+            if num_shards is not None and num_shards != n:
+                raise ValueError(f"num_shards={num_shards} disagrees with "
+                                 f"the mesh's {n} devices")
+            num_shards = n
+        self.mesh = mesh
+        self.num_shards = 8 if num_shards is None else num_shards
         self.probe = probe
         self.seed = seed
         if rebalance is True:
@@ -335,7 +366,8 @@ class SparseMatrixEngine:
             name=name, choice=choice, dist=dist,
             csr=csr if monitor is not None else None,
             plan_cache_hit=cache_hit, warm_start=warm is not None,
-            bundle_dir=bundle, rebalance_cfg=rebalance, monitor=monitor)
+            bundle_dir=bundle, rebalance_cfg=rebalance, monitor=monitor,
+            device_fn=self._device_fn(dist))
         if self.micro_batch is not None:
             m.batcher = _MicroBatcher(
                 self.micro_batch,
@@ -354,9 +386,19 @@ class SparseMatrixEngine:
                 f"engine.ingest({name!r}, csr) first")
         return m
 
+    def _device_fn(self, dist: SpmvProgram):
+        """The compiled device function serving ``dist`` (None: no mesh)."""
+        if self.mesh is None:
+            return None
+        return make_program_spmv_fn(dist, self.mesh, axis=MESH_AXIS)
+
     def _serve_block(self, m: IngestedMatrix, x: np.ndarray,
                      n_requests: int = 1) -> np.ndarray:
-        y = execute(m.dist, x)
+        fn = m.device_fn                 # one read: fn and its program agree
+        if fn is None:
+            y = execute(m.dist, x)
+        else:
+            y = gather_b(fn.program, fn(scatter_x(fn.program, x)))
         m.spmv_count += n_requests
         self.total_requests += n_requests
         if m.monitor is not None and m.monitor.observe(x):
@@ -366,12 +408,12 @@ class SparseMatrixEngine:
     def spmv(self, name: str, x: np.ndarray) -> np.ndarray:
         """y = A @ x for the ingested tenant ``name`` (original order).
 
-        ``x``: (N,) or multi-RHS (N, B) → (M,) or (M, B); batched columns
-        are bitwise-equal to per-vector calls — which is also why
-        micro-batched single-vector requests (``micro_batch=``) return
-        exactly what a solo call would.  Unknown names raise an
-        actionable :class:`KeyError` *before* any stats are touched, so
-        ``stats()`` counts successful calls only.
+        ``x``: (N,) or multi-RHS (N, B) → (M,) or (M, B): float32 from
+        the device, float64 from the NumPy reference, whose batched
+        columns are bitwise-equal to per-vector calls (so there a
+        micro-batched request returns exactly what a solo call would).
+        Unknown names raise an actionable :class:`KeyError` *before* any
+        stats are touched, so ``stats()`` counts successful calls only.
         """
         m = self._lookup(name)
         if m.batcher is not None and np.ndim(x) == 1:
@@ -431,6 +473,9 @@ class SparseMatrixEngine:
             amortization_horizon=self._amortization_horizon(m))
         m.rebalance_log.append(event)
         if new_dist is not None:
+            # Build the new program's device function while the old one
+            # keeps serving; each swing below is one attribute rebind.
+            m.device_fn = self._device_fn(new_dist)
             m.dist = new_dist          # the double-buffer swing
             m.choice = new_choice
             m.monitor.attach(new_dist)
@@ -457,6 +502,11 @@ class SparseMatrixEngine:
     def plan(self, name: str) -> SpmvPlan:
         """The plan serving ``name``."""
         return self._lookup(name).choice.plan
+
+    def device_fn(self, name: str):
+        """The compiled device function serving ``name`` (None without a
+        mesh); its ``program`` and ``operands`` are the live ones."""
+        return self._lookup(name).device_fn
 
     def plans(self) -> Dict[str, str]:
         """name -> PlanChoice JSON for every ingested tenant."""
@@ -492,5 +542,7 @@ class SparseMatrixEngine:
                     "rejected": sum(not e.swapped for e in m.rebalance_log)}
             if m.batcher is not None:
                 s["micro_batch"] = m.batcher.stats()
+            if m.device_fn is not None:
+                s["device_operand_bytes"] = m.device_fn.operand_bytes
             out[n] = s
         return out

@@ -12,9 +12,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.configs.registry import get_smoke_config
-from repro.launch.mesh import auto_axis_types
 from repro.models import model as mm, params as pp
 from repro.optim import adamw
 from repro.train.loop import RunConfig, make_train_step
@@ -29,9 +29,9 @@ _SUBPROC = textwrap.dedent("""
                                  make_seg_spmv_fn)
     from repro.core.sparse_matrix import csr_to_dense
     from repro.data.matrices import make_matrix
-    from repro.launch.mesh import auto_axis_types
+    from jax.sharding import AxisType
 
-    mesh = jax.make_mesh((8,), ("model",), **auto_axis_types(1))
+    mesh = jax.make_mesh((8,), ("model",), axis_types=(AxisType.Auto,))
     A = make_matrix("cop20k_A", scale=0.005)
     x = np.random.default_rng(1).standard_normal(A.ncols).astype(np.float32)
     out = {}
@@ -105,7 +105,8 @@ def test_distributed_spmv_8dev_subprocess():
 def test_train_step_factory_single_device():
     """The jitted train step runs on a 1x1 mesh (CPU) and reduces loss."""
     cfg = get_smoke_config("qwen3_4b")
-    mesh = jax.make_mesh((1, 1), ("data", "model"), **auto_axis_types(2))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     run = RunConfig(fsdp=False, remat=True, donate=False, grad_accum=2)
     _, jit_for, _ = make_train_step(cfg, adamw.AdamWConfig(lr=1e-2), mesh, run)
     params = pp.init_params(cfg, jax.random.PRNGKey(0))

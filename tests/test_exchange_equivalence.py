@@ -7,12 +7,12 @@ invariant on the device executor's exchange tables: rebuilding each
 reader's ``[x_local ++ recv]`` buffer from the send tables in numpy must
 reproduce the owner's x value at every mapped position — the exchange
 machinery validated without a device mesh (the mesh-backed bitwise run
-lives in ``test_program.py``'s subprocess tests).
+lives in ``test_program.py``'s subprocess tests; single-shard programs
+also run here on a 1-device mesh).
 
-Runs property-based when ``hypothesis`` is installed (the CI
-``tier1-with-hypothesis`` job); falls back to a deterministic seeded
-sweep of the same property otherwise, so the local environment — which
-has no hypothesis — still covers every axis.
+Runs property-based when ``hypothesis`` is installed; falls back to a
+deterministic seeded sweep of the same property otherwise, so every axis
+is covered either way.
 """
 import itertools
 
@@ -143,6 +143,24 @@ def test_single_shard_mesh(kernel, exchange):
     plan = SpmvPlan(num_shards=1, kernel=kernel, exchange=exchange,
                     shard_exchanges=(exchange,))
     _check_plan(A, plan)
+
+
+@pytest.mark.parametrize("kernel", PLAN_KERNELS)
+@pytest.mark.parametrize("exchange", PLAN_EXCHANGES)
+def test_single_shard_device_mesh(kernel, exchange):
+    """The same single-shard programs through the device executor on a
+    1-device mesh (Pallas kernels, interpreted on the CPU), against the
+    float64 oracle."""
+    import jax
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((1,), ("model",), axis_types=(AxisType.Auto,),
+                         devices=jax.devices()[:1])
+    A = mixed_structure(128, 128 * 5, seed=2)
+    prog = lower(A, SpmvPlan(num_shards=1, kernel=kernel, exchange=exchange,
+                             shard_exchanges=(exchange,)))
+    x = np.random.default_rng(7).standard_normal(A.ncols)
+    y = execute(prog, x, backend="shard_map", mesh=mesh, use_kernel=True)
+    np.testing.assert_allclose(y, csr_matvec(A, x), atol=2e-4, rtol=2e-4)
 
 
 def _property(M, density, num_shards, layout, distribution, kid, seed,

@@ -30,9 +30,9 @@ def setup(tmp_path_factory):
 
 def small_mesh(n_model=1):
     n = len(jax.devices())
-    from repro.launch.mesh import auto_axis_types
+    from jax.sharding import AxisType
     return jax.make_mesh(((n // n_model) or 1, n_model), ("data", "model"),
-                         **auto_axis_types(2))
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 class TestCheckpoint:

@@ -1,5 +1,10 @@
 """Per-kernel tests: Pallas (interpret=True) vs pure-jnp oracle vs dense.
 
+Every kernel pads its operands to the (8, 128) tile and picks aligned
+block extents itself (``kernels/tiling.py``), so the odd shapes below run
+the same block shapes the chip compiles; ``tests/test_tpu_compile.py``
+compiles them for a described TPU v5e.
+
 Shape/dtype sweeps + hypothesis property tests, per the assignment brief.
 ``hypothesis`` is an optional extra: without it only the property-test
 class is skipped — the sweep tests always collect and run.
@@ -508,6 +513,97 @@ class TestSplitKernel:
             use_kernel=True, interpret=True))
         np.testing.assert_allclose(y_flat, np.asarray(ops.split_spmv(spl, x)),
                                    rtol=1e-5, atol=1e-5)
+
+
+class TestAlignedBlocks:
+    """The padding and block-fitting paths that make each kernel's
+    blocks (8, 128)-aligned, on shapes that are not."""
+
+    @pytest.mark.parametrize("n,cap,align,want", [
+        (120, 256, 8, 120), (120_000, 256, 8, 240), (512, 512, 128, 512),
+        (640, 512, 128, 128), (8, 1, 8, 8), (1024, 100, 128, 128)])
+    def test_fit_tile(self, n, cap, align, want):
+        from repro.kernels.tiling import fit_tile
+        t = fit_tile(n, cap, align)
+        assert t == want and n % t == 0 and t % align == 0
+
+    def test_fit_tile_rejects_unaligned_extent(self):
+        from repro.kernels.tiling import fit_tile
+        with pytest.raises(ValueError, match="multiple"):
+            fit_tile(100, 64, 8)
+
+    @pytest.mark.parametrize("L", [128, 512])
+    def test_lane_scan_is_inclusive_prefix_sum(self, L):
+        from jax.experimental import pallas as pl
+        from repro.kernels.spmv_seg import lane_scan
+        v = np.random.default_rng(L).standard_normal((16, L)) \
+            .astype(np.float32)
+
+        def k(v_ref, o_ref):
+            o_ref[...] = lane_scan(v_ref[...])
+        out = pl.pallas_call(k, out_shape=jax.ShapeDtypeStruct(v.shape,
+                                                               v.dtype),
+                             interpret=True)(jnp.asarray(v))
+        np.testing.assert_allclose(np.asarray(out), np.cumsum(v, axis=1),
+                                   rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("M,W", [(13, 40), (8, 128), (300, 200)])
+    def test_ell_unaligned_slab(self, M, W):
+        rng = np.random.default_rng(M)
+        data = rng.standard_normal((M, W)).astype(np.float32)
+        cols = rng.integers(0, 777, (M, W)).astype(np.int32)
+        x = rng.standard_normal(777).astype(np.float32)
+        y = np.asarray(ops.ell_spmv(jnp.asarray(data), jnp.asarray(cols),
+                                    jnp.asarray(x), interpret=True))
+        assert y.shape == (M,)
+        np.testing.assert_allclose(y, (data * x[cols]).sum(axis=1),
+                                   rtol=1e-5, atol=1e-4)
+
+    @pytest.mark.parametrize("C", [1, 5, 13])
+    def test_seg_psum_unaligned_chunk_count(self, C):
+        from repro.kernels.spmv_seg import seg_psum
+        rng = np.random.default_rng(C)
+        vals = rng.standard_normal((C, 128)).astype(np.float32)
+        cols = rng.integers(0, 300, (C, 128)).astype(np.int32)
+        x = rng.standard_normal(300).astype(np.float32)
+        ps = np.asarray(seg_psum(jnp.asarray(vals), jnp.asarray(cols),
+                                 jnp.asarray(x), interpret=True))
+        np.testing.assert_allclose(
+            ps, np.asarray(ref.seg_psum_ref(vals, cols, x)),
+            rtol=1e-4, atol=1e-4)
+
+    @pytest.mark.parametrize("NS,Cs", [(3, 5), (2, 16)])
+    def test_split_psum_unaligned_split_slab(self, NS, Cs):
+        from repro.kernels.spmv_split import split_psum
+        rng = np.random.default_rng(NS * Cs)
+        vals = rng.standard_normal((NS, Cs, 128)).astype(np.float32)
+        cols = rng.integers(0, 200, (NS, Cs, 128)).astype(np.int32)
+        x = rng.standard_normal(200).astype(np.float32)
+        ps = np.asarray(split_psum(jnp.asarray(vals), jnp.asarray(cols),
+                                   jnp.asarray(x), interpret=True))
+        np.testing.assert_allclose(
+            ps, np.asarray(ref.split_psum_ref(vals, cols, x)),
+            rtol=1e-4, atol=1e-4)
+
+    @pytest.mark.parametrize("NS,R", [(1, 7), (5, 300), (64, 1024)])
+    def test_split_combine_unaligned_rows(self, NS, R):
+        from repro.kernels.spmv_split import split_combine
+        part = np.random.default_rng(R).standard_normal((NS, R)) \
+            .astype(np.float32)
+        y = np.asarray(split_combine(jnp.asarray(part), interpret=True))
+        np.testing.assert_allclose(y, part.sum(axis=0), rtol=1e-5,
+                                   atol=1e-5)
+
+    @pytest.mark.parametrize("T,tile_t", [(1, 8), (13, 8), (16, 4)])
+    def test_tile_contrib_padded_tile_count(self, T, tile_t):
+        from repro.kernels.spmv_tile import tile_contrib
+        rng = np.random.default_rng(T)
+        data = rng.standard_normal((T, 8, 128)).astype(np.float32)
+        xg = rng.standard_normal((T, 128)).astype(np.float32)
+        out = np.asarray(tile_contrib(jnp.asarray(data), jnp.asarray(xg),
+                                      tile_t=tile_t, interpret=True))
+        np.testing.assert_allclose(out, np.einsum("tij,tj->ti", data, xg),
+                                   rtol=1e-4, atol=1e-4)
 
 
 @pytest.mark.skipif(not HAVE_HYPOTHESIS, reason="hypothesis not installed")

@@ -204,9 +204,9 @@ _SUBPROC = textwrap.dedent("""
     from repro.core.sparse_matrix import csr_matvec
     from repro.core.spmv import SpmvPlan
     from repro.data.matrices import make_matrix
-    from repro.launch.mesh import auto_axis_types
+    from jax.sharding import AxisType
 
-    mesh = jax.make_mesh((4,), ("model",), **auto_axis_types(1))
+    mesh = jax.make_mesh((4,), ("model",), axis_types=(AxisType.Auto,))
     A = make_matrix("cop20k_A", scale=0.003)
     x = np.random.default_rng(1).standard_normal(A.ncols).astype(np.float32)
     X = np.random.default_rng(2).standard_normal((A.ncols, 3)) \\
@@ -334,9 +334,9 @@ _SUBPROC_PIPELINE = textwrap.dedent("""
     from repro.core.spmv import SpmvPlan
     from repro.data.matrices import make_matrix, mixed_structure, \\
         powerlaw_tail
-    from repro.launch.mesh import auto_axis_types
+    from jax.sharding import AxisType
 
-    mesh = jax.make_mesh((4,), ("model",), **auto_axis_types(1))
+    mesh = jax.make_mesh((4,), ("model",), axis_types=(AxisType.Auto,))
     A_mixed = mixed_structure(1024, 1024 * 8, seed=0)
     A_tail = powerlaw_tail(1024, 2 * 4 * 1024, n_monster=4, seed=3)
     A_cop = make_matrix("cop20k_A", scale=0.003)
@@ -412,3 +412,72 @@ def test_pipelined_executor_bitwise_equals_serial_4dev_subprocess():
     assert r.returncode == 0, r.stderr[-2000:]
     res = json.loads(r.stdout.strip().splitlines()[-1])
     assert all(res.values()), res
+
+
+# --------------------------------------------------------------------------
+# the device executor on a 1-device mesh (in-process; interpret mode)
+# --------------------------------------------------------------------------
+
+def _mesh1():
+    import jax
+    from jax.sharding import AxisType
+    return jax.make_mesh((1,), ("model",), axis_types=(AxisType.Auto,),
+                         devices=jax.devices()[:1])
+
+
+def test_execute_rejects_mesh_of_wrong_size():
+    """A 4-shard program on a 1-device mesh used to die in gather_b with an
+    IndexError; the executor now refuses it up front."""
+    from repro.core.program import make_program_spmv_fn
+    A = make_matrix("ford1", scale=0.05)
+    prog = lower(A, SpmvPlan(num_shards=4))
+    with pytest.raises(ValueError, match="4 shards"):
+        execute(prog, np.zeros(A.ncols), backend="shard_map", mesh=_mesh1())
+    with pytest.raises(ValueError, match="4 shards"):
+        make_program_spmv_fn(prog, _mesh1())
+    with pytest.raises(ValueError, match="no axis"):
+        make_program_spmv_fn(lower(A, SpmvPlan(num_shards=1)), _mesh1(),
+                             axis="data")
+
+
+def test_interpret_follows_the_platform(monkeypatch):
+    import repro.core.program as program
+    from repro.core.program import kernel_interpret, make_program_spmv_fn
+    assert kernel_interpret("cpu") is True
+    assert kernel_interpret("tpu") is False
+    assert kernel_interpret("tpu", False) is False
+    with pytest.raises(ValueError, match="interpret=True"):
+        kernel_interpret("tpu", True)
+    with pytest.raises(ValueError, match="interpret=False"):
+        kernel_interpret("cpu", False)
+    A = make_matrix("ford1", scale=0.05)
+    prog = lower(A, SpmvPlan(num_shards=1))
+    monkeypatch.setattr(program, "_mesh_platform", lambda mesh: "tpu")
+    with pytest.raises(ValueError, match="interpret=True"):
+        make_program_spmv_fn(prog, _mesh1(), use_kernel=True,
+                             interpret=True)
+
+
+@pytest.mark.parametrize("kernel", ["ell", "seg", "hyb", "split", "tile"])
+@pytest.mark.parametrize("reordering", ["none", "bfs"])
+def test_single_device_executor_matches_oracle(kernel, reordering):
+    """One shard on one device, jnp and Pallas paths, 1-D and (N, B):
+    ``scatter_x`` applies the reordering that ``gather_b`` undoes."""
+    from repro.core.program import gather_b, make_program_spmv_fn, scatter_x
+    A = make_matrix("cop20k_A", scale=0.005)
+    prog = lower(A, SpmvPlan(kernel=kernel, reordering=reordering,
+                             num_shards=1))
+    assert (prog.perm is None) == (reordering == "none")
+    rng = np.random.default_rng(11)
+    x = rng.standard_normal(A.ncols)
+    X = rng.standard_normal((A.ncols, 3))
+    for use_kernel in (False, True):
+        fn = make_program_spmv_fn(prog, _mesh1(), use_kernel=use_kernel)
+        assert fn.program is prog and fn.operand_bytes > 0
+        for v in (x, X):
+            y = gather_b(prog, fn(scatter_x(prog, v)))
+            np.testing.assert_allclose(y, csr_matvec(A, v), atol=1e-4,
+                                       rtol=1e-4)
+    np.testing.assert_allclose(
+        execute(prog, x, backend="shard_map", mesh=_mesh1()),
+        csr_matvec(A, x), atol=1e-4, rtol=1e-4)

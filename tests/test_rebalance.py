@@ -436,3 +436,51 @@ def test_monitor_batched_requests_count_columns():
     X = np.random.default_rng(0).standard_normal((A.ncols, 5))
     eng.spmv("a", X)
     assert mon.requests_seen == 5
+
+
+def test_swap_rebuilds_device_fn(monkeypatch):
+    """On a mesh, a validated swap rebuilds the tenant's device function
+    for the new program before rebinding it; serving then runs the new
+    program on the device."""
+    import dataclasses
+
+    import jax
+    from jax.sharding import AxisType
+
+    import repro.serve.router as router
+    from repro.core.plan import PlanChoice, RankedPlan
+    from repro.core.program import relower
+    from repro.serve.rebalance import RebalanceEvent
+
+    mesh = jax.make_mesh((1,), ("model",), axis_types=(AxisType.Auto,),
+                         devices=jax.devices()[:1])
+    A = make_matrix("cop20k_A", scale=0.005)
+    eng = SparseMatrixEngine(mesh=mesh, rebalance=CFG)
+    eng.ingest("a", A)
+    m = eng._matrices["a"]
+    old_fn, old_dist = m.device_fn, m.dist
+    new_kernel = "ell" if old_dist.plan.resolved_shard_kernels()[0] != "ell" \
+        else "seg"
+    new_plan = dataclasses.replace(old_dist.plan, kernel=new_kernel,
+                                   shard_kernels=None)
+
+    def fake_replan(csr, monitor, current, **kw):
+        new_dist = relower(kw["program"], new_plan)
+        choice = dataclasses.replace(
+            current, ranking=(RankedPlan(plan=new_plan,
+                                         cost=current.ranking[0].cost),))
+        event = RebalanceEvent(
+            request_index=kw["request_index"], window_index=0,
+            old_plan=current.plan, new_plan=new_plan, load_cv_before=0.0,
+            load_cv_after=0.0, probe_old_seconds=None,
+            probe_new_seconds=None, swapped=True, reason="test")
+        return new_dist, choice, event
+
+    monkeypatch.setattr(router, "replan", fake_replan)
+    eng._replan_and_swap(m)
+    assert m.dist is not old_dist and m.device_fn is not old_fn
+    assert m.device_fn.program is m.dist
+    assert m.dist.shard_kernels() == (new_kernel,)
+    x = np.random.default_rng(6).standard_normal(A.ncols)
+    np.testing.assert_allclose(eng.spmv("a", x), csr_matvec(A, x),
+                               atol=1e-4, rtol=1e-4)

@@ -316,3 +316,111 @@ def test_rebalance_swap_rewrites_artifact(tmp_path):
     x = np.zeros(N)
     x[rng.choice(hot, size=k)] = rng.standard_normal(k)
     assert np.array_equal(fresh.spmv("a", x), eng.spmv("a", x))
+
+
+# --------------------------------------------------------------------------
+# Serving on the device executor (a 1-device CPU mesh, interpret mode)
+# --------------------------------------------------------------------------
+
+def _mesh1():
+    import jax
+    from jax.sharding import AxisType
+    return jax.make_mesh((1,), ("model",), axis_types=(AxisType.Auto,),
+                         devices=jax.devices()[:1])
+
+
+def _assert_within_smoke_tol(A, X, Y):
+    """|y - y_ref|_i <= 1e-4 * (|A| |x|)_i, the chip smoke's bound."""
+    import dataclasses
+    from repro.core.sparse_matrix import csr_matvec
+    absA = dataclasses.replace(A, values=np.abs(A.values))
+    err = np.abs(np.asarray(Y, np.float64) - csr_matvec(A, X))
+    assert (err <= 1e-4 * csr_matvec(absA, np.abs(X))).all()
+
+
+@pytest.mark.parametrize("name,scale", [("cop20k_A", 0.005),
+                                        ("ford1", 0.05), ("rmat", 0.002)])
+def test_device_engine_serves_within_tolerance(name, scale):
+    """Ingest on a mesh builds the device function once; 1-D and (N, B)
+    requests go through it and match the float64 oracle."""
+    A = make_matrix(name, scale=scale)
+    eng = SparseMatrixEngine(mesh=_mesh1())
+    assert eng.num_shards == 1
+    eng.ingest("a", A)
+    fn = eng.device_fn("a")
+    assert fn is not None and fn.program is eng._matrices["a"].dist
+    rng = np.random.default_rng(3)
+    x = rng.standard_normal(A.ncols)
+    y = eng.spmv("a", x)
+    assert y.shape == (A.nrows,) and y.dtype == np.float32
+    _assert_within_smoke_tol(A, x, y)
+    X = rng.standard_normal((A.ncols, 4))
+    Y = eng.spmv("a", X)
+    assert Y.shape == (A.nrows, 4)
+    _assert_within_smoke_tol(A, X, Y)
+    assert eng.stats()["a"]["device_operand_bytes"] == fn.operand_bytes > 0
+    assert eng.device_fn("a") is fn          # built once, not per request
+
+
+def test_device_engine_micro_batched_requests():
+    import threading
+    from repro.serve.router import MicroBatchConfig
+    A = make_matrix("cop20k_A", scale=0.005)
+    eng = SparseMatrixEngine(
+        mesh=_mesh1(),
+        micro_batch=MicroBatchConfig(max_batch=4, max_wait_ms=100.0))
+    eng.ingest("a", A)
+    rng = np.random.default_rng(4)
+    xs = [rng.standard_normal(A.ncols) for _ in range(4)]
+    got = [None] * 4
+    barrier = threading.Barrier(4)
+
+    def hit(i):
+        barrier.wait()
+        got[i] = eng.spmv("a", xs[i])
+
+    threads = [threading.Thread(target=hit, args=(i,)) for i in range(4)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    for x, y in zip(xs, got):
+        _assert_within_smoke_tol(A, x, y)
+    assert eng.stats()["a"]["micro_batch"]["requests"] == 4
+    assert eng.stats()["a"]["spmv_count"] == 4
+
+
+def test_device_engine_warm_start_builds_device_fn(tmp_path, monkeypatch):
+    """A warm start from an artifact skips autotune and lower but still
+    builds the tenant's device function."""
+    A = make_matrix("cop20k_A", scale=0.005)
+    store = str(tmp_path / "artifacts")
+    e1 = SparseMatrixEngine(mesh=_mesh1(), artifact_dir=store)
+    e1.ingest("a", A)
+    x = np.random.default_rng(5).standard_normal(A.ncols)
+    y1 = e1.spmv("a", x)
+    import repro.serve.router as router
+    monkeypatch.setattr(router, "autotune", _boom)
+    monkeypatch.setattr(router, "lower", _boom)
+    e2 = SparseMatrixEngine(mesh=_mesh1(), artifact_dir=store)
+    e2.ingest("a", A)
+    assert e2.stats()["a"]["warm_start"]
+    fn = e2.device_fn("a")
+    assert fn is not None and fn.program is e2._matrices["a"].dist
+    y2 = e2.spmv("a", x)
+    _assert_within_smoke_tol(A, x, y2)
+    assert np.array_equal(y1, y2)
+
+
+def test_device_engine_mesh_and_shard_count_mismatch_raise():
+    import jax
+    from jax.sharding import AxisType
+    with pytest.raises(ValueError, match="disagrees"):
+        SparseMatrixEngine(mesh=_mesh1(), num_shards=4)
+    two_d = jax.make_mesh((1, 1), ("data", "model"),
+                          axis_types=(AxisType.Auto,) * 2,
+                          devices=jax.devices()[:1])
+    with pytest.raises(ValueError, match="1-D mesh"):
+        SparseMatrixEngine(mesh=two_d)
+    assert SparseMatrixEngine(mesh=_mesh1(), num_shards=1).num_shards == 1
+    assert SparseMatrixEngine().num_shards == 8      # no mesh: the default
