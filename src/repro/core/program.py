@@ -59,6 +59,8 @@ from .sparse_matrix import CSRMatrix, ELL_LANE, ELL_SUBLANE, EllMatrix, \
     SegMatrix, SplitMatrix, TileMatrix, csr_to_ell
 from .spmv import PLAN_KERNELS, SpmvPlan
 from repro.kernels import ops as kops
+from repro.kernels.spmv_tile import TILES_PER_STEP
+from repro.kernels.tiling import round_up
 
 __all__ = ["ShardStage", "SpmvProgram", "lower", "relower", "execute",
            "make_program_spmv_fn", "build_program_step", "kernel_interpret",
@@ -635,7 +637,7 @@ def _stack_stages(stages, R: int, remap) -> dict:
     C = max(max((s.num_chunks for s in segs), default=ELL_SUBLANE),
             max((s.num_splits * s.chunks_per_split for s in spls),
                 default=ELL_SUBLANE))
-    C = _round_up(C, ELL_SUBLANE)
+    C = round_up(C, ELL_SUBLANE)
     NS = max((s.num_splits for s in spls), default=1)
     Pp = max(max((s.n_pieces for s in segs), default=0),
              max((s.n_pieces for s in spls), default=0))
@@ -656,7 +658,9 @@ def _stack_stages(stages, R: int, remap) -> dict:
     t_bn = tiles[0].bn if tiles else ELL_LANE
     if any((t.bm, t.bn) != (t_bm, t_bn) for t in tiles):
         raise AssertionError("tile stages must share one tile shape")
-    Tp = max(max((t.num_tiles for t in tiles), default=0), 1)
+    # the tile count, likewise, to the tile kernel's tiles per grid step
+    Tp = round_up(max(max((t.num_tiles for t in tiles), default=0), 1),
+                  TILES_PER_STEP)
     Rb = -(-R // t_bm)
     tile_data = np.zeros((S, Tp, t_bm, t_bn), dtype=np.float32)
     tile_xcol = np.zeros((S, Tp, t_bn), dtype=np.int32)
@@ -756,7 +760,7 @@ def _device_operands(program: SpmvProgram) -> dict:
         out = lay.local_index(cols).astype(np.int32)
         return np.where(vals != 0, out, 0).astype(np.int32)
 
-    R = int(max(_round_up(max(st.rows, 1), ELL_SUBLANE) for st in stages))
+    R = int(max(round_up(max(st.rows, 1), ELL_SUBLANE) for st in stages))
     flags = _row_remote_flags(program)
     row_remote = np.zeros((S, R), dtype=bool)
     loc_stages, rem_stages = [], []
@@ -778,10 +782,6 @@ def _device_operands(program: SpmvProgram) -> dict:
     return cached
 
 
-def _round_up(x: int, m: int) -> int:
-    return ((x + m - 1) // m) * m
-
-
 _SET_KEYS = ("ell_data", "ell_cols", "ovf_rows", "ovf_cols", "ovf_vals",
              "seg_vals", "seg_cols", "seg_rows", "seg_pieces",
              "tile_data", "tile_xcol", "tile_brow")
@@ -792,19 +792,14 @@ _OPERAND_KEYS = (("kid",)
                  + ("send_idx", "row_remote"))
 
 
-def kernel_interpret(platform: str, interpret: bool | None = None) -> bool:
+def kernel_interpret(platform: str) -> bool:
     """Pallas interpret mode for the devices of ``platform``.
 
     Interpret mode is the CPU stand-in for the chip and nothing else: it
-    is on exactly when the platform is ``"cpu"``.  ``None`` derives it;
-    an explicit value that disagrees raises, so that a kernel meant for
-    the chip never falls back to the interpreter in silence."""
-    want = platform == "cpu"
-    if interpret is None or interpret == want:
-        return want
-    raise ValueError(
-        f"interpret={interpret} on platform {platform!r}: Pallas kernels run "
-        f"interpreted only on cpu and compiled everywhere else")
+    is on exactly when the platform is ``"cpu"``.  The executor derives
+    it from its mesh and takes no override, so a kernel meant for the
+    chip never falls back to the interpreter in silence."""
+    return platform == "cpu"
 
 
 def _mesh_platform(mesh) -> str:
@@ -812,9 +807,7 @@ def _mesh_platform(mesh) -> str:
 
 
 def build_program_step(program: SpmvProgram, mesh, axis: str = "model", *,
-                       use_kernel: bool = False,
-                       interpret: bool | None = None,
-                       pipeline: bool = True):
+                       use_kernel: bool = False, pipeline: bool = True):
     """The device executor's jitted step and its host operands.
 
     Returns ``(step, operands)``: ``step(*operands, x_shards)`` is the one
@@ -837,7 +830,7 @@ def build_program_step(program: SpmvProgram, mesh, axis: str = "model", *,
             f"the program has {S} shards but the mesh has "
             f"{mesh.shape[axis]} devices along {axis!r}: lower the plan "
             f"with num_shards={mesh.shape[axis]}")
-    interpret = kernel_interpret(_mesh_platform(mesh), interpret)
+    interpret = kernel_interpret(_mesh_platform(mesh))
     ops = _device_operands(program)
     R = ops["R"]
     NS_loc, NS_rem = ops["NS_loc"], ops["NS_rem"]
@@ -859,9 +852,12 @@ def build_program_step(program: SpmvProgram, mesh, axis: str = "model", *,
         """One slice's kernel dispatch against its own x buffer.
 
         A multi-RHS buffer (n, B) runs one vector at a time in a device
-        loop: a batched gather would put B on the lanes, which the chip
-        pads to 128, so an (R, W, B) temporary would take 128 / B times
-        its size."""
+        loop, so each request reads the slice's operands B times.  A
+        batched gather keeps the B values of each index together on the
+        lanes, which the chip pads to 128.  XLA picks that layout even
+        for a vmapped gather with B leading, and the v5e step's
+        temporaries then reach about 64 times the one-vector step's
+        (7.6 GiB on cop20k_A with one shard and B = 8)."""
         *slabs, xv = args
         if xv.ndim == 2:
             return jax.lax.map(partial(vector_pass, *slabs), xv.T).T
@@ -941,9 +937,7 @@ def build_program_step(program: SpmvProgram, mesh, axis: str = "model", *,
 
 
 def make_program_spmv_fn(program: SpmvProgram, mesh, axis: str = "model", *,
-                         use_kernel: bool = False,
-                         interpret: bool | None = None,
-                         pipeline: bool = True):
+                         use_kernel: bool = False, pipeline: bool = True):
     """THE device executor: one shard_map function for any lowered program.
 
     Returns ``f(x_shards) -> y_shards`` with ``x_shards`` of shape
@@ -984,8 +978,7 @@ def make_program_spmv_fn(program: SpmvProgram, mesh, axis: str = "model", *,
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     step, host_ops = build_program_step(
-        program, mesh, axis, use_kernel=use_kernel, interpret=interpret,
-        pipeline=pipeline)
+        program, mesh, axis, use_kernel=use_kernel, pipeline=pipeline)
     sharding = NamedSharding(mesh, P(axis))
     operands = jax.device_put(host_ops, sharding)
 
@@ -1034,8 +1027,7 @@ def probe_program(program: SpmvProgram, *, emu: EmuConfig | None = None,
 
 def execute(program: SpmvProgram, x: np.ndarray | None = None, *,
             backend: str = "numpy", mesh=None, axis: str = "model",
-            use_kernel: bool = False, interpret: bool | None = None,
-            pipeline: bool = True,
+            use_kernel: bool = False, pipeline: bool = True,
             emu: EmuConfig | None = None, engine: str = "vectorized"):
     """Execute a lowered program — the single entry point for every backend.
 
@@ -1062,8 +1054,7 @@ def execute(program: SpmvProgram, x: np.ndarray | None = None, *,
             raise ValueError("backend='shard_map' needs a mesh with "
                              "plan.num_shards devices")
         fn = make_program_spmv_fn(program, mesh, axis=axis,
-                                  use_kernel=use_kernel, interpret=interpret,
-                                  pipeline=pipeline)
+                                  use_kernel=use_kernel, pipeline=pipeline)
         return gather_b(program, fn(scatter_x(program, x)))
     raise ValueError(f"unknown executor backend {backend!r}; expected "
                      f"'numpy', 'shard_map', or 'emu'")

@@ -235,7 +235,7 @@ def local_spmv(dist, x: np.ndarray) -> np.ndarray:
 
 
 def make_spmv_fn(dist, mesh: Mesh, axis: str = "model",
-                 *, use_kernel: bool = False, interpret: bool | None = None):
+                 *, use_kernel: bool = False):
     """Deprecated shim over :func:`repro.core.program.make_program_spmv_fn`.
 
     Returns the old ``f(data, cols, x_shards) -> b_shards`` signature; the
@@ -254,7 +254,7 @@ def make_spmv_fn(dist, mesh: Mesh, axis: str = "model",
             prog, dataclasses.replace(prog.plan, exchange="allgather",
                                       shard_exchanges=None))
     inner = make_program_spmv_fn(prog, mesh, axis=axis,
-                                 use_kernel=use_kernel, interpret=interpret)
+                                 use_kernel=use_kernel)
 
     @jax.jit
     def fn(data, cols, x_shards):
@@ -264,8 +264,7 @@ def make_spmv_fn(dist, mesh: Mesh, axis: str = "model",
 
 
 def make_seg_spmv_fn(dist, mesh: Mesh, axis: str = "model",
-                     *, use_kernel: bool = False,
-                     interpret: bool | None = None):
+                     *, use_kernel: bool = False):
     """Deprecated shim over :func:`repro.core.program.make_program_spmv_fn`
     for uniform-seg programs (old ``f(vals, cols, rows, pieces, x_shards)``
     signature)."""
@@ -281,7 +280,7 @@ def make_seg_spmv_fn(dist, mesh: Mesh, axis: str = "model",
             prog, dataclasses.replace(prog.plan, exchange="allgather",
                                       shard_exchanges=None))
     inner = make_program_spmv_fn(prog, mesh, axis=axis,
-                                 use_kernel=use_kernel, interpret=interpret)
+                                 use_kernel=use_kernel)
     rows_pad = int(dist.rows_per_shard.max())
 
     @jax.jit
@@ -370,8 +369,7 @@ def build_halo(dist) -> HaloProgram:
 
 
 def make_halo_spmv_fn(dist, halo: HaloProgram, mesh: Mesh,
-                      axis: str = "model", *, use_kernel: bool = False,
-                      interpret: bool | None = None):
+                      axis: str = "model", *, use_kernel: bool = False):
     """Deprecated shim over :func:`repro.core.program.make_program_spmv_fn`
     (old ``f(data, cols_remap, send_idx, x_shards)`` signature).
 
@@ -392,7 +390,7 @@ def make_halo_spmv_fn(dist, halo: HaloProgram, mesh: Mesh,
             prog, dataclasses.replace(prog.plan, exchange="halo",
                                       shard_exchanges=None))
     inner = make_program_spmv_fn(prog, mesh, axis=axis,
-                                 use_kernel=use_kernel, interpret=interpret)
+                                 use_kernel=use_kernel)
 
     @jax.jit
     def fn(data, cols_remap, send_idx, x_shards):

@@ -32,7 +32,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .tiling import pad_axis, round_up
 
-__all__ = ["tile_walk_spmv", "tile_contrib"]
+__all__ = ["TILES_PER_STEP", "tile_walk_spmv", "tile_contrib"]
+
+#: Tiles per grid step of :func:`tile_contrib`.  The device executor
+#: rounds its tile count to a multiple of it, so its operands reach the
+#: kernel unpadded.
+TILES_PER_STEP = 8
 
 
 def _tile_spmv_kernel(counts_ref, tid_ref, bc_ref, data_ref, xb_ref, y_ref):
@@ -99,19 +104,21 @@ def _tile_contrib_kernel(d_ref, x_ref, o_ref):
     o_ref[...] = jnp.sum(d_ref[...] * x_ref[...], axis=2, keepdims=True)
 
 
-@functools.partial(jax.jit, static_argnames=("tile_t", "interpret"))
-def tile_contrib(data: jnp.ndarray, xg: jnp.ndarray, *, tile_t: int = 8,
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def tile_contrib(data: jnp.ndarray, xg: jnp.ndarray, *,
                  interpret: bool = False) -> jnp.ndarray:
     """Per-tile dense matvec (T, bm, bn) x (T, bn) -> (T, bm).
 
     The device executor's flat tile path: x lanes are pre-gathered
     through the remapped augmented buffer (so there is no block grid to
-    index), and the dense per-tile FMA stream runs here, ``tile_t``
-    tiles per grid step (T is zero-padded to a multiple of it); the
-    caller scatter-adds the contributions into block rows.
+    index), and the dense per-tile FMA stream runs here,
+    ``TILES_PER_STEP`` tiles per grid step; the caller scatter-adds the
+    contributions into block rows.  A tile count that is not a multiple
+    of ``TILES_PER_STEP`` is zero-padded here, which copies the operands:
+    the executor's are already aligned.
     """
     T, bm, bn = data.shape
-    tb = max(int(tile_t), 1)
+    tb = TILES_PER_STEP
     Tp = round_up(max(T, 1), tb)
     d = pad_axis(data, 0, Tp)
     x3 = pad_axis(xg.astype(data.dtype), 0, Tp).reshape(Tp, 1, bn)

@@ -72,7 +72,7 @@ _SUBPROC = textwrap.dedent("""
         seg_plan = SpmvPlan(layout="block", distribution=strat, kernel="seg",
                             num_shards=8)
         d = build_distributed(A, seg_plan)
-        fn = make_seg_spmv_fn(d, mesh, use_kernel=True, interpret=True)
+        fn = make_seg_spmv_fn(d, mesh, use_kernel=True)
         with mesh:
             y = fn(jnp.array(d.seg_vals), jnp.array(d.seg_cols),
                    jnp.array(d.seg_rows), jnp.array(d.seg_pieces),

@@ -245,7 +245,7 @@ _SUBPROC = textwrap.dedent("""
                     shard_kernels=("ell", "seg", "hyb", "seg"))
     prog = lower(A, plan)
     y_pal = execute(prog, x, backend="shard_map", mesh=mesh,
-                    use_kernel=True, interpret=True)
+                    use_kernel=True)
     out["pallas"] = bool(np.allclose(y_pal, ref, atol=1e-3))
     # batched (N, B) through the device path
     Y = execute(prog, X, backend="shard_map", mesh=mesh)
@@ -267,7 +267,7 @@ _SUBPROC = textwrap.dedent("""
     y_np = execute(pm, xm)
     y_sm = execute(pm, xm, backend="shard_map", mesh=mesh)
     y_pk = execute(pm, xm, backend="shard_map", mesh=mesh,
-                   use_kernel=True, interpret=True)
+                   use_kernel=True)
     out["monster_split"] = bool(
         np.allclose(y_np, refm, atol=1e-2) and
         np.allclose(y_sm, refm, atol=1e-2) and
@@ -289,7 +289,7 @@ _SUBPROC = textwrap.dedent("""
     y_np = execute(pt, xt)
     y_sm = execute(pt, xt, backend="shard_map", mesh=mesh)
     y_pk = execute(pt, xt, backend="shard_map", mesh=mesh,
-                   use_kernel=True, interpret=True)
+                   use_kernel=True)
     out["blocked_tile"] = bool(
         np.allclose(y_np, reft, atol=1e-2) and
         np.allclose(y_sm, reft, atol=1e-2) and
@@ -388,10 +388,9 @@ _SUBPROC_PIPELINE = textwrap.dedent("""
         num_shards=4, exchange="halo",
         shard_kernels=("ell", "seg", "hyb", "seg")))
     y_pipe = np.asarray(execute(prog, xk, backend="shard_map", mesh=mesh,
-                                use_kernel=True, interpret=True))
+                                use_kernel=True))
     y_ser = np.asarray(execute(prog, xk, backend="shard_map", mesh=mesh,
-                               use_kernel=True, interpret=True,
-                               pipeline=False))
+                               use_kernel=True, pipeline=False))
     out["pallas_interpret_bitwise"] = bool(
         np.array_equal(y_pipe, y_ser) and
         np.allclose(y_pipe, csr_matvec(A_mixed, xk), atol=1e-2, rtol=1e-4))
@@ -441,21 +440,34 @@ def test_execute_rejects_mesh_of_wrong_size():
 
 
 def test_interpret_follows_the_platform(monkeypatch):
+    """Interpret mode is derived from the mesh's platform and cannot be
+    asked for: on a (faked) TPU mesh the kernels trace compiled, and an
+    ``interpret`` argument is refused."""
+    import jax
     import repro.core.program as program
-    from repro.core.program import kernel_interpret, make_program_spmv_fn
+    from repro.core.program import build_program_step, kernel_interpret, \
+        make_program_spmv_fn
     assert kernel_interpret("cpu") is True
     assert kernel_interpret("tpu") is False
-    assert kernel_interpret("tpu", False) is False
-    with pytest.raises(ValueError, match="interpret=True"):
-        kernel_interpret("tpu", True)
-    with pytest.raises(ValueError, match="interpret=False"):
-        kernel_interpret("cpu", False)
     A = make_matrix("ford1", scale=0.05)
-    prog = lower(A, SpmvPlan(num_shards=1))
+    prog = lower(A, SpmvPlan(kernel="ell", num_shards=1))
     monkeypatch.setattr(program, "_mesh_platform", lambda mesh: "tpu")
-    with pytest.raises(ValueError, match="interpret=True"):
+    with pytest.raises(TypeError, match="interpret"):
         make_program_spmv_fn(prog, _mesh1(), use_kernel=True,
                              interpret=True)
+    with pytest.raises(TypeError, match="interpret"):
+        execute(prog, np.zeros(A.ncols), backend="shard_map",
+                mesh=_mesh1(), use_kernel=True, interpret=True)
+    seen = []
+
+    def ell_spy(data, cols, x, *, interpret):
+        seen.append(interpret)
+        return program.kops.ell_spmv_ref(data, cols, x)
+    monkeypatch.setattr(program.kops, "ell_spmv", ell_spy)
+    step, operands = build_program_step(prog, _mesh1(), use_kernel=True)
+    x = np.zeros((1, prog.x_layout.padded_length()), np.float32)
+    jax.eval_shape(step, *operands, x)
+    assert seen and not any(seen)
 
 
 @pytest.mark.parametrize("kernel", ["ell", "seg", "hyb", "split", "tile"])

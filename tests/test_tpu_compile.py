@@ -24,7 +24,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
 from repro.core.program import build_program_step, lower
 from repro.core.spmv import SpmvPlan
 from repro.data.matrices import make_matrix, mixed_structure
-from repro.kernels import spmv_ell, spmv_seg, spmv_split, spmv_tile
+from repro.kernels import ops, spmv_ell, spmv_seg, spmv_split, spmv_tile
 
 COP_N = 120_000                 # cop20k_A rows at scale 1.0
 AUDIKW_N = 943_000              # audikw_1 rows at scale 1.0
@@ -103,10 +103,11 @@ def test_kernel_family_compiles_for_v5e(one_chip, case):
 
 
 def test_batched_ell_compiles_for_v5e(one_chip):
-    """The (N, B) request path vmaps the kernel over the right-hand sides."""
-    batched = jax.vmap(spmv_ell.ell_spmv, in_axes=(None, None, 1),
-                       out_axes=1)
-    _, hlo = _compile(batched, _spec(one_chip, (COP_N, 128)),
+    """The ops-level multi-RHS ELL call (``ops.ell_spmv`` on an (N, B)
+    block) vmaps the kernel over the right-hand sides.  The executor does
+    not take this path (it loops over the vectors); direct callers of the
+    ops do."""
+    _, hlo = _compile(ops.ell_spmv, _spec(one_chip, (COP_N, 128)),
                       _spec(one_chip, (COP_N, 128), jnp.int32),
                       _spec(one_chip, (COP_N, 8)))
     assert "tpu_custom_call" in hlo
@@ -155,5 +156,5 @@ def test_executor_step_interpret_true_is_refused(topo):
     A = make_matrix("ford1", scale=0.05)
     prog = lower(A, SpmvPlan(num_shards=1))
     mesh = Mesh(np.array(topo.devices[:1]), ("model",))
-    with pytest.raises(ValueError, match="interpret"):
+    with pytest.raises(TypeError, match="interpret"):
         build_program_step(prog, mesh, use_kernel=True, interpret=True)
