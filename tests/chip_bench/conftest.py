@@ -1,0 +1,8 @@
+"""The chip benchmark's CPU tests import it as the package ``chip_bench``
+from the root of the repository."""
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+if str(ROOT) not in sys.path:
+    sys.path.insert(0, str(ROOT))
