@@ -1,7 +1,6 @@
 """Benchmark entry point: one section per paper table/figure.
 
-Prints ``name,...`` CSV blocks.  The TPU roofline table (from the dry-run
-artifacts) is emitted by ``benchmarks.roofline`` when the JSON exists.
+Prints ``name,...`` CSV blocks.
 """
 from __future__ import annotations
 
@@ -17,7 +16,7 @@ def main() -> None:
 
     from . import (autotune_bench, bottleneck_bench, fig3_layout,
                    fig6_distribution, fig7_cv, fig8_residency, fig10_reorder,
-                   fig12_cache, hetero_bench, kernels_bench)
+                   fig12_cache, hetero_bench)
     sections = [
         ("Fig.3 cyclic-vs-block", fig3_layout.run),
         # fast=True keeps the all-sections sweep snappy; run the fig6/fig8
@@ -27,7 +26,6 @@ def main() -> None:
         ("Fig.8/11 residency", lambda: fig8_residency.run(fast=True)),
         ("Fig.10 reorderings (Emu)", fig10_reorder.run),
         ("Fig.12 reorderings (cache CPU)", fig12_cache.run),
-        ("kernel microbench", kernels_bench.run),
         ("Autotuner chosen-vs-best-static", autotune_bench.run),
         ("Per-shard program vs best global (hetero)",
          lambda: print(json.dumps(hetero_bench.run_hetero_bench(fast=True),
@@ -37,11 +35,6 @@ def main() -> None:
              bottleneck_bench.run_bottleneck_bench(scale=0.003, window=16),
              indent=2))),
     ]
-    try:
-        from . import roofline
-        sections.append(("TPU roofline (dry-run)", roofline.run))
-    except Exception:
-        pass
     failures = 0
     for title, fn in sections:
         print(f"# === {title} ===")

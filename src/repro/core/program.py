@@ -54,6 +54,7 @@ from .layout import VectorLayout, make_layout
 from .migration import TrafficReport, count_migrations, remote_access_matrix
 from .partition import Partition, make_partition
 from .reorder import reordering_permutation
+from .spans import span
 from .plan import split_meta
 from .sparse_matrix import CSRMatrix, ELL_LANE, ELL_SUBLANE, EllMatrix, \
     SegMatrix, SplitMatrix, TileMatrix, csr_to_ell
@@ -806,6 +807,17 @@ def _mesh_platform(mesh) -> str:
     return mesh.devices.flat[0].platform
 
 
+def _named(scope: str, fn):
+    """``fn`` traced under ``jax.named_scope(scope)``: its operations carry
+    the scope in their metadata, and a device trace can group them."""
+    import jax
+
+    def scoped(*args):
+        with jax.named_scope(scope):
+            return fn(*args)
+    return scoped
+
+
 def build_program_step(program: SpmvProgram, mesh, axis: str = "model", *,
                        use_kernel: bool = False, pipeline: bool = True):
     """The device executor's jitted step and its host operands.
@@ -890,8 +902,11 @@ def build_program_step(program: SpmvProgram, mesh, axis: str = "model", *,
                 td[0], txc[0], tbr[0], xv, num_rows=R,
                 use_kernel=use_kernel, interpret=interpret)
 
-        return jax.lax.switch(kid[0], (ell_branch, seg_branch, hyb_branch,
-                                       split_branch, tile_branch), None)
+        # Each branch runs under its family's name (PROGRAM_KERNELS order).
+        return jax.lax.switch(kid[0], [
+            _named(k, b) for k, b in zip(PROGRAM_KERNELS, (
+                ell_branch, seg_branch, hyb_branch, split_branch,
+                tile_branch))], None)
 
     def shard_fn(kid, led, lec, lorow, locol, loval, lsv, lsc, lsr, lsp,
                  ltd, ltxc, ltbr,
@@ -899,15 +914,16 @@ def build_program_step(program: SpmvProgram, mesh, axis: str = "model", *,
                  rtd, rtxc, rtbr,
                  send_idx, row_rem, x_shard):
         x_local = x_shard[0]                               # (per[, B])
-        if use_a2a:
-            to_send = jnp.take(x_local, send_idx[0], axis=0)   # (S, H[, B])
-            recv = jax.lax.all_to_all(to_send, axis, split_axis=0,
-                                      concat_axis=0, tiled=True)
-            xg = jnp.concatenate(
-                [x_local, recv.reshape((-1,) + recv.shape[2:])], axis=0)
-        else:
-            x_all = jax.lax.all_gather(x_local, axis)      # (S, per[, B])
-            xg = _to_global(x_all)
+        with jax.named_scope("spmv.exchange"):
+            if use_a2a:
+                to_send = jnp.take(x_local, send_idx[0], axis=0)
+                recv = jax.lax.all_to_all(to_send, axis, split_axis=0,
+                                          concat_axis=0, tiled=True)
+                xg = jnp.concatenate(
+                    [x_local, recv.reshape((-1,) + recv.shape[2:])], axis=0)
+            else:
+                x_all = jax.lax.all_gather(x_local, axis)  # (S, per[, B])
+                xg = _to_global(x_all)
 
         x_loc_in = x_local
         if not pipeline:
@@ -918,14 +934,17 @@ def build_program_step(program: SpmvProgram, mesh, axis: str = "model", *,
             # only the scheduling freedom differs.
             x_loc_in, _ = jax.lax.optimization_barrier((x_local, xg))
 
-        y_loc = kernel_pass(kid, led, lec, lorow, locol, loval, lsv, lsc,
-                            lsr, lsp, ltd, ltxc, ltbr, NS_loc, x_loc_in)
-        y_rem = kernel_pass(kid, red, rec, rorow, rocol, roval, rsv, rsc,
-                            rsr, rsp, rtd, rtxc, rtbr, NS_rem, xg)
-        m = row_rem[0]
-        if y_rem.ndim == 2:                                # batched (R, B)
-            m = m[:, None]
-        y = jnp.where(m, y_rem, y_loc)
+        y_loc = _named("spmv.local", kernel_pass)(
+            kid, led, lec, lorow, locol, loval, lsv, lsc, lsr, lsp, ltd,
+            ltxc, ltbr, NS_loc, x_loc_in)
+        y_rem = _named("spmv.remote", kernel_pass)(
+            kid, red, rec, rorow, rocol, roval, rsv, rsc, rsr, rsp, rtd,
+            rtxc, rtbr, NS_rem, xg)
+        with jax.named_scope("spmv.combine"):
+            m = row_rem[0]
+            if y_rem.ndim == 2:                            # batched (R, B)
+                m = m[:, None]
+            y = jnp.where(m, y_rem, y_loc)
         return y[None]
 
     n_ops = len(_OPERAND_KEYS)
@@ -937,7 +956,8 @@ def build_program_step(program: SpmvProgram, mesh, axis: str = "model", *,
 
 
 def make_program_spmv_fn(program: SpmvProgram, mesh, axis: str = "model", *,
-                         use_kernel: bool = False, pipeline: bool = True):
+                         use_kernel: bool = False, pipeline: bool = True,
+                         phases: dict | None = None):
     """THE device executor: one shard_map function for any lowered program.
 
     Returns ``f(x_shards) -> y_shards`` with ``x_shards`` of shape
@@ -973,14 +993,21 @@ def make_program_spmv_fn(program: SpmvProgram, mesh, axis: str = "model", *,
     ``use_kernel=True`` runs the Pallas kernels, compiled on the chip and
     interpreted on the CPU (:func:`kernel_interpret`); the default runs
     the pure-jnp oracles.
+
+    Building the operand sets and placing them are the spans
+    ``ingest.stack`` and ``ingest.place``, counted into ``phases`` where
+    it is given (:func:`repro.core.spans.span`).
     """
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
+    with span("ingest.stack", phases):
+        _device_operands(program)
     step, host_ops = build_program_step(
         program, mesh, axis, use_kernel=use_kernel, pipeline=pipeline)
     sharding = NamedSharding(mesh, P(axis))
-    operands = jax.device_put(host_ops, sharding)
+    with span("ingest.place", phases):
+        operands = jax.device_put(host_ops, sharding)
 
     def run(x_shards):
         return step(*operands, jax.device_put(x_shards, sharding))

@@ -38,6 +38,7 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
+import itertools
 import os
 import re
 import threading
@@ -50,6 +51,7 @@ from repro.core.artifacts import ArtifactError, load_program, save_program
 from repro.core.plan import PlanCache, PlanChoice, autotune, feature_key
 from repro.core.program import SpmvProgram, execute, gather_b, lower, \
     make_program_spmv_fn, scatter_x
+from repro.core.spans import add_to, span
 from repro.core.sparse_matrix import CSRMatrix
 from repro.core.spmv import SpmvPlan
 from repro.serve.rebalance import LoadMonitor, RebalanceConfig, \
@@ -79,23 +81,33 @@ class MicroBatchConfig:
 
 
 class _MicroBatcher:
-    """Leader/follower gatherer for one tenant (thread-safe)."""
+    """Leader/follower gatherer for one tenant (thread-safe).
+
+    Each wave is the host span ``spmv.wave`` (its sequence number, width
+    and the sequence numbers of its requests).  ``queue`` counts each
+    request's wait from its submit to the start of its wave: seconds
+    under ``"queue_s"``, requests under ``"queue_s#"``, and the longest
+    under ``"queue_max_s"``."""
 
     def __init__(self, cfg: MicroBatchConfig, compute):
         self.cfg = cfg
         self._compute = compute          # (N, B) ndarray, n_requests -> (M, B)
         self._lock = threading.Lock()
-        self._pending: list = []         # (x, slot, event)
+        self._pending: list = []         # (x, slot, event, req, submitted)
         self._leading = False
         self.batches = 0
         self.requests = 0
         self.widest = 0
+        self._waves = itertools.count()
+        self.queue: dict = {"queue_s": 0.0, "queue_s#": 0,
+                            "queue_max_s": 0.0}
 
     def submit(self, x: np.ndarray, timeout: float = 60.0) -> np.ndarray:
         evt = threading.Event()
         slot: dict = {}
         with self._lock:
-            self._pending.append((x, slot, evt))
+            self._pending.append((x, slot, evt, self.requests,
+                                  time.perf_counter()))
             self.requests += 1
             lead = not self._leading
             if lead:
@@ -123,9 +135,17 @@ class _MicroBatcher:
                 if not batch:
                     self._leading = False
                     break
+                start = time.perf_counter()
+                waits = [start - b[4] for b in batch]
+                add_to(self.queue, "queue_s", sum(waits), len(batch))
+                self.queue["queue_max_s"] = max(self.queue["queue_max_s"],
+                                                *waits)
+                wave = next(self._waves)
             try:
-                X = np.stack([b[0] for b in batch], axis=1)
-                Y = self._compute(X, len(batch))
+                with span("spmv.wave", wave=wave, width=len(batch),
+                          reqs=",".join(str(b[3]) for b in batch)):
+                    X = np.stack([b[0] for b in batch], axis=1)
+                    Y = self._compute(X, len(batch))
             except BaseException as err:
                 # Fail every waiter (drained and still-queued) rather than
                 # leaving followers blocked on a dead leader.
@@ -133,20 +153,21 @@ class _MicroBatcher:
                     batch += self._pending
                     self._pending.clear()
                     self._leading = False
-                for _, s, e in batch:
+                for _, s, e, *_ in batch:
                     s["err"] = err
                     e.set()
                 raise
             self.batches += 1
             self.widest = max(self.widest, len(batch))
-            for i, (_, s, e) in enumerate(batch):
+            for i, (_, s, e, *_) in enumerate(batch):
                 s["y"] = Y[:, i]
                 e.set()
         return slot["y"]
 
     def stats(self) -> dict:
-        return {"requests": self.requests, "batches": self.batches,
-                "widest": self.widest}
+        with self._lock:
+            return {"requests": self.requests, "batches": self.batches,
+                    "widest": self.widest, **self.queue}
 
 
 @dataclasses.dataclass
@@ -183,6 +204,32 @@ class IngestedMatrix:
     # Compiled device function of the served program (``device_fn.program``
     # is that program); None when the engine has no mesh.
     device_fn: object | None = None
+    # Span counters (``repro.core.spans``): the seconds and calls of each
+    # phase of this tenant's ingest, and of each stage of its served
+    # device blocks, the latter under ``counter_lock``.
+    ingest_phases_s: dict = dataclasses.field(default_factory=dict)
+    stages_s: dict = dataclasses.field(default_factory=dict)
+    counter_lock: threading.Lock = dataclasses.field(
+        default_factory=threading.Lock)
+
+
+def _device_block(m: IngestedMatrix, fn, x: np.ndarray) -> np.ndarray:
+    """One block through the device function ``fn``, each host stage a
+    span counted into the tenant's ``stages_s``: the permute and split of
+    x (``spmv.scatter_x``), its copy to the device and the step's
+    dispatch (``spmv.put``), the step itself (``spmv.wait``), and the copy
+    back with the unpadding and unpermute (``spmv.gather_b``)."""
+    import jax
+
+    into, lock = m.stages_s, m.counter_lock
+    with span("spmv.scatter_x", into, lock):
+        xs = scatter_x(fn.program, x)
+    with span("spmv.put", into, lock):
+        y = fn(xs)
+    with span("spmv.wait", into, lock):
+        y = jax.block_until_ready(y)
+    with span("spmv.gather_b", into, lock):
+        return gather_b(fn.program, y)
 
 
 class SparseMatrixEngine:
@@ -218,6 +265,16 @@ class SparseMatrixEngine:
     overridable per ingest) watches each tenant's request mix and swaps
     validated re-plans in double-buffered (``serve/rebalance.py``); a
     swap rewrites the tenant's artifact so restarts resume the new plan.
+
+    The ingest's phases (``ingest.plan``, ``ingest.lower``,
+    ``ingest.stack``, ``ingest.place``), each served block
+    (``spmv.request``, its stages ``spmv.scatter_x``, ``spmv.put``,
+    ``spmv.wait``, ``spmv.gather_b``) and each micro-batch wave
+    (``spmv.wave``) are host spans (:mod:`repro.core.spans`): a
+    ``jax.profiler`` trace of the serving process holds them beside the
+    device's operations, and ``stats()`` gives each tenant's running
+    seconds and counts of them (``ingest_phases_s``, ``stages_s``, and
+    the queue wait in ``micro_batch``).
     """
 
     def __init__(self, *, mesh=None, num_shards: int | None = None,
@@ -260,6 +317,7 @@ class SparseMatrixEngine:
         #: tenant's traffic share, which scales the amortization horizon
         #: the re-plan gate sees (``RebalanceConfig.amortization_lookahead``).
         self.total_requests = 0
+        self._block_ids = itertools.count()    # ``req`` of ``spmv.request``
 
     # -- ingest ------------------------------------------------------------
 
@@ -322,37 +380,42 @@ class SparseMatrixEngine:
         elif rebalance is False:
             rebalance = None
 
-        warm = None if plan is not None else self._warm_ingest(name, csr)
+        phases: dict = {}
         cache_hit = False
         bundle = None
+        with span("ingest.plan", phases):
+            warm = None if plan is not None else \
+                self._warm_ingest(name, csr)
+            if warm is None:
+                features = extract_features(csr, num_shards=self.num_shards)
+                cache_key = (feature_key(features), self.num_shards)
+                if plan is None and self._plan_cache is not None:
+                    cached = self._plan_cache.get(cache_key)
+                    if cached is not None:
+                        plan = cached
+                        cache_hit = True
+                        self.plan_cache_hits += 1
+                if plan is None:
+                    choice = autotune(csr, num_shards=self.num_shards,
+                                      seed=self.seed, probe=self.probe)
+                    if self._plan_cache is not None:
+                        self._plan_cache.put(cache_key, choice.plan)
+                else:
+                    # retarget (not replace): a per-shard kernel tuple
+                    # tuned for a different shard count is dropped rather
+                    # than kept unlowerable.
+                    plan = plan.retarget(self.num_shards)
+                    choice = PlanChoice(
+                        features=features,
+                        ranking=(RankedPlan(
+                            plan=plan, cost=oracle.plan_cost(csr, plan)),),
+                        probed=0, bottleneck=oracle.classify(features))
         if warm is not None:
             dist, choice, bundle = warm
             self.warm_starts += 1
         else:
-            features = extract_features(csr, num_shards=self.num_shards)
-            cache_key = (feature_key(features), self.num_shards)
-            if plan is None and self._plan_cache is not None:
-                cached = self._plan_cache.get(cache_key)
-                if cached is not None:
-                    plan = cached
-                    cache_hit = True
-                    self.plan_cache_hits += 1
-            if plan is None:
-                choice = autotune(csr, num_shards=self.num_shards,
-                                  seed=self.seed, probe=self.probe)
-                if self._plan_cache is not None:
-                    self._plan_cache.put(cache_key, choice.plan)
-            else:
-                # retarget (not replace): a per-shard kernel tuple tuned
-                # for a different shard count is dropped rather than kept
-                # unlowerable.
-                plan = plan.retarget(self.num_shards)
-                choice = PlanChoice(
-                    features=features,
-                    ranking=(RankedPlan(plan=plan,
-                                        cost=oracle.plan_cost(csr, plan)),),
-                    probed=0, bottleneck=oracle.classify(features))
-            dist = lower(csr, choice.plan)
+            with span("ingest.lower", phases):
+                dist = lower(csr, choice.plan)
             if self.artifact_dir is not None:
                 bundle = self._bundle_dir(name)
                 try:
@@ -367,7 +430,7 @@ class SparseMatrixEngine:
             csr=csr if monitor is not None else None,
             plan_cache_hit=cache_hit, warm_start=warm is not None,
             bundle_dir=bundle, rebalance_cfg=rebalance, monitor=monitor,
-            device_fn=self._device_fn(dist))
+            device_fn=self._device_fn(dist, phases), ingest_phases_s=phases)
         if self.micro_batch is not None:
             m.batcher = _MicroBatcher(
                 self.micro_batch,
@@ -386,19 +449,22 @@ class SparseMatrixEngine:
                 f"engine.ingest({name!r}, csr) first")
         return m
 
-    def _device_fn(self, dist: SpmvProgram):
-        """The compiled device function serving ``dist`` (None: no mesh)."""
+    def _device_fn(self, dist: SpmvProgram, phases: dict | None = None):
+        """The compiled device function serving ``dist`` (None: no mesh);
+        its ingest phases are counted into ``phases``."""
         if self.mesh is None:
             return None
-        return make_program_spmv_fn(dist, self.mesh, axis=MESH_AXIS)
+        return make_program_spmv_fn(dist, self.mesh, axis=MESH_AXIS,
+                                    phases=phases)
 
     def _serve_block(self, m: IngestedMatrix, x: np.ndarray,
                      n_requests: int = 1) -> np.ndarray:
         fn = m.device_fn                 # one read: fn and its program agree
-        if fn is None:
-            y = execute(m.dist, x)
-        else:
-            y = gather_b(fn.program, fn(scatter_x(fn.program, x)))
+        with span("spmv.request", tenant=m.name, req=next(self._block_ids)):
+            if fn is None:
+                y = execute(m.dist, x)
+            else:
+                y = _device_block(m, fn, x)
         m.spmv_count += n_requests
         self.total_requests += n_requests
         if m.monitor is not None and m.monitor.observe(x):
@@ -544,5 +610,8 @@ class SparseMatrixEngine:
                 s["micro_batch"] = m.batcher.stats()
             if m.device_fn is not None:
                 s["device_operand_bytes"] = m.device_fn.operand_bytes
+            s["ingest_phases_s"] = dict(m.ingest_phases_s)
+            with m.counter_lock:
+                s["stages_s"] = dict(m.stages_s)
             out[n] = s
         return out
