@@ -734,21 +734,28 @@ def _device_operands(program: SpmvProgram) -> dict:
     ``x_local`` positions; the remote set's ids target the exchange
     buffer (``[x_local ++ recv]`` for any program with a halo reader,
     the gathered global x for uniform all-gather).
+
+    A program in which no row of any shard reads a remote entry (every
+    one-shard program, and block-diagonal ones under their layout) has
+    an empty remote slice: it gets the local set alone, with no
+    ``rem_*``, ``send_idx`` or ``row_remote``.  ``passes`` is the number
+    of kernel passes the step runs per vector (1 or 2).
     """
     cached = getattr(program, "_device_ops_cache", None)
     if cached is not None:
         return cached
     S = program.plan.num_shards
     stages = program.stages
-    policies = program.plan.resolved_shard_exchanges()
-    use_a2a = any(e == "halo" for e in policies)
     lay = program.x_layout
+    R = int(max(round_up(max(st.rows, 1), ELL_SUBLANE) for st in stages))
+    flags = _row_remote_flags(program)
+    remote = bool(flags.any())
+    use_a2a = any(e == "halo" for e in program.plan.resolved_shard_exchanges())
 
-    if use_a2a:
-        send_idx, pos_map, H = _halo_tables(program)
+    if remote and use_a2a:
+        send_idx, pos_map, _ = _halo_tables(program)
     else:
         send_idx = np.zeros((S, 1, 1), dtype=np.int32)
-        pos_map, H = None, 0
 
     def remap_rem(cols, vals, p):
         if not use_a2a:
@@ -761,8 +768,6 @@ def _device_operands(program: SpmvProgram) -> dict:
         out = lay.local_index(cols).astype(np.int32)
         return np.where(vals != 0, out, 0).astype(np.int32)
 
-    R = int(max(round_up(max(st.rows, 1), ELL_SUBLANE) for st in stages))
-    flags = _row_remote_flags(program)
     row_remote = np.zeros((S, R), dtype=bool)
     loc_stages, rem_stages = [], []
     kid = np.zeros(S, dtype=np.int32)
@@ -772,13 +777,16 @@ def _device_operands(program: SpmvProgram) -> dict:
         row_remote[p, : st.rows] = rr
         sub = program.partition.shard_csr(program.matrix, p)
         loc_stages.append(_masked_stage(sub, ~rr, st))
-        rem_stages.append(_masked_stage(sub, rr, st))
+        if remote:
+            rem_stages.append(_masked_stage(sub, rr, st))
     loc = _stack_stages(loc_stages, R, remap_loc)
-    rem = _stack_stages(rem_stages, R, remap_rem)
-    cached = dict(kid=kid, send_idx=send_idx, row_remote=row_remote,
-                  R=R, halo_H=H, NS_loc=loc.pop("NS"), NS_rem=rem.pop("NS"))
+    cached = dict(kid=kid, R=R, passes=1, NS_loc=loc.pop("NS"))
     cached.update({"loc_" + k: v for k, v in loc.items()})
-    cached.update({"rem_" + k: v for k, v in rem.items()})
+    if remote:
+        rem = _stack_stages(rem_stages, R, remap_rem)
+        cached.update(passes=2, NS_rem=rem.pop("NS"), send_idx=send_idx,
+                      row_remote=row_remote)
+        cached.update({"rem_" + k: v for k, v in rem.items()})
     program._device_ops_cache = cached
     return cached
 
@@ -787,10 +795,15 @@ _SET_KEYS = ("ell_data", "ell_cols", "ovf_rows", "ovf_cols", "ovf_vals",
              "seg_vals", "seg_cols", "seg_rows", "seg_pieces",
              "tile_data", "tile_xcol", "tile_brow")
 
-_OPERAND_KEYS = (("kid",)
-                 + tuple("loc_" + k for k in _SET_KEYS)
-                 + tuple("rem_" + k for k in _SET_KEYS)
+
+def _operand_keys(ops: dict) -> tuple:
+    """The step's operand names in argument order: the local set, then,
+    for a two-pass program, the remote set and the exchange tables."""
+    keys = ("kid",) + tuple("loc_" + k for k in _SET_KEYS)
+    if ops["passes"] == 2:
+        keys += (tuple("rem_" + k for k in _SET_KEYS)
                  + ("send_idx", "row_remote"))
+    return keys
 
 
 def kernel_interpret(platform: str) -> bool:
@@ -824,8 +837,10 @@ def build_program_step(program: SpmvProgram, mesh, axis: str = "model", *,
 
     Returns ``(step, operands)``: ``step(*operands, x_shards)`` is the one
     jitted ``shard_map`` program, and ``operands`` are the host arrays in
-    ``_OPERAND_KEYS`` order, each with the shard axis first.  Nothing is
-    placed on a device here, so the step can be lowered from shapes alone
+    ``_operand_keys`` order, each with the shard axis first.  A program
+    with no remote reads steps through its local slice alone: no
+    exchange, no remote pass, no combine.  Nothing is placed on a device
+    here, so the step can be lowered from shapes alone
     (``step.lower(*shape_structs)``) for a mesh of described devices.
     :func:`make_program_spmv_fn` is the serving wrapper around it.
     """
@@ -845,7 +860,7 @@ def build_program_step(program: SpmvProgram, mesh, axis: str = "model", *,
     interpret = kernel_interpret(_mesh_platform(mesh))
     ops = _device_operands(program)
     R = ops["R"]
-    NS_loc, NS_rem = ops["NS_loc"], ops["NS_rem"]
+    keys = _operand_keys(ops)
     policies = program.plan.resolved_shard_exchanges()
     use_a2a = any(e == "halo" for e in policies)
     kind = program.x_layout.kind
@@ -908,12 +923,18 @@ def build_program_step(program: SpmvProgram, mesh, axis: str = "model", *,
                 ell_branch, seg_branch, hyb_branch, split_branch,
                 tile_branch))], None)
 
-    def shard_fn(kid, led, lec, lorow, locol, loval, lsv, lsc, lsr, lsp,
-                 ltd, ltxc, ltbr,
-                 red, rec, rorow, rocol, roval, rsv, rsc, rsr, rsp,
-                 rtd, rtxc, rtbr,
-                 send_idx, row_rem, x_shard):
+    n_set = len(_SET_KEYS)
+
+    def shard_fn(kid, *args):
+        # args: the local set, [the remote set, send_idx, row_remote,] x
+        *args, x_shard = args
+        loc, rem = args[:n_set], args[n_set:2 * n_set]
         x_local = x_shard[0]                               # (per[, B])
+        if ops["passes"] == 1:          # no remote reads: y is y_loc
+            y = _named("spmv.local", kernel_pass)(
+                kid, *loc, ops["NS_loc"], x_local)
+            return y[None]
+        send_idx, row_rem = args[2 * n_set:]
         with jax.named_scope("spmv.exchange"):
             if use_a2a:
                 to_send = jnp.take(x_local, send_idx[0], axis=0)
@@ -935,11 +956,9 @@ def build_program_step(program: SpmvProgram, mesh, axis: str = "model", *,
             x_loc_in, _ = jax.lax.optimization_barrier((x_local, xg))
 
         y_loc = _named("spmv.local", kernel_pass)(
-            kid, led, lec, lorow, locol, loval, lsv, lsc, lsr, lsp, ltd,
-            ltxc, ltbr, NS_loc, x_loc_in)
+            kid, *loc, ops["NS_loc"], x_loc_in)
         y_rem = _named("spmv.remote", kernel_pass)(
-            kid, red, rec, rorow, rocol, roval, rsv, rsc, rsr, rsp, rtd,
-            rtxc, rtbr, NS_rem, xg)
+            kid, *rem, ops["NS_rem"], xg)
         with jax.named_scope("spmv.combine"):
             m = row_rem[0]
             if y_rem.ndim == 2:                            # batched (R, B)
@@ -947,12 +966,11 @@ def build_program_step(program: SpmvProgram, mesh, axis: str = "model", *,
             y = jnp.where(m, y_rem, y_loc)
         return y[None]
 
-    n_ops = len(_OPERAND_KEYS)
     # check_vma off: pallas_call has no replication rule.
     fn = jax.shard_map(shard_fn, mesh=mesh,
-                       in_specs=(P(axis),) * (n_ops + 1),
+                       in_specs=(P(axis),) * (len(keys) + 1),
                        out_specs=P(axis), check_vma=False)
-    return jax.jit(fn), tuple(ops[k] for k in _OPERAND_KEYS)
+    return jax.jit(fn), tuple(ops[k] for k in keys)
 
 
 def make_program_spmv_fn(program: SpmvProgram, mesh, axis: str = "model", *,
@@ -967,8 +985,9 @@ def make_program_spmv_fn(program: SpmvProgram, mesh, axis: str = "model", *,
     or use :func:`gather_b`).  The mesh must have ``plan.num_shards``
     devices along ``axis``; the program's operands are placed on them
     once, split along the shard axis, here.  ``f.program`` is the program
-    it serves, ``f.operands`` its placed device operands and
-    ``f.operand_bytes`` their bytes.
+    it serves, ``f.operands`` its placed device operands,
+    ``f.operand_bytes`` their bytes and ``f.passes`` the kernel passes it
+    runs per vector.
 
     The exchange prologue
     follows ``plan.resolved_shard_exchanges()``: uniform all-gather when
@@ -988,7 +1007,9 @@ def make_program_spmv_fn(program: SpmvProgram, mesh, axis: str = "model", *,
     ``optimization_barrier`` that ties the local pass's input to the
     completed exchange — the pre-pipeline serial order, bitwise-equal
     output by construction (identical operands and combine, scheduling
-    freedom removed).
+    freedom removed).  A program in which no row reads a remote entry
+    has no remote slice (:func:`_device_operands`): it runs one pass,
+    with no exchange, and ``pipeline`` has nothing to order in it.
 
     ``use_kernel=True`` runs the Pallas kernels, compiled on the chip and
     interpreted on the CPU (:func:`kernel_interpret`); the default runs
@@ -1015,6 +1036,7 @@ def make_program_spmv_fn(program: SpmvProgram, mesh, axis: str = "model", *,
     run.program = program
     run.operands = operands
     run.operand_bytes = int(sum(a.nbytes for a in host_ops))
+    run.passes = _device_operands(program)["passes"]
     return run
 
 

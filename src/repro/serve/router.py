@@ -610,6 +610,7 @@ class SparseMatrixEngine:
                 s["micro_batch"] = m.batcher.stats()
             if m.device_fn is not None:
                 s["device_operand_bytes"] = m.device_fn.operand_bytes
+                s["device_passes"] = m.device_fn.passes
             s["ingest_phases_s"] = dict(m.ingest_phases_s)
             with m.counter_lock:
                 s["stages_s"] = dict(m.stages_s)

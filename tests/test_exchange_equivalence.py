@@ -19,7 +19,8 @@ import itertools
 import numpy as np
 import pytest
 
-from repro.core.program import _device_operands, _halo_tables, execute, lower
+from repro.core.program import _device_operands, _halo_tables, \
+    _row_remote_flags, execute, lower
 from repro.core.sparse_matrix import CSRMatrix, csr_from_coo, csr_matvec
 from repro.core.spmv import PLAN_EXCHANGES, PLAN_KERNELS, SpmvPlan
 from repro.data.matrices import mixed_structure
@@ -82,8 +83,14 @@ def _check_plan(A: CSRMatrix, plan: SpmvPlan, *, batch: bool = True) -> None:
     # the device operand split must cover every stored entry exactly once
     ops = _device_operands(prog)
     loc_nnz = sum(st.nnz for st in prog.stages)
-    assert ops["row_remote"].shape[0] == plan.num_shards
+    assert ops["kid"].shape[0] == plan.num_shards
     assert loc_nnz == A.nnz
+    # a remote slice (and its selector) exists iff some row reads remotely
+    remote = bool(_row_remote_flags(prog).any())
+    assert ops["passes"] == 1 + remote
+    assert ("row_remote" in ops) == remote
+    if remote:
+        assert ops["row_remote"].shape[0] == plan.num_shards
     _exchange_buffer_invariant(prog)
 
 

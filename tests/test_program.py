@@ -413,6 +413,84 @@ def test_pipelined_executor_bitwise_equals_serial_4dev_subprocess():
     assert all(res.values()), res
 
 
+_SUBPROC_PASSES = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import json, re
+    import jax, numpy as np
+    from repro.core.program import _device_operands, build_program_step, \\
+        execute, lower, make_program_spmv_fn
+    from repro.core.sparse_matrix import csr_from_coo, csr_matvec
+    from repro.core.spmv import SpmvPlan
+    from repro.data.matrices import make_matrix
+    from jax.sharding import AxisType
+
+    mesh = jax.make_mesh((4,), ("model",), axis_types=(AxisType.Auto,))
+    # block diagonal, one 128-row block per shard under an even row split
+    rng = np.random.default_rng(0)
+    blk, r, c = (rng.integers(0, k, 3000) for k in (4, 128, 128))
+    A_bd = csr_from_coo(blk * 128 + r, blk * 128 + c,
+                        rng.standard_normal(3000), (512, 512))
+    A_cop = make_matrix("cop20k_A", scale=0.003)
+    cases = {
+        "halo": (A_cop, SpmvPlan(num_shards=4, exchange="halo",
+                                 kernel="hyb")),
+        "allgather": (A_cop, SpmvPlan(num_shards=4, exchange="allgather",
+                                      kernel="hyb")),
+        "block_diagonal": (A_bd, SpmvPlan(num_shards=4, exchange="halo",
+                                          kernel="seg", distribution="row")),
+    }
+    out = {}
+    for name, (A, plan) in cases.items():
+        prog = lower(A, plan)
+        fn = make_program_spmv_fn(prog, mesh)
+        ops = _device_operands(prog)
+        step, host_ops = build_program_step(prog, mesh)
+        xs = np.zeros((4, prog.x_layout.padded_length() // 4), np.float32)
+        text = step.lower(*host_ops, xs).as_text(debug_info=True)
+        x = np.random.default_rng(5).standard_normal(A.ncols) \\
+            .astype(np.float32)
+        y = np.asarray(execute(prog, x, backend="shard_map", mesh=mesh))
+        y_ser = np.asarray(execute(prog, x, backend="shard_map", mesh=mesh,
+                                   pipeline=False))
+        out[name] = dict(
+            passes=fn.passes,
+            rem_keys=sum(k.startswith("rem_") for k in ops),
+            tables=sorted(k for k in ("send_idx", "row_remote") if k in ops),
+            collectives=sorted(set(re.findall(
+                r"stablehlo\\.(all_to_all|all_gather)", text))),
+            scopes=[s for s in ("spmv.exchange", "spmv.local",
+                                "spmv.remote", "spmv.combine") if s in text],
+            matches=bool(np.allclose(y, csr_matvec(A, x), atol=1e-3,
+                                     rtol=1e-4)),
+            pipelined_equals_serial=bool(np.array_equal(y, y_ser)))
+    print(json.dumps(out))
+""")
+
+
+@pytest.mark.slow
+def test_remote_reads_decide_the_kernel_passes_4dev_subprocess():
+    """On four devices a program with remote reads keeps both slices, its
+    remote operand set, the exchange and the combine; a block-diagonal
+    program, whose rows read only their own shard's x, runs one pass with
+    no collective."""
+    r = subprocess.run([sys.executable, "-c", _SUBPROC_PASSES],
+                       capture_output=True, text=True, timeout=600,
+                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"})
+    assert r.returncode == 0, r.stderr[-2000:]
+    res = json.loads(r.stdout.strip().splitlines()[-1])
+    two = ["spmv.exchange", "spmv.local", "spmv.remote", "spmv.combine"]
+    for name, collective in (("halo", "all_to_all"),
+                             ("allgather", "all_gather")):
+        assert res[name] == dict(
+            passes=2, rem_keys=12, tables=["row_remote", "send_idx"],
+            collectives=[collective], scopes=two, matches=True,
+            pipelined_equals_serial=True), name
+    assert res["block_diagonal"] == dict(
+        passes=1, rem_keys=0, tables=[], collectives=[],
+        scopes=["spmv.local"], matches=True, pipelined_equals_serial=True)
+
+
 # --------------------------------------------------------------------------
 # the device executor on a 1-device mesh (in-process; interpret mode)
 # --------------------------------------------------------------------------
@@ -493,3 +571,37 @@ def test_single_device_executor_matches_oracle(kernel, reordering):
     np.testing.assert_allclose(
         execute(prog, x, backend="shard_map", mesh=_mesh1()),
         csr_matvec(A, x), atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("kernel", ["ell", "seg", "hyb", "split", "tile"])
+def test_one_shard_programs_run_one_kernel_pass(kernel):
+    """One shard reads no remote x entry, so the step gets the local
+    operand set alone and one pass: no remote set, exchange tables,
+    exchange, remote slice or combine, and the same answers."""
+    from repro.core.program import _SET_KEYS, _device_operands, \
+        build_program_step, gather_b, make_program_spmv_fn, scatter_x
+    A = make_matrix("cop20k_A", scale=0.003)
+    prog = lower(A, SpmvPlan(kernel=kernel, num_shards=1))
+    fn = make_program_spmv_fn(prog, _mesh1())
+    ops = _device_operands(prog)
+    assert fn.passes == ops["passes"] == 1
+    assert not any(k.startswith("rem_") for k in ops)
+    assert "send_idx" not in ops and "row_remote" not in ops
+    assert fn.operand_bytes == ops["kid"].nbytes + sum(
+        ops["loc_" + k].nbytes for k in _SET_KEYS)
+    step, host_ops = build_program_step(prog, _mesh1())
+    xs = np.zeros((1, prog.x_layout.padded_length()), np.float32)
+    text = step.lower(*host_ops, xs).as_text(debug_info=True)
+    assert "spmv.local" in text
+    for absent in ("spmv.exchange", "spmv.remote", "spmv.combine",
+                   "all_gather", "all_to_all"):
+        assert absent not in text, absent
+    rng = np.random.default_rng(12)
+    for v in (rng.standard_normal(A.ncols),
+              rng.standard_normal((A.ncols, 3))):
+        y = gather_b(prog, fn(scatter_x(prog, v)))
+        np.testing.assert_allclose(y, csr_matvec(A, v), atol=1e-4,
+                                   rtol=1e-4)
+        y_ser = execute(prog, v, backend="shard_map", mesh=_mesh1(),
+                        pipeline=False)
+        np.testing.assert_array_equal(y, y_ser)

@@ -359,6 +359,7 @@ def test_device_engine_serves_within_tolerance(name, scale):
     assert Y.shape == (A.nrows, 4)
     _assert_within_smoke_tol(A, X, Y)
     assert eng.stats()["a"]["device_operand_bytes"] == fn.operand_bytes > 0
+    assert eng.stats()["a"]["device_passes"] == fn.passes == 1  # one shard
     assert eng.device_fn("a") is fn          # built once, not per request
 
 

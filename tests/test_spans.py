@@ -1,7 +1,9 @@
 """The program's spans and counters: ``repro.core.spans.span`` itself, the
 engine's ingest phases, served-block stages and micro-batch queue wait,
 and the named scopes of the device step."""
+import subprocess
 import sys
+import textwrap
 import threading
 import time
 
@@ -157,19 +159,46 @@ def test_the_micro_batch_queue_wait_counts_every_request():
     assert s["stages_s"]["spmv.wait#"] == mb["batches"]
 
 
-def test_the_device_step_names_its_slices_and_families():
-    """The lowered step's op metadata carries the exchange, the two
-    slices, the combine and the kernel family of each switch branch."""
+_LOWERED_STEP = textwrap.dedent("""
+    import os, sys
+    S = int(sys.argv[1])
+    os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={S}"
+    import jax, numpy as np
+    from jax.sharding import AxisType
     from repro.core.program import build_program_step, lower
     from repro.core.spmv import SpmvPlan
+    from repro.data.matrices import make_matrix
 
-    A = make_matrix("cop20k_A", scale=0.002)
-    prog = lower(A, SpmvPlan(kernel="hyb", distribution="row",
-                             num_shards=1))
-    step, ops = build_program_step(prog, _mesh1())
-    xs = np.zeros((1, prog.x_layout.padded_length()), np.float32)
-    text = step.lower(*ops, xs).as_text(debug_info=True)
-    for scope in ("spmv.exchange", "spmv.local", "spmv.remote",
-                  "spmv.combine", "spmv.remote/cond/branch_2_fun/hyb",
-                  "spmv.local/cond/branch_1_fun/seg"):
-        assert scope in text, scope
+    mesh = jax.make_mesh((S,), ("model",), axis_types=(AxisType.Auto,))
+    prog = lower(make_matrix("cop20k_A", scale=0.002),
+                 SpmvPlan(kernel="hyb", distribution="row", num_shards=S))
+    step, ops = build_program_step(prog, mesh)
+    xs = np.zeros((S, prog.x_layout.padded_length() // S), np.float32)
+    sys.stdout.write(step.lower(*ops, xs).as_text(debug_info=True))
+""")
+
+
+@pytest.mark.parametrize("shards,present,absent", [
+    # one shard reads no remote entry: the local slice alone
+    (1, ("spmv.local", "spmv.local/cond/branch_1_fun/seg",
+         "spmv.local/cond/branch_2_fun/hyb"),
+     ("spmv.exchange", "spmv.remote", "spmv.combine")),
+    # four shards of a banded matrix read their neighbours' entries
+    (4, ("spmv.exchange", "spmv.local", "spmv.remote", "spmv.combine",
+         "spmv.remote/cond/branch_2_fun/hyb",
+         "spmv.local/cond/branch_1_fun/seg"), ()),
+], ids=["one_shard", "remote_reads_4dev"])
+def test_the_device_step_names_its_slices_and_families(shards, present,
+                                                       absent):
+    """The lowered step's op metadata carries each slice it runs, the
+    exchange and the combine where there are remote reads, and the
+    kernel family of each switch branch.  Lowered on ``shards`` virtual
+    devices in a subprocess, so they never leak into this session."""
+    r = subprocess.run([sys.executable, "-c", _LOWERED_STEP, str(shards)],
+                       capture_output=True, text=True, timeout=300,
+                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"})
+    assert r.returncode == 0, r.stderr[-2000:]
+    for scope in present:
+        assert scope in r.stdout, scope
+    for scope in absent:
+        assert scope not in r.stdout, scope
