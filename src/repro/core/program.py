@@ -476,7 +476,23 @@ def _execute_numpy_block(program: SpmvProgram, x: np.ndarray) -> np.ndarray:
 # make_spmv_fn / make_seg_spmv_fn / make_halo_spmv_fn collapse to this)
 # --------------------------------------------------------------------------
 
-def _halo_tables(program: SpmvProgram):
+def _remote_reads(program: SpmvProgram) -> tuple:
+    """The stored non-zeros that read a remote x entry under the
+    program's layout (zero-valued entries excluded: they contribute
+    nothing).  Returns ``(rows, keys)``: each such entry's row, and the
+    sorted distinct ``reader * ncols + col`` keys of the (reader shard,
+    global column) pairs they read."""
+    A, part, lay = program.matrix, program.partition, program.x_layout
+    rows_of_nnz = np.repeat(np.arange(A.nrows), np.diff(A.row_ptr))
+    home = part.owner_of_rows(A.nrows)[rows_of_nnz]
+    owners = lay.owner_of(A.col_index)
+    rem = (A.values != 0) & (owners != home)
+    keys = np.unique(home[rem].astype(np.int64) * A.ncols +
+                     A.col_index[rem].astype(np.int64))
+    return rows_of_nnz[rem], keys
+
+
+def _halo_tables(program: SpmvProgram, reads: tuple | None = None):
     """Structure-level exchange tables (format-independent, per policy).
 
     For a reader p with exchange policy ``"halo"``, shard q sends exactly
@@ -489,21 +505,16 @@ def _halo_tables(program: SpmvProgram):
     ``send_idx[q, p]`` are sender-local indices (padded to H) and
     ``pos_map[p, g]`` the augmented-buffer position of global id g on
     reader p (the buffer is ``[x_local ++ recv]``, ``per + q * H +
-    slot``).
+    slot``).  ``reads`` is :func:`_remote_reads` of the program, where
+    the caller has it already.
     """
     A, part, lay = program.matrix, program.partition, program.x_layout
     S = part.num_shards
     per = lay.padded_length() // S
     policies = program.plan.resolved_shard_exchanges()
-    rows_of_nnz = np.repeat(np.arange(A.nrows), np.diff(A.row_ptr))
-    home = part.owner_of_rows(A.nrows)[rows_of_nnz]
-    owners = lay.owner_of(A.col_index)
-    rem = (A.values != 0) & (owners != home)
+    _, uniq = _remote_reads(program) if reads is None else reads
     needed = [[np.zeros(0, np.int64)] * S for _ in range(S)]
-    if rem.any():
-        key = home[rem].astype(np.int64) * A.ncols + \
-            A.col_index[rem].astype(np.int64)
-        uniq = np.unique(key)             # sorted: per reader, by global id
+    if uniq.size:                         # sorted: per reader, by global id
         up, ucol = uniq // A.ncols, uniq % A.ncols
         uq = lay.owner_of(ucol)
         for p in range(S):
@@ -546,18 +557,17 @@ def _remap_cols(cols: np.ndarray, vals: np.ndarray, lay: VectorLayout,
     return out
 
 
-def _row_remote_flags(program: SpmvProgram) -> np.ndarray:
+def _row_remote_flags(program: SpmvProgram,
+                      reads: tuple | None = None) -> np.ndarray:
     """(nrows,) bool — rows with >= 1 stored non-zero reading a remote x
     entry under the program's layout.  These are the rows whose partial
     products must wait for the exchange; every other row is computable
-    from ``x_local`` alone (the pipelined executor's local slice)."""
-    A, part, lay = program.matrix, program.partition, program.x_layout
-    rows_of_nnz = np.repeat(np.arange(A.nrows), np.diff(A.row_ptr))
-    home = part.owner_of_rows(A.nrows)[rows_of_nnz]
-    owners = lay.owner_of(A.col_index)
-    rem = (A.values != 0) & (owners != home)
-    flags = np.zeros(A.nrows, dtype=bool)
-    flags[rows_of_nnz[rem]] = True
+    from ``x_local`` alone (the pipelined executor's local slice).
+    ``reads`` is :func:`_remote_reads` of the program, where the caller
+    has it already."""
+    rows, _ = _remote_reads(program) if reads is None else reads
+    flags = np.zeros(program.matrix.nrows, dtype=bool)
+    flags[rows] = True
     return flags
 
 
@@ -740,6 +750,15 @@ def _device_operands(program: SpmvProgram) -> dict:
     an empty remote slice: it gets the local set alone, with no
     ``rem_*``, ``send_idx`` or ``row_remote``.  ``passes`` is the number
     of kernel passes the step runs per vector (1 or 2).
+
+    ``exchange`` counts what the second pass adds, from the tables built
+    here: ``sent_entries``, the x entries one vector's collective moves,
+    padded as sent (S·S·H for the all-to-all, S·S·per for the
+    all-gather); ``needed_entries``, the distinct (reader shard, column)
+    pairs the stored non-zeros read remotely, unpadded; and per shard
+    ``remote_rows`` (rows with a remote read), ``remote_pass_rows`` (rows
+    the remote pass computes, ``R``) and ``shard_nnz`` (stored entries).
+    Every count of a one-pass program is 0.
     """
     cached = getattr(program, "_device_ops_cache", None)
     if cached is not None:
@@ -748,12 +767,13 @@ def _device_operands(program: SpmvProgram) -> dict:
     stages = program.stages
     lay = program.x_layout
     R = int(max(round_up(max(st.rows, 1), ELL_SUBLANE) for st in stages))
-    flags = _row_remote_flags(program)
+    reads = _remote_reads(program)
+    flags = _row_remote_flags(program, reads)
     remote = bool(flags.any())
     use_a2a = any(e == "halo" for e in program.plan.resolved_shard_exchanges())
 
     if remote and use_a2a:
-        send_idx, pos_map, _ = _halo_tables(program)
+        send_idx, pos_map, _ = _halo_tables(program, reads)
     else:
         send_idx = np.zeros((S, 1, 1), dtype=np.int32)
 
@@ -780,12 +800,23 @@ def _device_operands(program: SpmvProgram) -> dict:
         if remote:
             rem_stages.append(_masked_stage(sub, rr, st))
     loc = _stack_stages(loc_stages, R, remap_loc)
-    cached = dict(kid=kid, R=R, passes=1, NS_loc=loc.pop("NS"))
+    cached = dict(kid=kid, R=R, passes=1, NS_loc=loc.pop("NS"),
+                  exchange=dict(sent_entries=0, needed_entries=0,
+                                remote_rows=[0] * S,
+                                remote_pass_rows=[0] * S, shard_nnz=[0] * S))
     cached.update({"loc_" + k: v for k, v in loc.items()})
     if remote:
         rem = _stack_stages(rem_stages, R, remap_rem)
+        per = lay.padded_length() // S
         cached.update(passes=2, NS_rem=rem.pop("NS"), send_idx=send_idx,
-                      row_remote=row_remote)
+                      row_remote=row_remote, exchange=dict(
+                          sent_entries=int(send_idx.size) if use_a2a
+                          else S * S * per,
+                          needed_entries=int(reads[1].size),
+                          remote_rows=[int(n) for n in
+                                       row_remote.sum(axis=1)],
+                          remote_pass_rows=[R] * S,
+                          shard_nnz=[int(st.nnz) for st in stages]))
         cached.update({"rem_" + k: v for k, v in rem.items()})
     program._device_ops_cache = cached
     return cached
@@ -986,8 +1017,9 @@ def make_program_spmv_fn(program: SpmvProgram, mesh, axis: str = "model", *,
     devices along ``axis``; the program's operands are placed on them
     once, split along the shard axis, here.  ``f.program`` is the program
     it serves, ``f.operands`` its placed device operands,
-    ``f.operand_bytes`` their bytes and ``f.passes`` the kernel passes it
-    runs per vector.
+    ``f.operand_bytes`` their bytes, ``f.passes`` the kernel passes it
+    runs per vector and ``f.exchange`` the counters of what its second
+    pass adds (:func:`_device_operands`).
 
     The exchange prologue
     follows ``plan.resolved_shard_exchanges()``: uniform all-gather when
@@ -1036,7 +1068,9 @@ def make_program_spmv_fn(program: SpmvProgram, mesh, axis: str = "model", *,
     run.program = program
     run.operands = operands
     run.operand_bytes = int(sum(a.nbytes for a in host_ops))
-    run.passes = _device_operands(program)["passes"]
+    ops = _device_operands(program)
+    run.passes = ops["passes"]
+    run.exchange = ops["exchange"]
     return run
 
 

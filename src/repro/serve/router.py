@@ -36,6 +36,7 @@ per-vector calls.
 """
 from __future__ import annotations
 
+import copy
 import dataclasses
 import hashlib
 import itertools
@@ -611,6 +612,7 @@ class SparseMatrixEngine:
             if m.device_fn is not None:
                 s["device_operand_bytes"] = m.device_fn.operand_bytes
                 s["device_passes"] = m.device_fn.passes
+                s["exchange"] = copy.deepcopy(m.device_fn.exchange)
             s["ingest_phases_s"] = dict(m.ingest_phases_s)
             with m.counter_lock:
                 s["stages_s"] = dict(m.stages_s)
