@@ -622,13 +622,15 @@ def _stack_stages(stages, R: int, remap) -> dict:
     shapes.  Split stages flatten their (NS, Cs, L) slab into the shared
     seg (C, L) operand — the split structure travels in the piece table,
     widened to 5 columns [flat_chunk, lo, hi, row, split] (padded rows
-    [0, 1, 0, 0, 0] are an exact zero).  Tile stages expand their
-    per-tile block-column id into per-lane x positions (``tile_xcol``) —
-    the augmented exchange buffer has no block grid to index, so the
-    remap runs on the expanded lanes, with *nonzero lane occupancy* as
-    the remap values (dead / stored-zero-only lanes keep position 0 and
-    contribute exact zeros); padding tiles point their block row
-    (``tile_brow``) one past the last block so the scatter drops them.
+    [0, 1, 0, 0, 0] are an exact zero); ``seg_starts`` marks each
+    piece's first slot, where the jnp pass's scan restarts.  Tile stages
+    expand their per-tile block-column id into per-lane x positions
+    (``tile_xcol``) — the augmented exchange buffer has no block grid to
+    index, so the remap runs on the expanded lanes, with *nonzero lane
+    occupancy* as the remap values (dead / stored-zero-only lanes keep
+    position 0 and contribute exact zeros); padding tiles point their
+    block row (``tile_brow``) one past the last block so the scatter
+    drops them.
     ``remap(cols, vals, p)`` maps global column ids into the buffer this
     set's kernel pass reads.
     """
@@ -661,7 +663,7 @@ def _stack_stages(stages, R: int, remap) -> dict:
     ovf_vals = np.zeros((S, O), dtype=np.float32)
     seg_vals = np.zeros((S, C, L), dtype=np.float32)
     seg_cols = np.zeros((S, C, L), dtype=np.int32)
-    seg_rows = np.zeros((S, C, L), dtype=np.int32)
+    seg_starts = np.zeros((S, C, L), dtype=bool)
     seg_pieces = np.zeros((S, Pp, 5), dtype=np.int32)
     seg_pieces[:, :, 1] = 1           # (lo=1, hi=0, row=0, split=0) -> zero
     tiles = [st.tile for st in stages if st.tile is not None]
@@ -692,7 +694,8 @@ def _stack_stages(stages, R: int, remap) -> dict:
             s = st.seg
             seg_vals[p, : s.num_chunks] = s.vals
             seg_cols[p, : s.num_chunks] = remap(s.cols, s.vals, p)
-            seg_rows[p, : s.num_chunks] = s.rows
+            seg_starts[p, : s.num_chunks] = kops.piece_starts(
+                s.num_chunks, L, s.piece_chunk, s.piece_lo)
             n = s.n_pieces
             seg_pieces[p, :n, 0] = s.piece_chunk
             seg_pieces[p, :n, 1] = s.piece_lo
@@ -704,13 +707,14 @@ def _stack_stages(stages, R: int, remap) -> dict:
             fv = s.vals.reshape(ns * Cs, L)
             seg_vals[p, : ns * Cs] = fv
             seg_cols[p, : ns * Cs] = remap(s.cols.reshape(ns * Cs, L), fv, p)
-            seg_rows[p, : ns * Cs] = s.rows.reshape(ns * Cs, L)
             n = s.n_pieces
             seg_pieces[p, :n, 0] = s.piece_split * Cs + s.piece_chunk
             seg_pieces[p, :n, 1] = s.piece_lo
             seg_pieces[p, :n, 2] = s.piece_hi
             seg_pieces[p, :n, 3] = s.piece_row
             seg_pieces[p, :n, 4] = s.piece_split
+            seg_starts[p, : ns * Cs] = kops.piece_starts(
+                ns * Cs, L, seg_pieces[p, :n, 0], s.piece_lo)
         if st.tile is not None and st.tile.num_tiles:
             t = st.tile
             T = t.num_tiles
@@ -725,9 +729,25 @@ def _stack_stages(stages, R: int, remap) -> dict:
             tile_brow[p, :T] = t.tile_rows
     return dict(ell_data=ell_data, ell_cols=ell_cols, ovf_rows=ovf_rows,
                 ovf_cols=ovf_cols, ovf_vals=ovf_vals, seg_vals=seg_vals,
-                seg_cols=seg_cols, seg_rows=seg_rows, seg_pieces=seg_pieces,
+                seg_cols=seg_cols, seg_starts=seg_starts,
+                seg_pieces=seg_pieces,
                 tile_data=tile_data, tile_xcol=tile_xcol, tile_brow=tile_brow,
                 NS=NS)
+
+
+def _scatter_updates(st: ShardStage) -> int:
+    """Scatter-add updates one vector's pass over stage ``st`` runs: one
+    per piece for seg and split (the carry fix-up), one per overflow
+    entry for hyb, none for ell (rows sum along the lanes) and tile
+    (whole tile rows, not entries).  The padding that evens the shards'
+    shapes is not counted: it adds exact zeros."""
+    if st.seg is not None:
+        return st.seg.n_pieces
+    if st.split is not None:
+        return st.split.n_pieces
+    if st.kernel == "hyb":
+        return int(st.ell.overflow_vals.size)
+    return 0
 
 
 def _device_operands(program: SpmvProgram) -> dict:
@@ -759,6 +779,10 @@ def _device_operands(program: SpmvProgram) -> dict:
     ``remote_rows`` (rows with a remote read), ``remote_pass_rows`` (rows
     the remote pass computes, ``R``) and ``shard_nnz`` (stored entries).
     Every count of a one-pass program is 0.
+
+    ``kernels["scatter_updates"]`` counts, per shard, the scatter-add
+    updates one vector's step runs, summed over its passes
+    (:func:`_scatter_updates`).
     """
     cached = getattr(program, "_device_ops_cache", None)
     if cached is not None:
@@ -790,6 +814,7 @@ def _device_operands(program: SpmvProgram) -> dict:
 
     row_remote = np.zeros((S, R), dtype=bool)
     loc_stages, rem_stages = [], []
+    scatter = [0] * S
     kid = np.zeros(S, dtype=np.int32)
     for p, st in enumerate(stages):
         kid[p] = PROGRAM_KERNELS.index(st.kernel)
@@ -797,13 +822,16 @@ def _device_operands(program: SpmvProgram) -> dict:
         row_remote[p, : st.rows] = rr
         sub = program.partition.shard_csr(program.matrix, p)
         loc_stages.append(_masked_stage(sub, ~rr, st))
+        scatter[p] += _scatter_updates(loc_stages[-1])
         if remote:
             rem_stages.append(_masked_stage(sub, rr, st))
+            scatter[p] += _scatter_updates(rem_stages[-1])
     loc = _stack_stages(loc_stages, R, remap_loc)
     cached = dict(kid=kid, R=R, passes=1, NS_loc=loc.pop("NS"),
                   exchange=dict(sent_entries=0, needed_entries=0,
                                 remote_rows=[0] * S,
-                                remote_pass_rows=[0] * S, shard_nnz=[0] * S))
+                                remote_pass_rows=[0] * S, shard_nnz=[0] * S),
+                  kernels=dict(scatter_updates=scatter))
     cached.update({"loc_" + k: v for k, v in loc.items()})
     if remote:
         rem = _stack_stages(rem_stages, R, remap_rem)
@@ -823,7 +851,7 @@ def _device_operands(program: SpmvProgram) -> dict:
 
 
 _SET_KEYS = ("ell_data", "ell_cols", "ovf_rows", "ovf_cols", "ovf_vals",
-             "seg_vals", "seg_cols", "seg_rows", "seg_pieces",
+             "seg_vals", "seg_cols", "seg_starts", "seg_pieces",
              "tile_data", "tile_xcol", "tile_brow")
 
 
@@ -921,7 +949,7 @@ def build_program_step(program: SpmvProgram, mesh, axis: str = "model", *,
             return jax.lax.map(partial(vector_pass, *slabs), xv.T).T
         return vector_pass(*slabs, xv)
 
-    def vector_pass(kid, ed, ec, orow, ocol, oval, sv, sc, sr, sp,
+    def vector_pass(kid, ed, ec, orow, ocol, oval, sv, sc, ss, sp,
                     td, txc, tbr, ns, xv):
 
         def ell_branch(_):
@@ -930,9 +958,9 @@ def build_program_step(program: SpmvProgram, mesh, axis: str = "model", *,
         def seg_branch(_):
             pc = sp[0]
             return kops.seg_spmv(
-                (sv[0], sc[0], sr[0], pc[:, 0], pc[:, 1], pc[:, 2],
-                 pc[:, 3]), xv, num_rows=R,
-                use_kernel=use_kernel, interpret=interpret)
+                (sv[0], sc[0], ss[0], pc[:, 0], pc[:, 1], pc[:, 2],
+                 pc[:, 3]), xv, num_rows=R, use_kernel=use_kernel,
+                interpret=interpret)
 
         def hyb_branch(_):
             y = ell_op(ed[0], ec[0], xv)
@@ -940,7 +968,7 @@ def build_program_step(program: SpmvProgram, mesh, axis: str = "model", *,
 
         def split_branch(_):
             return kops.split_flat_spmv(
-                sv[0], sc[0], sr[0], sp[0], xv, num_rows=R, num_splits=ns,
+                sv[0], sc[0], ss[0], sp[0], xv, num_rows=R, num_splits=ns,
                 use_kernel=use_kernel, interpret=interpret)
 
         def tile_branch(_):
@@ -1018,8 +1046,9 @@ def make_program_spmv_fn(program: SpmvProgram, mesh, axis: str = "model", *,
     once, split along the shard axis, here.  ``f.program`` is the program
     it serves, ``f.operands`` its placed device operands,
     ``f.operand_bytes`` their bytes, ``f.passes`` the kernel passes it
-    runs per vector and ``f.exchange`` the counters of what its second
-    pass adds (:func:`_device_operands`).
+    runs per vector, ``f.exchange`` the counters of what its second
+    pass adds and ``f.kernel_counts`` the scatter-add updates each shard
+    runs a vector (:func:`_device_operands`).
 
     The exchange prologue
     follows ``plan.resolved_shard_exchanges()``: uniform all-gather when
@@ -1071,6 +1100,7 @@ def make_program_spmv_fn(program: SpmvProgram, mesh, axis: str = "model", *,
     ops = _device_operands(program)
     run.passes = ops["passes"]
     run.exchange = ops["exchange"]
+    run.kernel_counts = ops["kernels"]
     return run
 
 

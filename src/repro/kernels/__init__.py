@@ -26,8 +26,10 @@ Five kernel families, one per sparse format/work-distribution choice:
   (``bell_*``) is absorbed as a special case of this walk; its ops
   survive as warn-once deprecated shims.
 
-Every kernel has the same contract: pure-jnp oracle as the default
-execution path, ``use_kernel=True`` for the Pallas path (compiled on the
+Every kernel has the same contract: a pure-jnp default execution path
+(the oracle, except for seg and split, which serve a segmented chunk scan
+with one scatter update per piece and keep the slot-by-slot scatter-add
+as their oracle), ``use_kernel=True`` for the Pallas path (compiled on the
 TPU), and ``interpret=True`` to run the Pallas path on CPU; the device
 executor derives ``interpret`` from its mesh's platform.  The public API is
 re-exported here (from ``ops.py``), so callers write

@@ -1,8 +1,12 @@
-"""Jit'd public wrappers around the Pallas kernels (+ jnp oracles).
+"""Jit'd public wrappers around the Pallas kernels (+ jnp paths).
 
-The pure-jnp oracle is the default execution path; ``use_kernel=True``
+The pure-jnp path is the default execution path; ``use_kernel=True``
 runs the Pallas kernel, compiled on the TPU and under ``interpret=True``
-on the CPU, so every higher layer works identically on both.
+on the CPU, so every higher layer works identically on both.  For most
+families the jnp path is the oracle of :mod:`repro.kernels.ref`; the seg
+and split families serve a segmented chunk scan and one scatter update
+per piece instead, and keep their slot-by-slot scatter-add as the oracle
+only.
 """
 from __future__ import annotations
 
@@ -25,9 +29,9 @@ from repro.core.sparse_matrix import EllMatrix, SegMatrix, SplitMatrix, \
 
 __all__ = ["SEG_CHUNK", "ell_spmv_ref", "ell_spmv", "hyb_spmv", "hyb_from_csr",
            "bell_spmv", "bell_spmm", "bell_from_bcsr", "seg_spmv",
-           "seg_spmv_ref", "seg_from_csr", "split_from_csr", "split_spmv",
-           "split_spmv_ref", "split_flat_spmv", "tile_from_csr", "tile_spmv",
-           "tile_spmv_ref", "tile_flat_spmv"]
+           "seg_spmv_ref", "seg_from_csr", "piece_starts", "split_from_csr",
+           "split_spmv", "split_spmv_ref", "split_flat_spmv", "tile_from_csr",
+           "tile_spmv", "tile_spmv_ref", "tile_flat_spmv"]
 
 #: Default elements per segmented chunk (lane-aligned).  Single source of
 #: truth shared with the plan cost model's padding arithmetic.
@@ -157,12 +161,15 @@ def bell_spmm(blocks, bcols, X, *, use_kernel: bool = False,
 @functools.partial(jax.jit, static_argnames=("num_rows",))
 def _seg_fixup(psum, piece_chunk, piece_lo, piece_hi, piece_row,
                num_rows: int):
-    """Cross-chunk carry fix-up: scatter per-(chunk, row) pieces into y.
+    """Cross-chunk carry fix-up of the Pallas path: scatter per-(chunk,
+    row) pieces into y.
 
     A piece covering in-chunk offsets [lo, hi] contributes
-    ``psum[chunk, hi] - psum[chunk, lo-1]`` (0 when lo == 0) to its row.
-    Prefix differences stay chunk-local, so fp32 error is bounded by one
-    chunk's scan, not the whole stream's.
+    ``psum[chunk, hi] - psum[chunk, lo-1]`` (0 when lo == 0) to its row:
+    one scatter update per piece, not per stored slot.  Prefix
+    differences stay chunk-local, so fp32 error is bounded by one
+    chunk's scan, not the whole stream's — but it scales with the
+    chunk's running sum, not with the row's own terms.
     """
     hi = psum[piece_chunk, piece_hi]
     lo = jnp.where(piece_lo > 0,
@@ -172,37 +179,84 @@ def _seg_fixup(psum, piece_chunk, piece_lo, piece_hi, piece_row,
     return y.at[piece_row].add(hi - lo)
 
 
+def piece_starts(num_chunks: int, chunk: int, piece_chunk,
+                 piece_lo) -> np.ndarray:
+    """(C, L) bool slab, True at each piece's first slot: where the
+    served scan (:func:`_segmented_scan`) restarts its running sum."""
+    starts = np.zeros((num_chunks, chunk), dtype=bool)
+    starts[np.asarray(piece_chunk), np.asarray(piece_lo)] = True
+    return starts
+
+
+def _segmented_scan(v, starts):
+    """Inclusive prefix sums along the lanes that restart at every
+    ``starts`` lane, so each slot holds the sum of its own piece up to
+    itself and nothing before the piece.  ``log2(L)`` steps; step ``s``
+    adds the value ``s`` lanes to the left unless a start lies between
+    (Hillis-Steele, segmented)."""
+    s = 1
+    while s < v.shape[-1]:
+        pad = ((0, 0), (s, 0))
+        v = jnp.where(starts, v, v + jnp.pad(v[:, :-s], pad))
+        starts = starts | jnp.pad(starts[:, :-s], pad, constant_values=True)
+        s *= 2
+    return v
+
+
+@functools.partial(jax.jit, static_argnames=("num_rows",))
+def _seg_scan_spmv(vals, cols, starts, piece_chunk, piece_lo, piece_hi,
+                   piece_row, x, num_rows: int):
+    """The served seg pass: segmented scans of ``vals * x[cols]``, then
+    one scatter update per piece, its scan at its last slot — no
+    difference is taken, so a row's fp32 error is bounded by its own
+    terms, not by the chunk's running sum before it.  Padded pieces
+    (lo > hi) add an exact zero."""
+    scan = _segmented_scan(vals * jnp.take(x, cols, axis=0), starts)
+    part = jnp.where(piece_lo <= piece_hi, scan[piece_chunk, piece_hi],
+                     jnp.zeros((), dtype=scan.dtype))
+    return jnp.zeros((num_rows,), dtype=scan.dtype).at[piece_row].add(part)
+
+
 def seg_spmv(seg: "SegMatrix | tuple", x, *, num_rows: int | None = None,
              use_kernel: bool = False, interpret: bool = False,
              tile_c: int = 8):
     """Nonzero-balanced segmented SpMV: y = A @ x over the chunked stream.
 
     ``seg`` is a host :class:`SegMatrix` (or the equivalent array tuple
-    ``(vals, cols, rows, piece_chunk, piece_lo, piece_hi, piece_row)``).
-    Same contract as the other ops: the jnp scatter-add oracle is the
-    default execution path; ``use_kernel=True`` runs the Pallas per-chunk
-    prefix-sum kernel (``interpret=True`` on CPU) followed by the jit'd
-    cross-chunk carry fix-up.
+    ``(vals, cols, starts, piece_chunk, piece_lo, piece_hi, piece_row)``,
+    ``starts`` from :func:`piece_starts`).  Both paths scan the products
+    within each chunk and sum rows with one scatter update per (chunk,
+    row) piece, not per stored slot.  The default scans in jnp, restarting
+    at each piece (:func:`_seg_scan_spmv`); ``use_kernel=True`` runs the
+    Pallas per-chunk prefix-sum kernel (``interpret=True`` on CPU) and
+    the prefix-difference fix-up (:func:`_seg_fixup`).  ``x`` may be (N,)
+    or a multi-RHS block (N, B), one column at a time.
+    :func:`seg_spmv_ref`, which scatter-adds every slot, is the oracle.
     """
     if isinstance(seg, SegMatrix):
-        arrays = (seg.vals, seg.cols, seg.rows, seg.piece_chunk,
-                  seg.piece_lo, seg.piece_hi, seg.piece_row)
+        arrays = (seg.vals, seg.cols,
+                  piece_starts(*seg.vals.shape, seg.piece_chunk,
+                               seg.piece_lo),
+                  seg.piece_chunk, seg.piece_lo, seg.piece_hi, seg.piece_row)
         if num_rows is None:
             num_rows = seg.shape[0]
     else:
         arrays = seg
         if num_rows is None:
             raise ValueError("num_rows is required with raw seg arrays")
-    vals, cols, rows, p_chunk, p_lo, p_hi, p_row = map(jnp.asarray, arrays)
-    if use_kernel:
-        def one(xb):
-            psum = _seg_psum_pallas(vals, cols, xb, tile_c=tile_c,
-                                    interpret=interpret)
-            return _seg_fixup(psum, p_chunk, p_lo, p_hi, p_row, num_rows)
-        if jnp.asarray(x).ndim == 2:    # multi-RHS: vmap the kernel path
-            return jax.vmap(one, in_axes=1, out_axes=1)(jnp.asarray(x))
-        return one(x)
-    return seg_spmv_ref(vals, cols, rows, x, num_rows=num_rows)
+    vals, cols, starts, p_chunk, p_lo, p_hi, p_row = map(jnp.asarray, arrays)
+
+    def one(xb):
+        if not use_kernel:
+            return _seg_scan_spmv(vals, cols, starts, p_chunk, p_lo, p_hi,
+                                  p_row, xb, num_rows=num_rows)
+        psum = _seg_psum_pallas(vals, cols, xb, tile_c=tile_c,
+                                interpret=interpret)
+        return _seg_fixup(psum, p_chunk, p_lo, p_hi, p_row, num_rows)
+    x = jnp.asarray(x)
+    if x.ndim == 2:                     # multi-RHS: one column at a time
+        return jax.vmap(one, in_axes=1, out_axes=1)(x)
+    return one(x)
 
 
 def seg_from_csr(csr, *, chunk: int = SEG_CHUNK, lane: int = 128,
@@ -321,7 +375,7 @@ def split_spmv(spl: "SplitMatrix | tuple", x, *, num_rows: int | None = None,
     return split_spmv_ref(vals, cols, rows, x, num_rows=num_rows)
 
 
-def split_flat_spmv(vals, cols, rows, pieces, x, *, num_rows: int,
+def split_flat_spmv(vals, cols, starts, pieces, x, *, num_rows: int,
                     num_splits: int, use_kernel: bool = False,
                     interpret: bool = False, tile_c: int = 8):
     """Split SpMV over the *flattened* (NS*Cs, L) device slab.
@@ -329,22 +383,27 @@ def split_flat_spmv(vals, cols, rows, pieces, x, *, num_rows: int,
     The distributed executor stacks every shard's slab into one uniform
     (C, L) operand, so the split structure travels in the (P, 5) int32
     piece table [flat_chunk, lo, hi, row, split] instead of a third slab
-    axis (padded piece rows hold [0, 1, 0, 0, 0] — an exact zero).  The
-    oracle path is the seg scatter-add on the flat slab (the split axis
-    only partitions the stream); the kernel path is the two-stage
-    pipeline sharing :func:`~repro.kernels.spmv_seg.seg_psum` for stage 1.
+    axis (padded piece rows hold [0, 1, 0, 0, 0] — an exact zero), with
+    ``starts`` its :func:`piece_starts` slab.  The default path is the
+    seg pass on the flat slab (:func:`seg_spmv`, the table's first four
+    columns): the split axis only partitions the stream.  The kernel
+    path is the two-stage pipeline sharing
+    :func:`~repro.kernels.spmv_seg.seg_psum` for stage 1.
     """
-    if use_kernel:
-        def one(xb):
-            psum = _seg_psum_pallas(vals, cols, xb, tile_c=tile_c,
-                                    interpret=interpret)
-            part = _split_flat_fixup(psum, pieces, num_splits=num_splits,
-                                     num_rows=num_rows)
-            return _split_combine_pallas(part, interpret=interpret)
-        if jnp.asarray(x).ndim == 2:
-            return jax.vmap(one, in_axes=1, out_axes=1)(jnp.asarray(x))
-        return one(x)
-    return seg_spmv_ref(vals, cols, rows, x, num_rows=num_rows)
+    if not use_kernel:
+        return seg_spmv((vals, cols, starts)
+                        + tuple(pieces[:, k] for k in range(4)),
+                        x, num_rows=num_rows)
+
+    def one(xb):
+        psum = _seg_psum_pallas(vals, cols, xb, tile_c=tile_c,
+                                interpret=interpret)
+        part = _split_flat_fixup(psum, pieces, num_splits=num_splits,
+                                 num_rows=num_rows)
+        return _split_combine_pallas(part, interpret=interpret)
+    if jnp.asarray(x).ndim == 2:
+        return jax.vmap(one, in_axes=1, out_axes=1)(jnp.asarray(x))
+    return one(x)
 
 
 def split_from_csr(csr, num_splits: int, *, chunk: int = SEG_CHUNK,

@@ -1,8 +1,10 @@
 """Pure-jnp oracles for every kernel in this package.
 
 These are the correctness references the per-kernel tests sweep against
-(shapes x dtypes, assert_allclose).  They are also the fallback execution
-path on backends without Pallas support.
+(shapes x dtypes, assert_allclose).  They are also the ops' default
+execution path without Pallas, except for seg and split, which serve a
+segmented chunk scan (``ops.seg_spmv``) and keep ``seg_spmv_ref`` as
+their oracle.
 """
 from __future__ import annotations
 
@@ -46,7 +48,8 @@ def seg_spmv_ref(vals: jnp.ndarray, cols: jnp.ndarray, rows: jnp.ndarray,
 
     vals/cols/rows: (C, L) slab (padded slots: val 0 / col 0 / row 0).
     Scatter-adds every product into its destination row — the order-free
-    definition the chunked prefix-sum kernel must reproduce.  ``x`` may be
+    definition the served scan and the chunked prefix-sum kernel must
+    reproduce; no served path runs it.  ``x`` may be
     (N,) or a multi-RHS block (N, B); the (C, L) row ids then scatter
     whole (B,) slices, so batched columns match per-vector runs exactly.
     """

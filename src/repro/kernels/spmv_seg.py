@@ -12,12 +12,15 @@ prefix sums:
     psum[c, l] = sum_{k <= l} vals[c, k] * x[cols[c, k]]
 
 Row results are then assembled by the cross-chunk carry fix-up (a cheap
-jit'd gather/scatter in ops.seg_spmv): each (chunk, row) *piece* contributes
-``psum[c, hi] - psum[c, lo-1]`` to its row, so a row spanning many chunks
-sums one carry per chunk and a chunk holding many short rows yields them
-all from one scan.  The grid is therefore load-balance-aware rather than
-shape-aware — the first kernel in this repo whose work distribution, not
-its operand shape, defines the grid.
+jit'd gather/scatter, ``ops._seg_fixup``): each (chunk, row) *piece*
+contributes ``psum[c, hi] - psum[c, lo-1]`` to its row, so a row spanning
+many chunks sums one carry per chunk and a chunk holding many short rows
+yields them all from one scan.  The grid is therefore load-balance-aware
+rather than shape-aware — the first kernel in this repo whose work
+distribution, not its operand shape, defines the grid.  The served jnp
+path (``ops.seg_spmv`` without ``use_kernel``) scans each piece on its own
+instead, restarting at piece starts, and scatters a piece's last slot:
+no prefix difference, so no rounding of the chunk's running sum.
 
 Mosaic lowers neither a 1-D dynamic gather nor ``cumsum``: the gather
 ``x[cols]`` runs in XLA before the kernel, and the scan is a log-step

@@ -613,6 +613,7 @@ class SparseMatrixEngine:
                 s["device_operand_bytes"] = m.device_fn.operand_bytes
                 s["device_passes"] = m.device_fn.passes
                 s["exchange"] = copy.deepcopy(m.device_fn.exchange)
+                s["kernels"] = copy.deepcopy(m.device_fn.kernel_counts)
             s["ingest_phases_s"] = dict(m.ingest_phases_s)
             with m.counter_lock:
                 s["stages_s"] = dict(m.stages_s)

@@ -251,11 +251,35 @@ class TestTileKernel:
                                    rtol=1e-3, atol=1e-3)
 
 
-class TestSegKernel:
-    """Nonzero-balanced segmented SpMV: kernel vs oracle vs dense."""
+#: The two execution paths of the seg and split ops: the served jnp scan
+#: and the Pallas kernel (interpret mode here).  Both sum rows with the
+#: piece fix-up; ``seg_spmv_ref`` is the oracle they are held to.
+PATHS = {"jnp": {}, "pallas": dict(use_kernel=True, interpret=True)}
 
+
+class TestSegKernel:
+    """Nonzero-balanced segmented SpMV: both paths vs oracle vs dense."""
+
+    def test_oracle_scatter_adds_every_slot(self):
+        """``seg_spmv_ref``, the order-free oracle: every slot scatter-added
+        into its row gives the float64 product, for one vector and for a
+        block whose columns are the per-vector runs."""
+        A = powerlaw(512, 4000, seed=3)
+        seg = ops.seg_from_csr(A)
+        X = np.random.default_rng(3).standard_normal((512, 2)) \
+            .astype(np.float32)
+        args = (jnp.asarray(seg.vals), jnp.asarray(seg.cols),
+                jnp.asarray(seg.rows))
+        Y = np.asarray(ops.seg_spmv_ref(*args, jnp.asarray(X), num_rows=512))
+        np.testing.assert_allclose(Y, csr_to_dense(A) @ X.astype(np.float64),
+                                   rtol=1e-4, atol=1e-5)
+        for b in range(2):
+            np.testing.assert_array_equal(Y[:, b], np.asarray(
+                ops.seg_spmv_ref(*args, jnp.asarray(X[:, b]), num_rows=512)))
+
+    @pytest.mark.parametrize("path", PATHS)
     @pytest.mark.parametrize("M,nnz", [(512, 4000), (2048, 16000)])
-    def test_matches_oracle_and_dense_on_powerlaw(self, M, nnz):
+    def test_matches_oracle_and_dense_on_powerlaw(self, M, nnz, path):
         """Skewed power-law matrix (max-row-nnz >> mean): the load-balance
         case the row-tiled ELL kernel handles worst."""
         A = powerlaw(M, nnz, seed=3)
@@ -264,15 +288,16 @@ class TestSegKernel:
         x = jnp.asarray(np.random.default_rng(0).standard_normal(M),
                         jnp.float32)
         seg = ops.seg_from_csr(A)
-        y_ref = np.asarray(ops.seg_spmv(seg, x))
-        y_pal = np.asarray(ops.seg_spmv(seg, x, use_kernel=True,
-                                        interpret=True))
-        np.testing.assert_allclose(y_pal, y_ref, rtol=1e-5, atol=1e-5)
-        dense = csr_to_dense(A) @ np.asarray(x)
-        np.testing.assert_allclose(y_ref, dense, rtol=1e-4, atol=1e-5)
-        np.testing.assert_allclose(y_pal, dense, rtol=1e-4, atol=1e-5)
+        y_ref = np.asarray(ops.seg_spmv_ref(
+            jnp.asarray(seg.vals), jnp.asarray(seg.cols),
+            jnp.asarray(seg.rows), x, num_rows=M))
+        y = np.asarray(ops.seg_spmv(seg, x, **PATHS[path]))
+        np.testing.assert_allclose(y, y_ref, rtol=1e-5, atol=1e-5)
+        dense = csr_to_dense(A) @ np.asarray(x, np.float64)
+        np.testing.assert_allclose(y, dense, rtol=1e-4, atol=1e-5)
 
-    def test_row_spanning_many_chunks(self):
+    @pytest.mark.parametrize("path", PATHS)
+    def test_row_spanning_many_chunks(self, path):
         """One dense row (nnz >> chunk) must sum one carry per chunk."""
         rng = np.random.default_rng(1)
         M = 512
@@ -282,72 +307,83 @@ class TestSegKernel:
         x = jnp.asarray(rng.standard_normal(M), jnp.float32)
         seg = ops.seg_from_csr(A, chunk=128)
         assert np.diff(A.row_ptr)[0] > 3 * seg.chunk    # spans >= 4 chunks
-        y = np.asarray(ops.seg_spmv(seg, x, use_kernel=True, interpret=True))
+        y = np.asarray(ops.seg_spmv(seg, x, **PATHS[path]))
         np.testing.assert_allclose(y, csr_to_dense(A) @ np.asarray(x),
                                    rtol=1e-4, atol=1e-5)
 
-    def test_chunk_and_tile_sweep(self):
+    @pytest.mark.parametrize("path", PATHS)
+    def test_chunk_and_tile_sweep(self, path):
         A = powerlaw(1024, 8000, seed=5)
         x = jnp.asarray(np.random.default_rng(2).standard_normal(1024),
                         jnp.float32)
+        dense = csr_to_dense(A) @ np.asarray(x, np.float64)
         base = None
         for chunk in (128, 256, 512):
             seg = ops.seg_from_csr(A, chunk=chunk)
-            for tc in (1, 2, 8):
+            for tc in ((1, 2, 8) if path == "pallas" else (8,)):
                 if seg.num_chunks % tc:
                     continue
-                y = np.asarray(ops.seg_spmv(seg, x, use_kernel=True,
-                                            interpret=True, tile_c=tc))
+                y = np.asarray(ops.seg_spmv(seg, x, tile_c=tc,
+                                            **PATHS[path]))
                 if base is None:
                     base = y
                 np.testing.assert_allclose(y, base, rtol=1e-5, atol=1e-5)
+                np.testing.assert_allclose(y, dense, rtol=1e-4, atol=1e-5)
 
-    def test_empty_rows_and_empty_matrix(self):
+    @pytest.mark.parametrize("path", PATHS)
+    def test_empty_rows_and_empty_matrix(self, path):
         A = csr_from_coo([1, 1, 5], [0, 3, 2], [1.0, 2.0, 3.0], (8, 8))
         x = jnp.asarray(np.arange(8, dtype=np.float32))
         seg = ops.seg_from_csr(A)
-        y = np.asarray(ops.seg_spmv(seg, x, use_kernel=True, interpret=True))
+        y = np.asarray(ops.seg_spmv(seg, x, **PATHS[path]))
         np.testing.assert_allclose(y, csr_to_dense(A) @ np.asarray(x),
                                    atol=1e-6)
         E = csr_from_coo(np.zeros(0, int), np.zeros(0, int), np.zeros(0),
                          (16, 16))
         se = ops.seg_from_csr(E)
         ye = np.asarray(ops.seg_spmv(se, jnp.zeros(16, jnp.float32),
-                                     use_kernel=True, interpret=True))
+                                     **PATHS[path]))
         assert ye.shape == (16,) and not ye.any()
 
-    def test_batched_matches_per_vector(self):
-        """Multi-RHS (N, B) through the seg oracle and the vmapped kernel
-        path: every column equals its per-vector run."""
+    @pytest.mark.parametrize("path", PATHS)
+    def test_batched_matches_per_vector(self, path):
+        """Multi-RHS (N, B) runs one column at a time: every column
+        equals its per-vector run bitwise."""
         A = powerlaw(512, 4000, seed=9)
         X = np.random.default_rng(9).standard_normal((512, 3)) \
             .astype(np.float32)
         seg = ops.seg_from_csr(A)
-        Y_ref = np.asarray(ops.seg_spmv(seg, jnp.asarray(X)))
-        Y_pal = np.asarray(ops.seg_spmv(seg, jnp.asarray(X),
-                                        use_kernel=True, interpret=True))
-        assert Y_ref.shape == (512, 3)
+        Y = np.asarray(ops.seg_spmv(seg, jnp.asarray(X), **PATHS[path]))
+        assert Y.shape == (512, 3)
         for b in range(3):
-            np.testing.assert_allclose(
-                Y_ref[:, b],
-                np.asarray(ops.seg_spmv(seg, jnp.asarray(X[:, b]))),
-                rtol=1e-5, atol=1e-5)
-            np.testing.assert_allclose(
-                Y_pal[:, b],
-                np.asarray(ops.seg_spmv(seg, jnp.asarray(X[:, b]),
-                                        use_kernel=True, interpret=True)),
-                rtol=1e-5, atol=1e-5)
-        np.testing.assert_allclose(Y_ref, csr_to_dense(A) @ X,
+            np.testing.assert_array_equal(Y[:, b], np.asarray(
+                ops.seg_spmv(seg, jnp.asarray(X[:, b]), **PATHS[path])))
+        np.testing.assert_allclose(Y, csr_to_dense(A) @ X,
                                    rtol=1e-4, atol=1e-4)
 
-    def test_monster_row_carry_pinned_vs_csr_matvec(self):
+    def test_served_path_holds_each_row_to_its_own_terms(self):
+        """A short row of small terms right after a row of large ones in
+        the same chunk: the served scan restarts at the short row, so its
+        answer is exact, where a difference of running sums
+        (``psum[hi] - psum[lo-1]``, ~1e5 each) would lose it to the
+        large row's rounding."""
+        A = csr_from_coo(np.r_[np.zeros(100, int), 1, 1], np.r_[
+            np.arange(100), 100, 101], np.r_[np.full(100, 1e3), 1e-3, 2e-3],
+            (2, 128))
+        x = np.ones(128, np.float32)
+        seg = ops.seg_from_csr(A)
+        assert seg.num_chunks == 8 and seg.n_pieces == 2   # one chunk used
+        y = np.asarray(ops.seg_spmv(seg, jnp.asarray(x)))
+        np.testing.assert_allclose(y, [1e5, 3e-3], rtol=1e-6)
+
+    @pytest.mark.parametrize("path", PATHS)
+    def test_monster_row_carry_pinned_vs_csr_matvec(self, path):
         """Regression pin for the seg carry fix-up when a *single* row
         spans many chunks: one fully dense row (span 16 under chunk=128)
-        over a thin background must reproduce ``csr_matvec`` through the
-        oracle and the Pallas path, and a float64 scatter over the slab
-        must match ``csr_matvec`` on the same (fp32-stored) values to
-        fp64 round-off — the carry chain either sums every chunk's carry
-        exactly once or drifts visibly."""
+        over a thin background must reproduce ``csr_matvec``, and a
+        float64 scatter over the slab must match ``csr_matvec`` on the
+        same (fp32-stored) values to fp64 round-off — the carry chain
+        either sums every chunk's carry exactly once or drifts visibly."""
         rng = np.random.default_rng(11)
         M = 2048
         r = np.concatenate([np.zeros(M, int), np.arange(1, M)])
@@ -359,13 +395,10 @@ class TestSegKernel:
         assert M // seg.chunk >= 16                # spans >= 16 chunks
         x = rng.standard_normal(M)
         want = csr_matvec(A, x)
-        xj = jnp.asarray(x, jnp.float32)
-        y_ref = np.asarray(ops.seg_spmv(seg, xj))
-        y_pal = np.asarray(ops.seg_spmv(seg, xj, use_kernel=True,
-                                        interpret=True))
+        y = np.asarray(ops.seg_spmv(seg, jnp.asarray(x, jnp.float32),
+                                    **PATHS[path]))
         # fp32 paths: the monster row sums 2048 terms — scale tolerance
-        np.testing.assert_allclose(y_ref, want, rtol=1e-4, atol=1e-3)
-        np.testing.assert_allclose(y_pal, want, rtol=1e-4, atol=1e-3)
+        np.testing.assert_allclose(y, want, rtol=1e-4, atol=1e-3)
         A32 = dataclasses.replace(
             A, values=A.values.astype(np.float32).astype(np.float64))
         y64 = _np_slab_oracle(seg.vals, seg.cols, seg.rows, x, M)
@@ -493,26 +526,75 @@ class TestSplitKernel:
             np.asarray(ops.seg_spmv(ops.seg_from_csr(A), x)),
             rtol=1e-5, atol=1e-5)
 
-    def test_flat_path_matches_structured(self):
-        """``split_flat_spmv`` (the device-path flattened slab + widened
-        piece table) agrees with the structured ``split_spmv``."""
-        A = powerlaw_tail(512, 2 * 2 * 512, n_monster=2, seed=8)
-        x = jnp.asarray(np.random.default_rng(8).standard_normal(512),
-                        jnp.float32)
-        spl = ops.split_from_csr(A, 4)
-        ns, Cs = spl.num_splits, spl.chunks_per_split
+    @staticmethod
+    def _flat(spl):
+        """The device path's operands: the (NS*Cs, L) slab, its piece
+        starts and the (P, 5) piece table [flat_chunk, lo, hi, row,
+        split]."""
+        ns, Cs, L = spl.vals.shape
         pieces = np.stack([spl.piece_split * Cs + spl.piece_chunk,
                            spl.piece_lo, spl.piece_hi, spl.piece_row,
                            spl.piece_split], axis=1).astype(np.int32)
-        L = spl.vals.shape[-1]
+        pieces = pieces.reshape(-1, 5)
+        return (jnp.asarray(spl.vals.reshape(ns * Cs, L)),
+                jnp.asarray(spl.cols.reshape(ns * Cs, L)),
+                jnp.asarray(ops.piece_starts(ns * Cs, L, pieces[:, 0],
+                                             pieces[:, 1])),
+                jnp.asarray(pieces))
+
+    @pytest.mark.parametrize("path", PATHS)
+    @pytest.mark.parametrize("chunk", [128, 256, 512])
+    def test_flat_path_matches_structured(self, chunk, path):
+        """``split_flat_spmv`` (the device-path flattened slab + widened
+        piece table) agrees with the structured ``split_spmv`` and with
+        the float64 product."""
+        A = powerlaw_tail(512, 2 * 2 * 512, n_monster=2, seed=8)
+        x = jnp.asarray(np.random.default_rng(8).standard_normal(512),
+                        jnp.float32)
+        spl = ops.split_from_csr(A, 4, chunk=chunk)
         y_flat = np.asarray(ops.split_flat_spmv(
-            jnp.asarray(spl.vals.reshape(ns * Cs, L)),
-            jnp.asarray(spl.cols.reshape(ns * Cs, L)),
-            jnp.asarray(spl.rows.reshape(ns * Cs, L)),
-            jnp.asarray(pieces), x, num_rows=512, num_splits=ns,
-            use_kernel=True, interpret=True))
+            *self._flat(spl), x, num_rows=512, num_splits=spl.num_splits,
+            **PATHS[path]))
         np.testing.assert_allclose(y_flat, np.asarray(ops.split_spmv(spl, x)),
                                    rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(
+            y_flat, csr_matvec(A, np.asarray(x, np.float64)),
+            rtol=1e-4, atol=1e-3)
+
+    @pytest.mark.parametrize("path", PATHS)
+    @pytest.mark.parametrize("case", ["empty_rows", "empty"])
+    def test_flat_path_on_empty_rows_and_empty_matrix(self, case, path):
+        """Padded piece rows [0, 1, 0, 0, 0] add an exact zero: rows with
+        no entry, and a matrix with none, read 0."""
+        if case == "empty":
+            A = csr_from_coo(np.zeros(0, int), np.zeros(0, int),
+                             np.zeros(0), (16, 16))
+        else:
+            A = csr_from_coo([1, 1, 5, 9], [0, 3, 2, 15],
+                             [1.0, 2.0, 3.0, 4.0], (16, 16))
+        spl = ops.split_from_csr(A, 2)
+        vals, cols, starts, pieces = self._flat(spl)
+        pad = np.zeros((3, 5), np.int32)
+        pad[:, 1] = 1
+        pieces = jnp.concatenate([pieces, jnp.asarray(pad)])
+        x = np.arange(16, dtype=np.float32)
+        y = np.asarray(ops.split_flat_spmv(
+            vals, cols, starts, pieces, jnp.asarray(x), num_rows=16,
+            num_splits=spl.num_splits, **PATHS[path]))
+        np.testing.assert_array_equal(y, csr_to_dense(A) @ x)
+
+    @pytest.mark.parametrize("path", PATHS)
+    def test_flat_path_batched_matches_per_vector(self, path):
+        A = powerlaw_tail(512, 2 * 2 * 512, n_monster=2, seed=5)
+        X = np.random.default_rng(5).standard_normal((512, 3)) \
+            .astype(np.float32)
+        spl = ops.split_from_csr(A, 4)
+        flat = self._flat(spl)
+        kw = dict(num_rows=512, num_splits=spl.num_splits, **PATHS[path])
+        Y = np.asarray(ops.split_flat_spmv(*flat, jnp.asarray(X), **kw))
+        for b in range(3):
+            np.testing.assert_array_equal(Y[:, b], np.asarray(
+                ops.split_flat_spmv(*flat, jnp.asarray(X[:, b]), **kw)))
 
 
 class TestAlignedBlocks:

@@ -605,3 +605,87 @@ def test_one_shard_programs_run_one_kernel_pass(kernel):
         y_ser = execute(prog, v, backend="shard_map", mesh=_mesh1(),
                         pipeline=False)
         np.testing.assert_array_equal(y, y_ser)
+
+
+def _scatter_updates_in(jaxpr) -> list:
+    """The update counts of every scatter-add in ``jaxpr`` and the jaxprs
+    nested in it (shard_map, jit, the ``lax.switch`` branches)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scatter-add":
+            found.append(int(np.prod(eqn.invars[2].aval.shape)))
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _scatter_updates_in(sub)
+    return found
+
+
+def test_one_shard_seg_step_scatters_pieces_not_slots():
+    """The served seg pass sums rows by segmented chunk scans and one
+    scatter update a piece: the step holds no scatter over the slab's C·L
+    slots, the seg branch's scatter has ``n_pieces`` updates, and no
+    operand carries per-slot row ids."""
+    import jax
+    from repro.core.program import _device_operands, _operand_keys, \
+        build_program_step, make_program_spmv_fn
+    A = make_matrix("cop20k_A", scale=0.003)
+    prog = lower(A, SpmvPlan(kernel="seg", num_shards=1))
+    ops = _device_operands(prog)
+    assert "loc_seg_rows" not in _operand_keys(ops)
+    assert not any("seg_rows" in k for k in ops)
+    n_pieces = prog.stages[0].seg.n_pieces
+    C, L = ops["loc_seg_vals"].shape[1:]
+    assert n_pieces < C * L
+    step, host_ops = build_program_step(prog, _mesh1())
+    xs = np.zeros((1, prog.x_layout.padded_length()), np.float32)
+    updates = _scatter_updates_in(jax.make_jaxpr(step)(*host_ops, xs).jaxpr)
+    assert C * L not in updates
+    assert n_pieces in updates
+    fn = make_program_spmv_fn(prog, _mesh1())
+    assert fn.kernel_counts == dict(scatter_updates=[n_pieces])
+
+
+_SUBPROC_SEG4 = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import json
+    import jax, numpy as np
+    from repro.core.program import execute, lower, make_program_spmv_fn
+    from repro.core.sparse_matrix import csr_matvec
+    from repro.core.spmv import SpmvPlan
+    from repro.data.matrices import make_matrix
+    from jax.sharding import AxisType
+
+    mesh = jax.make_mesh((4,), ("model",), axis_types=(AxisType.Auto,))
+    A = make_matrix("cop20k_A", scale=0.003)
+    prog = lower(A, SpmvPlan(num_shards=4, kernel="seg", exchange="halo",
+                             distribution="nonzero"))
+    fn = make_program_spmv_fn(prog, mesh)
+    X = np.random.default_rng(6).standard_normal((A.ncols, 3)) \\
+        .astype(np.float32)
+    Y = np.asarray(execute(prog, X, backend="shard_map", mesh=mesh))
+    cols = [np.asarray(execute(prog, X[:, b], backend="shard_map",
+                               mesh=mesh)) for b in range(3)]
+    print(json.dumps(dict(
+        passes=fn.passes,
+        matches=bool(np.allclose(Y, execute(prog, X), atol=1e-4, rtol=1e-4)
+                     and np.allclose(Y, csr_matvec(A, X), atol=1e-4,
+                                     rtol=1e-4)),
+        columns_bitwise=all(np.array_equal(Y[:, b], cols[b])
+                            for b in range(3)))))
+""")
+
+
+@pytest.mark.slow
+def test_four_shard_two_pass_seg_matches_numpy_4dev_subprocess():
+    """The served seg pass in both kernel passes of a sharded program:
+    answers match the numpy backend and the float64 product, and the
+    columns of a block equal their per-vector runs bitwise."""
+    r = subprocess.run([sys.executable, "-c", _SUBPROC_SEG4],
+                       capture_output=True, text=True, timeout=600,
+                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"})
+    assert r.returncode == 0, r.stderr[-2000:]
+    res = json.loads(r.stdout.strip().splitlines()[-1])
+    assert res == dict(passes=2, matches=True, columns_bitwise=True)
