@@ -112,8 +112,7 @@ _W_EXCH_GATHER = 2.0
 _W_EXCH_STREAM = 1.0
 
 #: Kernel formats a shard stage may select, in tie-break preference order
-#: — alias of the single definition in ``spmv.PLAN_KERNELS`` (also aliased
-#: as ``program.PROGRAM_KERNELS`` for the switch branch ids).
+#: — alias of the single definition in ``spmv.PLAN_KERNELS``.
 KERNELS = PLAN_KERNELS
 #: Relative slot-cost weights behind :func:`kernel_shard_costs`.  An ELL
 #: slab cell costs 1 (one regular FMA lane-slot, padding included); a seg

@@ -27,14 +27,14 @@ makes that selectable:
 * :func:`execute` — one entry point, three backends:
 
   - ``"numpy"``: the exact host oracle (float64, bitwise-stable batched
-    multi-RHS) — the serving path of ``SparseMatrixEngine`` and the
-    correctness reference;
-  - ``"shard_map"``: the device executor.  One ``shard_map`` program runs
-    every shard; per-shard kernel dispatch is a ``lax.switch`` over the
-    stage's kernel id, so heterogeneous programs lower to a single SPMD
-    computation.  This collapses the old ``make_spmv_fn`` /
-    ``make_seg_spmv_fn`` / ``make_halo_spmv_fn`` triplet (kept as thin
-    deprecated shims in ``core/spmv.py``);
+    multi-RHS) — the correctness reference the device answers are
+    checked against;
+  - ``"shard_map"``: the device executor, which ``SparseMatrixEngine``
+    serves through on a mesh.  One ``shard_map`` program runs every
+    shard and carries only the kernel families its stages run: a
+    one-family program calls that family's pass, and a heterogeneous one
+    dispatches per shard with a ``lax.switch`` over its families, so it
+    still lowers to a single SPMD computation;
   - ``"emu"``: the Emu timeline probe (:func:`probe_program`) — the
     migratory-thread cost of the same (matrix, partition, layout) walk,
     which is what the autotuner's simulator re-ranking runs.
@@ -46,6 +46,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
+from typing import Callable
 
 import numpy as np
 
@@ -65,12 +66,7 @@ from repro.kernels.tiling import round_up
 
 __all__ = ["ShardStage", "SpmvProgram", "lower", "relower", "execute",
            "make_program_spmv_fn", "build_program_step", "kernel_interpret",
-           "probe_program", "scatter_x", "gather_b", "PROGRAM_KERNELS"]
-
-#: Kernels a shard stage may select — alias of the single definition in
-#: ``spmv.PLAN_KERNELS`` (tie-break preference order; the ``lax.switch``
-#: branch ids in the device executor follow this order).
-PROGRAM_KERNELS = PLAN_KERNELS
+           "probe_program", "scatter_x", "gather_b"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -97,53 +93,233 @@ class ShardStage:
     split: SplitMatrix | None = None   # kernel == "split"
     tile: TileMatrix | None = None     # kernel == "tile"
 
-
-def _shard_max_row_nnz(A: CSRMatrix, part: Partition, p: int) -> int:
-    r0, r1 = int(part.starts[p]), int(part.starts[p + 1])
-    if r1 <= r0:
-        return 0
-    return int((A.row_ptr[r0 + 1: r1 + 1] - A.row_ptr[r0: r1]).max())
+    @property
+    def payload(self):
+        """The format this stage's kernel family stores."""
+        return getattr(self, _FAMILIES[self.kernel].field)
 
 
-def _resolved_split_count(A: CSRMatrix, part: Partition, p: int,
-                          requested: int) -> int:
-    """The split count shard p actually lowers with: the plan's request
-    (or the :func:`~repro.core.plan.split_meta` policy when the request
-    is 0/absent), clamped to the shard's chunk count exactly as
+# --------------------------------------------------------------------------
+# kernel families: each one's device format, built, stacked, run and
+# counted in one place (the ``_FAMILIES`` table below)
+# --------------------------------------------------------------------------
+
+def _resolved_split_count(csr: CSRMatrix, requested: int) -> int:
+    """The split count a shard's CSR lowers with: the request (or the
+    :func:`~repro.core.plan.split_meta` policy when the request is
+    0/absent), clamped to the shard's chunk count exactly as
     :func:`~repro.kernels.ops.split_from_csr` clamps it — so
     :func:`relower` can compare effective counts, not raw requests."""
-    r0, r1 = int(part.starts[p]), int(part.starts[p + 1])
-    nnz_p = int(A.row_ptr[r1] - A.row_ptr[r0])
-    L = ((kops.SEG_CHUNK + ELL_LANE - 1) // ELL_LANE) * ELL_LANE
-    C = max(-(-nnz_p // L), 1)
+    L = round_up(kops.SEG_CHUNK, ELL_LANE)
+    C = max(-(-csr.nnz // L), 1)
     ns = requested if requested > 0 else \
-        split_meta(nnz_p, _shard_max_row_nnz(A, part, p))
+        split_meta(csr.nnz, int(np.diff(csr.row_ptr).max(initial=0)))
     return max(1, min(int(ns), C))
 
 
-def _build_stage(A: CSRMatrix, part: Partition, p: int,
-                 kernel: str, split_count: int = 0) -> ShardStage:
-    r0, r1 = int(part.starts[p]), int(part.starts[p + 1])
-    sub = part.shard_csr(A, p)
-    ell = seg = split = tile = None
-    if kernel == "ell":
-        ell = csr_to_ell(sub)
-        if ell.overflow_vals.size:
-            raise AssertionError("uncapped ELL conversion cannot overflow")
-    elif kernel == "hyb":
-        ell = kops.hyb_from_csr(sub)
-    elif kernel == "seg":
-        seg = kops.seg_from_csr(sub)
-    elif kernel == "split":
-        ns = _resolved_split_count(A, part, p, split_count)
-        split = kops.split_from_csr(sub, ns)
-    elif kernel == "tile":
-        tile = kops.tile_from_csr(sub)
-    else:
+def _ell_payload(csr: CSRMatrix, num_splits: int) -> EllMatrix:
+    ell = csr_to_ell(csr)
+    if ell.overflow_vals.size:
+        raise AssertionError("uncapped ELL conversion cannot overflow")
+    return ell
+
+
+def _stack_ell(payloads, R: int, remap) -> dict:
+    """The ELL slab of the ell and hyb stages, (S, R, W) at the widest
+    shard's width, and the hyb COO overflow, (S, O) at the longest tail
+    (at least 1).  Other shards, and shards without a tail, hold zeros:
+    value 0 at position 0 adds an exact zero."""
+    ells = [e for e in payloads if e is not None]
+    S, W = len(payloads), max(e.width for e in ells)
+    O = max(max(e.overflow_vals.size for e in ells), 1)
+    ell_data = np.zeros((S, R, W), dtype=np.float32)
+    ell_cols = np.zeros((S, R, W), dtype=np.int32)
+    ovf_rows = np.zeros((S, O), dtype=np.int32)
+    ovf_cols = np.zeros((S, O), dtype=np.int32)
+    ovf_vals = np.zeros((S, O), dtype=np.float32)
+    for p, e in enumerate(payloads):
+        if e is None:
+            continue
+        r, w = e.data.shape
+        ell_data[p, :r, :w] = e.data
+        ell_cols[p, :r, :w] = remap(e.cols, e.data, p)
+        n = e.overflow_vals.size
+        ovf_rows[p, :n] = e.overflow_rows
+        ovf_cols[p, :n] = remap(e.overflow_cols, e.overflow_vals, p)
+        ovf_vals[p, :n] = e.overflow_vals
+    return dict(ell_data=ell_data, ell_cols=ell_cols, ovf_rows=ovf_rows,
+                ovf_cols=ovf_cols, ovf_vals=ovf_vals)
+
+
+def _stack_chunks(payloads, R: int, remap) -> dict:
+    """The chunk slab of the seg and split stages, (S, C, L).
+
+    A split slab (NS, Cs, L) flattens into it (a seg slab is one split):
+    the split structure travels in the piece table, widened to 5 columns
+    [flat_chunk, lo, hi, row, split] (padded rows [0, 1, 0, 0, 0] are an
+    exact zero).  ``seg_starts`` marks each piece's first slot, where the
+    jnp pass's scan restarts.  ``NS``, the widest split count, is the
+    split pass's static argument.
+    """
+    slabs = [s for s in payloads if s is not None]
+    L = slabs[0].chunk
+    if any(s.chunk != L for s in slabs):
+        raise AssertionError("seg/split stages must share one chunk size")
+    # round the chunk count up to the sublane so the Pallas scan's tiling
+    # always divides it
+    S = len(payloads)
+    C = round_up(max(s.vals.size // L for s in slabs), ELL_SUBLANE)
+    Pp = max(max(s.n_pieces for s in slabs), 1)
+    seg_vals = np.zeros((S, C, L), dtype=np.float32)
+    seg_cols = np.zeros((S, C, L), dtype=np.int32)
+    seg_starts = np.zeros((S, C, L), dtype=bool)
+    seg_pieces = np.zeros((S, Pp, 5), dtype=np.int32)
+    seg_pieces[:, :, 1] = 1           # (lo=1, hi=0, row=0, split=0) -> zero
+    for p, s in enumerate(payloads):
+        if s is None:
+            continue
+        ns = getattr(s, "num_splits", 1)
+        split = getattr(s, "piece_split", 0)
+        fv = s.vals.reshape(-1, L)
+        nc, n = fv.shape[0], s.n_pieces
+        seg_vals[p, :nc] = fv
+        seg_cols[p, :nc] = remap(s.cols.reshape(-1, L), fv, p)
+        pc = seg_pieces[p, :n]
+        pc[:, 0] = split * (nc // ns) + s.piece_chunk
+        pc[:, 1] = s.piece_lo
+        pc[:, 2] = s.piece_hi
+        pc[:, 3] = s.piece_row
+        pc[:, 4] = split
+        seg_starts[p, :nc] = kops.piece_starts(nc, L, pc[:, 0], s.piece_lo)
+    return dict(seg_vals=seg_vals, seg_cols=seg_cols, seg_starts=seg_starts,
+                seg_pieces=seg_pieces,
+                NS=max(getattr(s, "num_splits", 1) for s in slabs))
+
+
+def _stack_tiles(payloads, R: int, remap) -> dict:
+    """The tile stream of the tile stages, (S, T, bm, bn).
+
+    Each tile's block-column id expands into per-lane x positions
+    (``tile_xcol``) — the augmented exchange buffer has no block grid to
+    index, so the remap runs on the expanded lanes, with *nonzero lane
+    occupancy* as the remap values (dead / stored-zero-only lanes keep
+    position 0 and contribute exact zeros); padding tiles point their
+    block row (``tile_brow``) one past the last block so the scatter
+    drops them.
+    """
+    tiles = [t for t in payloads if t is not None]
+    bm, bn = tiles[0].bm, tiles[0].bn
+    if any((t.bm, t.bn) != (bm, bn) for t in tiles):
+        raise AssertionError("tile stages must share one tile shape")
+    # the tile count rounds up to the tile kernel's tiles per grid step
+    S = len(payloads)
+    Tp = round_up(max(max(t.num_tiles for t in tiles), 1), TILES_PER_STEP)
+    tile_data = np.zeros((S, Tp, bm, bn), dtype=np.float32)
+    tile_xcol = np.zeros((S, Tp, bn), dtype=np.int32)
+    tile_brow = np.full((S, Tp), -(-R // bm), dtype=np.int32)
+    for p, t in enumerate(payloads):
+        if t is None or not t.num_tiles:
+            continue
+        T = t.num_tiles
+        tile_data[p, :T] = t.data
+        gcols = np.minimum(
+            t.tile_cols[:, None].astype(np.int64) * bn
+            + np.arange(bn, dtype=np.int64)[None, :],
+            t.shape[1] - 1)                            # (T, bn) global ids
+        lane_nz = (t.data != 0).any(axis=1).astype(np.float32)
+        tile_xcol[p, :T] = remap(np.where(lane_nz != 0, gcols, 0), lane_nz, p)
+        tile_brow[p, :T] = t.tile_rows
+    return dict(tile_data=tile_data, tile_xcol=tile_xcol, tile_brow=tile_brow)
+
+
+def _ell_pass(a, x, num_rows, num_splits, use_kernel, interpret):
+    if use_kernel:
+        return kops.ell_spmv(a["ell_data"], a["ell_cols"], x,
+                             interpret=interpret)
+    return kops.ell_spmv_ref(a["ell_data"], a["ell_cols"], x)
+
+
+def _hyb_pass(a, x, num_rows, num_splits, **kw):
+    return kops.hyb_spmv(a["ell_data"], a["ell_cols"], a["ovf_rows"],
+                         a["ovf_cols"], a["ovf_vals"], x, **kw)
+
+
+def _seg_pass(a, x, num_rows, num_splits, **kw):
+    pc = a["seg_pieces"]
+    return kops.seg_spmv((a["seg_vals"], a["seg_cols"], a["seg_starts"],
+                          pc[:, 0], pc[:, 1], pc[:, 2], pc[:, 3]), x,
+                         num_rows=num_rows, **kw)
+
+
+def _split_pass(a, x, num_rows, num_splits, **kw):
+    return kops.split_flat_spmv(a["seg_vals"], a["seg_cols"],
+                                a["seg_starts"], a["seg_pieces"], x,
+                                num_rows=num_rows, num_splits=num_splits, **kw)
+
+
+def _tile_pass(a, x, num_rows, num_splits, **kw):
+    return kops.tile_flat_spmv(a["tile_data"], a["tile_xcol"],
+                               a["tile_brow"], x, num_rows=num_rows, **kw)
+
+
+@dataclasses.dataclass(frozen=True)
+class _Family:
+    """One kernel family's device format: the :class:`ShardStage` payload
+    ``field`` it stores; ``build(csr, num_splits)``, that payload from a
+    shard's CSR; ``keys``, one operand set's arrays; ``stack(payloads, R,
+    remap)``, the payloads over the shards (``None`` for other families'
+    shards); ``run(a, x, num_rows, num_splits, use_kernel=, interpret=)``,
+    its device pass over one shard's set ``a`` and one vector; and
+    ``scatter_updates(payload)``, the scatter-add updates that pass runs a
+    vector (the padding that evens the shards adds exact zeros, uncounted).
+    """
+    field: str
+    keys: tuple
+    build: Callable
+    stack: Callable
+    run: Callable
+    scatter_updates: Callable
+
+
+_ELL_KEYS = ("ell_data", "ell_cols")
+_CHUNK_KEYS = ("seg_vals", "seg_cols", "seg_starts", "seg_pieces")
+
+#: One entry per family of ``PLAN_KERNELS``.  ell and hyb share the ELL
+#: slab, seg and split the chunk slab.  Scatter updates: one per piece for
+#: seg and split (the carry fix-up), one per overflow entry for hyb, none
+#: for ell (rows sum along the lanes) and tile (whole tile rows).
+_FAMILIES = {
+    "ell": _Family("ell", _ELL_KEYS, _ell_payload, _stack_ell, _ell_pass,
+                   lambda e: 0),
+    "seg": _Family("seg", _CHUNK_KEYS,
+                   lambda csr, ns: kops.seg_from_csr(csr), _stack_chunks,
+                   _seg_pass, lambda s: s.n_pieces),
+    "hyb": _Family("ell", _ELL_KEYS + ("ovf_rows", "ovf_cols", "ovf_vals"),
+                   lambda csr, ns: kops.hyb_from_csr(csr), _stack_ell,
+                   _hyb_pass, lambda e: int(e.overflow_vals.size)),
+    "split": _Family("split", _CHUNK_KEYS,
+                     lambda csr, ns: kops.split_from_csr(
+                         csr, _resolved_split_count(csr, ns)),
+                     _stack_chunks, _split_pass, lambda s: s.n_pieces),
+    "tile": _Family("tile", ("tile_data", "tile_xcol", "tile_brow"),
+                    lambda csr, ns: kops.tile_from_csr(csr), _stack_tiles,
+                    _tile_pass, lambda t: 0),
+}
+assert tuple(_FAMILIES) == PLAN_KERNELS
+
+
+def _build_stage(sub: CSRMatrix, kernel: str, shard: int, row_offset: int,
+                 split_count: int = 0) -> ShardStage:
+    """Shard ``shard``'s stage: its CSR ``sub``, whose first row is
+    ``row_offset`` of the program's matrix, in ``kernel``'s format (a
+    split stage asks for ``split_count`` splits, 0 = the policy)."""
+    if kernel not in _FAMILIES:
         raise ValueError(f"unknown shard kernel {kernel!r}; expected one of "
-                         f"{PROGRAM_KERNELS}")
-    return ShardStage(shard=p, kernel=kernel, rows=r1 - r0, row_offset=r0,
-                      nnz=sub.nnz, ell=ell, seg=seg, split=split, tile=tile)
+                         f"{PLAN_KERNELS}")
+    fam = _FAMILIES[kernel]
+    return ShardStage(shard=shard, kernel=kernel, rows=sub.nrows,
+                      row_offset=row_offset, nnz=sub.nnz,
+                      **{fam.field: fam.build(sub, split_count)})
 
 
 @dataclasses.dataclass
@@ -315,7 +491,8 @@ def lower(csr: CSRMatrix, plan: SpmvPlan) -> SpmvProgram:
     b_layout = make_layout(plan.layout, A.nrows, plan.num_shards)
     kernels = plan.resolved_shard_kernels()
     split_counts = plan.resolved_split_counts()
-    stages = tuple(_build_stage(A, part, p, kernels[p], split_counts[p])
+    stages = tuple(_build_stage(part.shard_csr(A, p), kernels[p], p,
+                                int(part.starts[p]), split_counts[p])
                    for p in range(plan.num_shards))
     return SpmvProgram(
         plan=plan, matrix=A, partition=part, x_layout=x_layout,
@@ -369,20 +546,21 @@ def relower(program: SpmvProgram, new_plan: SpmvPlan) -> SpmvProgram:
         # split stages also share when the *effective* (clamped/policy)
         # split count is unchanged — a different request that clamps to
         # the same NS must not trigger a rebuild.
-        want = _resolved_split_count(program.matrix, program.partition, p,
-                                     new_sc[p])
+        want = _resolved_split_count(
+            program.partition.shard_csr(program.matrix, p), new_sc[p])
         return program.stages[p].split.num_splits == want
 
     stages = tuple(
         program.stages[p] if unchanged(p)
-        else _build_stage(program.matrix, program.partition, p, new_k[p],
+        else _build_stage(program.partition.shard_csr(program.matrix, p),
+                          new_k[p], p, int(program.partition.starts[p]),
                           new_sc[p])
         for p in range(new_plan.num_shards))
     return dataclasses.replace(program, plan=new_plan, stages=stages)
 
 
 # --------------------------------------------------------------------------
-# numpy executor (exact host oracle; the serving path)
+# numpy executor (exact host oracle; the correctness reference)
 # --------------------------------------------------------------------------
 
 def _apply_perm(v: np.ndarray, perm: np.ndarray) -> np.ndarray:
@@ -472,8 +650,7 @@ def _execute_numpy_block(program: SpmvProgram, x: np.ndarray) -> np.ndarray:
 
 
 # --------------------------------------------------------------------------
-# device executor: one shard_map for every program (the old three-way
-# make_spmv_fn / make_seg_spmv_fn / make_halo_spmv_fn collapse to this)
+# device executor: one shard_map for every program
 # --------------------------------------------------------------------------
 
 def _remote_reads(program: SpmvProgram) -> tuple:
@@ -589,167 +766,6 @@ def _row_masked_csr(sub: CSRMatrix, keep: np.ndarray) -> CSRMatrix:
                      col_index=sub.col_index[m], row_ptr=row_ptr)
 
 
-def _masked_stage(sub: CSRMatrix, keep: np.ndarray,
-                  st: ShardStage) -> ShardStage:
-    """Lower one row-slice (local or remote) of a shard into the same
-    kernel family as its full stage — the executor-level stage split the
-    pipelined schedule runs."""
-    m = _row_masked_csr(sub, keep)
-    ell = seg = split = tile = None
-    if st.kernel == "ell":
-        ell = csr_to_ell(m)
-    elif st.kernel == "hyb":
-        ell = kops.hyb_from_csr(m)
-    elif st.kernel == "seg":
-        seg = kops.seg_from_csr(m)
-    elif st.kernel == "tile":
-        tile = kops.tile_from_csr(m)         # row count preserved: same grid
-    else:                                    # "split"
-        L = ((kops.SEG_CHUNK + ELL_LANE - 1) // ELL_LANE) * ELL_LANE
-        C = max(-(-m.nnz // L), 1)
-        ns = max(1, min(st.split.num_splits, C))
-        split = kops.split_from_csr(m, ns)
-    return ShardStage(shard=st.shard, kernel=st.kernel, rows=st.rows,
-                      row_offset=st.row_offset, nnz=m.nnz, ell=ell, seg=seg,
-                      split=split, tile=tile)
-
-
-def _stack_stages(stages, R: int, remap) -> dict:
-    """Stack a per-shard stage list into one uniform-shape operand set.
-
-    Every format payload exists for every shard (zeros where unused) so
-    the per-shard ``lax.switch`` can trace each branch with uniform
-    shapes.  Split stages flatten their (NS, Cs, L) slab into the shared
-    seg (C, L) operand — the split structure travels in the piece table,
-    widened to 5 columns [flat_chunk, lo, hi, row, split] (padded rows
-    [0, 1, 0, 0, 0] are an exact zero); ``seg_starts`` marks each
-    piece's first slot, where the jnp pass's scan restarts.  Tile stages
-    expand their per-tile block-column id into per-lane x positions
-    (``tile_xcol``) — the augmented exchange buffer has no block grid to
-    index, so the remap runs on the expanded lanes, with *nonzero lane
-    occupancy* as the remap values (dead / stored-zero-only lanes keep
-    position 0 and contribute exact zeros); padding tiles point their
-    block row (``tile_brow``) one past the last block so the scatter
-    drops them.
-    ``remap(cols, vals, p)`` maps global column ids into the buffer this
-    set's kernel pass reads.
-    """
-    S = len(stages)
-    ells = [st.ell for st in stages if st.ell is not None]
-    W = max((e.width for e in ells), default=ELL_LANE)
-    O = max((e.overflow_vals.size for e in ells), default=0)
-    O = max(O, 1)
-    segs = [st.seg for st in stages if st.seg is not None]
-    spls = [st.split for st in stages if st.split is not None]
-    slabs = segs + spls
-    L = slabs[0].chunk if slabs else kops.SEG_CHUNK
-    if slabs and any(s.chunk != L for s in slabs):
-        raise AssertionError("seg/split stages must share one chunk size")
-    # split slabs flatten to ns * Cs chunks; round the shared chunk count
-    # up to the sublane so the Pallas scan's tiling always divides it.
-    C = max(max((s.num_chunks for s in segs), default=ELL_SUBLANE),
-            max((s.num_splits * s.chunks_per_split for s in spls),
-                default=ELL_SUBLANE))
-    C = round_up(C, ELL_SUBLANE)
-    NS = max((s.num_splits for s in spls), default=1)
-    Pp = max(max((s.n_pieces for s in segs), default=0),
-             max((s.n_pieces for s in spls), default=0))
-    Pp = max(Pp, 1)
-
-    ell_data = np.zeros((S, R, W), dtype=np.float32)
-    ell_cols = np.zeros((S, R, W), dtype=np.int32)
-    ovf_rows = np.zeros((S, O), dtype=np.int32)
-    ovf_cols = np.zeros((S, O), dtype=np.int32)
-    ovf_vals = np.zeros((S, O), dtype=np.float32)
-    seg_vals = np.zeros((S, C, L), dtype=np.float32)
-    seg_cols = np.zeros((S, C, L), dtype=np.int32)
-    seg_starts = np.zeros((S, C, L), dtype=bool)
-    seg_pieces = np.zeros((S, Pp, 5), dtype=np.int32)
-    seg_pieces[:, :, 1] = 1           # (lo=1, hi=0, row=0, split=0) -> zero
-    tiles = [st.tile for st in stages if st.tile is not None]
-    t_bm = tiles[0].bm if tiles else ELL_SUBLANE
-    t_bn = tiles[0].bn if tiles else ELL_LANE
-    if any((t.bm, t.bn) != (t_bm, t_bn) for t in tiles):
-        raise AssertionError("tile stages must share one tile shape")
-    # the tile count, likewise, to the tile kernel's tiles per grid step
-    Tp = round_up(max(max((t.num_tiles for t in tiles), default=0), 1),
-                  TILES_PER_STEP)
-    Rb = -(-R // t_bm)
-    tile_data = np.zeros((S, Tp, t_bm, t_bn), dtype=np.float32)
-    tile_xcol = np.zeros((S, Tp, t_bn), dtype=np.int32)
-    tile_brow = np.full((S, Tp), Rb, dtype=np.int32)   # pad: drops in scatter
-
-    for p, st in enumerate(stages):
-        if st.ell is not None:
-            e = st.ell
-            r, w = e.data.shape
-            ell_data[p, :r, :w] = e.data
-            ell_cols[p, :r, :w] = remap(e.cols, e.data, p)
-            n = e.overflow_vals.size
-            if n:
-                ovf_rows[p, :n] = e.overflow_rows
-                ovf_cols[p, :n] = remap(e.overflow_cols, e.overflow_vals, p)
-                ovf_vals[p, :n] = e.overflow_vals
-        if st.seg is not None:
-            s = st.seg
-            seg_vals[p, : s.num_chunks] = s.vals
-            seg_cols[p, : s.num_chunks] = remap(s.cols, s.vals, p)
-            seg_starts[p, : s.num_chunks] = kops.piece_starts(
-                s.num_chunks, L, s.piece_chunk, s.piece_lo)
-            n = s.n_pieces
-            seg_pieces[p, :n, 0] = s.piece_chunk
-            seg_pieces[p, :n, 1] = s.piece_lo
-            seg_pieces[p, :n, 2] = s.piece_hi
-            seg_pieces[p, :n, 3] = s.piece_row
-        if st.split is not None:
-            s = st.split
-            ns, Cs = s.num_splits, s.chunks_per_split
-            fv = s.vals.reshape(ns * Cs, L)
-            seg_vals[p, : ns * Cs] = fv
-            seg_cols[p, : ns * Cs] = remap(s.cols.reshape(ns * Cs, L), fv, p)
-            n = s.n_pieces
-            seg_pieces[p, :n, 0] = s.piece_split * Cs + s.piece_chunk
-            seg_pieces[p, :n, 1] = s.piece_lo
-            seg_pieces[p, :n, 2] = s.piece_hi
-            seg_pieces[p, :n, 3] = s.piece_row
-            seg_pieces[p, :n, 4] = s.piece_split
-            seg_starts[p, : ns * Cs] = kops.piece_starts(
-                ns * Cs, L, seg_pieces[p, :n, 0], s.piece_lo)
-        if st.tile is not None and st.tile.num_tiles:
-            t = st.tile
-            T = t.num_tiles
-            tile_data[p, :T] = t.data
-            gcols = np.minimum(
-                t.tile_cols[:, None].astype(np.int64) * t_bn
-                + np.arange(t_bn, dtype=np.int64)[None, :],
-                t.shape[1] - 1)                        # (T, bn) global ids
-            lane_nz = (t.data != 0).any(axis=1).astype(np.float32)
-            tile_xcol[p, :T] = remap(np.where(lane_nz != 0, gcols, 0),
-                                     lane_nz, p)
-            tile_brow[p, :T] = t.tile_rows
-    return dict(ell_data=ell_data, ell_cols=ell_cols, ovf_rows=ovf_rows,
-                ovf_cols=ovf_cols, ovf_vals=ovf_vals, seg_vals=seg_vals,
-                seg_cols=seg_cols, seg_starts=seg_starts,
-                seg_pieces=seg_pieces,
-                tile_data=tile_data, tile_xcol=tile_xcol, tile_brow=tile_brow,
-                NS=NS)
-
-
-def _scatter_updates(st: ShardStage) -> int:
-    """Scatter-add updates one vector's pass over stage ``st`` runs: one
-    per piece for seg and split (the carry fix-up), one per overflow
-    entry for hyb, none for ell (rows sum along the lanes) and tile
-    (whole tile rows, not entries).  The padding that evens the shards'
-    shapes is not counted: it adds exact zeros."""
-    if st.seg is not None:
-        return st.seg.n_pieces
-    if st.split is not None:
-        return st.split.n_pieces
-    if st.kernel == "hyb":
-        return int(st.ell.overflow_vals.size)
-    return 0
-
-
 def _device_operands(program: SpmvProgram) -> dict:
     """Build the pipelined executor's operand sets (cached on the program).
 
@@ -765,11 +781,18 @@ def _device_operands(program: SpmvProgram) -> dict:
     buffer (``[x_local ++ recv]`` for any program with a halo reader,
     the gathered global x for uniform all-gather).
 
+    A set holds only ``set_keys``, the formats of ``families``: the
+    kernel families the program's stages run, in ``PLAN_KERNELS`` order.
+    Each slab is stacked once over all shards, zeros where a shard runs
+    another family.  ``kid``, each shard's index into ``families``,
+    exists only where there are several.
+
     A program in which no row of any shard reads a remote entry (every
     one-shard program, and block-diagonal ones under their layout) has
-    an empty remote slice: it gets the local set alone, with no
-    ``rem_*``, ``send_idx`` or ``row_remote``.  ``passes`` is the number
-    of kernel passes the step runs per vector (1 or 2).
+    an empty remote slice: it stacks ``program.stages`` as lowered, as
+    its local set alone, with no ``rem_*``, ``send_idx`` or
+    ``row_remote``.  ``passes`` is the number of kernel passes the step
+    runs per vector (1 or 2).
 
     ``exchange`` counts what the second pass adds, from the tables built
     here: ``sent_entries``, the x entries one vector's collective moves,
@@ -782,7 +805,7 @@ def _device_operands(program: SpmvProgram) -> dict:
 
     ``kernels["scatter_updates"]`` counts, per shard, the scatter-add
     updates one vector's step runs, summed over its passes
-    (:func:`_scatter_updates`).
+    (``_Family.scatter_updates``).
     """
     cached = getattr(program, "_device_ops_cache", None)
     if cached is not None:
@@ -791,6 +814,10 @@ def _device_operands(program: SpmvProgram) -> dict:
     stages = program.stages
     lay = program.x_layout
     R = int(max(round_up(max(st.rows, 1), ELL_SUBLANE) for st in stages))
+    families = tuple(k for k in PLAN_KERNELS
+                     if any(st.kernel == k for st in stages))
+    set_keys = tuple(dict.fromkeys(k for f in families
+                                   for k in _FAMILIES[f].keys))
     reads = _remote_reads(program)
     flags = _row_remote_flags(program, reads)
     remote = bool(flags.any())
@@ -812,31 +839,44 @@ def _device_operands(program: SpmvProgram) -> dict:
         out = lay.local_index(cols).astype(np.int32)
         return np.where(vals != 0, out, 0).astype(np.int32)
 
+    def stack_set(set_stages, remap):    # -> (set, the split pass's NS)
+        out = {}
+        for stack in dict.fromkeys(_FAMILIES[f].stack for f in families):
+            out.update(stack([st.payload if _FAMILIES[st.kernel].stack is stack
+                              else None for st in set_stages], R, remap))
+        return {k: out[k] for k in set_keys}, out.get("NS", 1)
+
     row_remote = np.zeros((S, R), dtype=bool)
-    loc_stages, rem_stages = [], []
-    scatter = [0] * S
-    kid = np.zeros(S, dtype=np.int32)
-    for p, st in enumerate(stages):
-        kid[p] = PROGRAM_KERNELS.index(st.kernel)
-        rr = flags[st.row_offset: st.row_offset + st.rows]
-        row_remote[p, : st.rows] = rr
-        sub = program.partition.shard_csr(program.matrix, p)
-        loc_stages.append(_masked_stage(sub, ~rr, st))
-        scatter[p] += _scatter_updates(loc_stages[-1])
-        if remote:
-            rem_stages.append(_masked_stage(sub, rr, st))
-            scatter[p] += _scatter_updates(rem_stages[-1])
-    loc = _stack_stages(loc_stages, R, remap_loc)
-    cached = dict(kid=kid, R=R, passes=1, NS_loc=loc.pop("NS"),
+    loc_stages, rem_stages = list(stages), []
+    if remote:
+        for p, st in enumerate(stages):
+            rr = flags[st.row_offset: st.row_offset + st.rows]
+            row_remote[p, : st.rows] = rr
+            sub = program.partition.shard_csr(program.matrix, p)
+            # each row slice in the stage's own family and split count
+            ns = getattr(st.payload, "num_splits", 0)
+            loc_stages[p] = _build_stage(_row_masked_csr(sub, ~rr),
+                                         st.kernel, p, st.row_offset, ns)
+            rem_stages.append(_build_stage(_row_masked_csr(sub, rr),
+                                           st.kernel, p, st.row_offset, ns))
+    scatter = [0] * S                    # summed over both slices
+    for p, st in enumerate(loc_stages + rem_stages):
+        scatter[p % S] += _FAMILIES[st.kernel].scatter_updates(st.payload)
+    loc, ns_loc = stack_set(loc_stages, remap_loc)
+    cached = dict(families=families, set_keys=set_keys, R=R, passes=1,
+                  NS_loc=ns_loc,
                   exchange=dict(sent_entries=0, needed_entries=0,
                                 remote_rows=[0] * S,
                                 remote_pass_rows=[0] * S, shard_nnz=[0] * S),
                   kernels=dict(scatter_updates=scatter))
+    if len(families) > 1:
+        cached["kid"] = np.array([families.index(st.kernel)
+                                  for st in stages], dtype=np.int32)
     cached.update({"loc_" + k: v for k, v in loc.items()})
     if remote:
-        rem = _stack_stages(rem_stages, R, remap_rem)
+        rem, ns_rem = stack_set(rem_stages, remap_rem)
         per = lay.padded_length() // S
-        cached.update(passes=2, NS_rem=rem.pop("NS"), send_idx=send_idx,
+        cached.update(passes=2, NS_rem=ns_rem, send_idx=send_idx,
                       row_remote=row_remote, exchange=dict(
                           sent_entries=int(send_idx.size) if use_a2a
                           else S * S * per,
@@ -850,17 +890,14 @@ def _device_operands(program: SpmvProgram) -> dict:
     return cached
 
 
-_SET_KEYS = ("ell_data", "ell_cols", "ovf_rows", "ovf_cols", "ovf_vals",
-             "seg_vals", "seg_cols", "seg_starts", "seg_pieces",
-             "tile_data", "tile_xcol", "tile_brow")
-
-
 def _operand_keys(ops: dict) -> tuple:
-    """The step's operand names in argument order: the local set, then,
-    for a two-pass program, the remote set and the exchange tables."""
-    keys = ("kid",) + tuple("loc_" + k for k in _SET_KEYS)
+    """The step's operand names in argument order: ``kid`` where the
+    program runs several families, the local set, then, for a two-pass
+    program, the remote set and the exchange tables."""
+    keys = ("kid",) if "kid" in ops else ()
+    keys += tuple("loc_" + k for k in ops["set_keys"])
     if ops["passes"] == 2:
-        keys += (tuple("rem_" + k for k in _SET_KEYS)
+        keys += (tuple("rem_" + k for k in ops["set_keys"])
                  + ("send_idx", "row_remote"))
     return keys
 
@@ -877,17 +914,6 @@ def kernel_interpret(platform: str) -> bool:
 
 def _mesh_platform(mesh) -> str:
     return mesh.devices.flat[0].platform
-
-
-def _named(scope: str, fn):
-    """``fn`` traced under ``jax.named_scope(scope)``: its operations carry
-    the scope in their metadata, and a device trace can group them."""
-    import jax
-
-    def scoped(*args):
-        with jax.named_scope(scope):
-            return fn(*args)
-    return scoped
 
 
 def build_program_step(program: SpmvProgram, mesh, axis: str = "model", *,
@@ -920,13 +946,9 @@ def build_program_step(program: SpmvProgram, mesh, axis: str = "model", *,
     ops = _device_operands(program)
     R = ops["R"]
     keys = _operand_keys(ops)
-    policies = program.plan.resolved_shard_exchanges()
-    use_a2a = any(e == "halo" for e in policies)
+    use_a2a = any(e == "halo" for e in program.plan.resolved_shard_exchanges())
     kind = program.x_layout.kind
-    if use_kernel:
-        ell_op = partial(kops.ell_spmv, interpret=interpret)
-    else:
-        ell_op = kops.ell_spmv_ref
+    families, set_keys = ops["families"], ops["set_keys"]
 
     def _to_global(x_all):
         """(S, per[, B]) gathered shards -> global (padded) order."""
@@ -934,69 +956,44 @@ def build_program_step(program: SpmvProgram, mesh, axis: str = "model", *,
             return x_all.reshape((-1,) + x_all.shape[2:])
         return jnp.swapaxes(x_all, 0, 1).reshape((-1,) + x_all.shape[2:])
 
-    def kernel_pass(*args):
-        """One slice's kernel dispatch against its own x buffer.
-
-        A multi-RHS buffer (n, B) runs one vector at a time in a device
-        loop, so each request reads the slice's operands B times.  A
-        batched gather keeps the B values of each index together on the
-        lanes, which the chip pads to 128.  XLA picks that layout even
-        for a vmapped gather with B leading, and the v5e step's
-        temporaries then reach about 64 times the one-vector step's
-        (7.6 GiB on cop20k_A with one shard and B = 8)."""
-        *slabs, xv = args
-        if xv.ndim == 2:
-            return jax.lax.map(partial(vector_pass, *slabs), xv.T).T
-        return vector_pass(*slabs, xv)
-
-    def vector_pass(kid, ed, ec, orow, ocol, oval, sv, sc, ss, sp,
-                    td, txc, tbr, ns, xv):
-
-        def ell_branch(_):
-            return ell_op(ed[0], ec[0], xv)
-
-        def seg_branch(_):
-            pc = sp[0]
-            return kops.seg_spmv(
-                (sv[0], sc[0], ss[0], pc[:, 0], pc[:, 1], pc[:, 2],
-                 pc[:, 3]), xv, num_rows=R, use_kernel=use_kernel,
-                interpret=interpret)
-
-        def hyb_branch(_):
-            y = ell_op(ed[0], ec[0], xv)
-            return y.at[orow[0]].add(oval[0] * jnp.take(xv, ocol[0]))
-
-        def split_branch(_):
-            return kops.split_flat_spmv(
-                sv[0], sc[0], ss[0], sp[0], xv, num_rows=R, num_splits=ns,
-                use_kernel=use_kernel, interpret=interpret)
-
-        def tile_branch(_):
-            return kops.tile_flat_spmv(
-                td[0], txc[0], tbr[0], xv, num_rows=R,
-                use_kernel=use_kernel, interpret=interpret)
-
-        # Each branch runs under its family's name (PROGRAM_KERNELS order).
-        return jax.lax.switch(kid[0], [
-            _named(k, b) for k, b in zip(PROGRAM_KERNELS, (
-                ell_branch, seg_branch, hyb_branch, split_branch,
-                tile_branch))], None)
-
-    n_set = len(_SET_KEYS)
-
-    def shard_fn(kid, *args):
-        # args: the local set, [the remote set, send_idx, row_remote,] x
+    def shard_fn(*args):
         *args, x_shard = args
-        loc, rem = args[:n_set], args[n_set:2 * n_set]
+        op = {k: a[0] for k, a in zip(keys, args)}    # this shard's operands
+
+        def kernel_pass(prefix, num_splits, xv):
+            """One slice's kernel pass against its own x buffer: its
+            family's pass, or a ``lax.switch`` over the program's families
+            where it runs several, each under the family's name.
+
+            A multi-RHS buffer (n, B) runs one vector at a time in a device
+            loop, so each request reads the slice's operands B times.  A
+            batched gather keeps the B values of each index together on the
+            lanes, which the chip pads to 128.  XLA picks that layout even
+            for a vmapped gather with B leading, and the v5e step's
+            temporaries then reach about 64 times the one-vector step's
+            (7.6 GiB on cop20k_A with one shard and B = 8)."""
+            a = {k: op[prefix + k] for k in set_keys}
+
+            def vector_pass(x):
+                passes = [jax.named_scope(f)(partial(
+                    _FAMILIES[f].run, a, x, R, num_splits,
+                    use_kernel=use_kernel, interpret=interpret))
+                    for f in families]
+                if len(passes) == 1:
+                    return passes[0]()
+                return jax.lax.switch(op["kid"], passes)
+            if xv.ndim == 2:
+                return jax.lax.map(vector_pass, xv.T).T
+            return vector_pass(xv)
+
         x_local = x_shard[0]                               # (per[, B])
         if ops["passes"] == 1:          # no remote reads: y is y_loc
-            y = _named("spmv.local", kernel_pass)(
-                kid, *loc, ops["NS_loc"], x_local)
+            y = jax.named_scope("spmv.local")(kernel_pass)(
+                "loc_", ops["NS_loc"], x_local)
             return y[None]
-        send_idx, row_rem = args[2 * n_set:]
         with jax.named_scope("spmv.exchange"):
             if use_a2a:
-                to_send = jnp.take(x_local, send_idx[0], axis=0)
+                to_send = jnp.take(x_local, op["send_idx"], axis=0)
                 recv = jax.lax.all_to_all(to_send, axis, split_axis=0,
                                           concat_axis=0, tiled=True)
                 xg = jnp.concatenate(
@@ -1014,12 +1011,12 @@ def build_program_step(program: SpmvProgram, mesh, axis: str = "model", *,
             # only the scheduling freedom differs.
             x_loc_in, _ = jax.lax.optimization_barrier((x_local, xg))
 
-        y_loc = _named("spmv.local", kernel_pass)(
-            kid, *loc, ops["NS_loc"], x_loc_in)
-        y_rem = _named("spmv.remote", kernel_pass)(
-            kid, *rem, ops["NS_rem"], xg)
+        y_loc = jax.named_scope("spmv.local")(kernel_pass)(
+            "loc_", ops["NS_loc"], x_loc_in)
+        y_rem = jax.named_scope("spmv.remote")(kernel_pass)(
+            "rem_", ops["NS_rem"], xg)
         with jax.named_scope("spmv.combine"):
-            m = row_rem[0]
+            m = op["row_remote"]
             if y_rem.ndim == 2:                            # batched (R, B)
                 m = m[:, None]
             y = jnp.where(m, y_rem, y_loc)
@@ -1054,10 +1051,11 @@ def make_program_spmv_fn(program: SpmvProgram, mesh, axis: str = "model", *,
     follows ``plan.resolved_shard_exchanges()``: uniform all-gather when
     every shard picks ``allgather``, otherwise one all-to-all whose
     per-reader payload is the exact halo (``halo`` shards) or the full
-    replication (``allgather`` shards).  Each shard dispatches to its
-    stage's kernel (``ell`` / ``seg`` / ``hyb`` / ``split`` / ``tile``)
-    through a ``lax.switch`` — one SPMD program, heterogeneous per-shard
-    execution.
+    replication (``allgather`` shards).  Each shard runs its stage's
+    kernel (``ell`` / ``seg`` / ``hyb`` / ``split`` / ``tile``): directly
+    where every shard runs one family, through a ``lax.switch`` over the
+    program's families otherwise — one SPMD program, heterogeneous
+    per-shard execution.
 
     The schedule is **pipelined** (the ROADMAP item-4 executor): each
     shard's kernel work is pre-split by row into a local slice whose
@@ -1084,7 +1082,7 @@ def make_program_spmv_fn(program: SpmvProgram, mesh, axis: str = "model", *,
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     with span("ingest.stack", phases):
-        _device_operands(program)
+        ops = _device_operands(program)
     step, host_ops = build_program_step(
         program, mesh, axis, use_kernel=use_kernel, pipeline=pipeline)
     sharding = NamedSharding(mesh, P(axis))
@@ -1097,7 +1095,6 @@ def make_program_spmv_fn(program: SpmvProgram, mesh, axis: str = "model", *,
     run.program = program
     run.operands = operands
     run.operand_bytes = int(sum(a.nbytes for a in host_ops))
-    ops = _device_operands(program)
     run.passes = ops["passes"]
     run.exchange = ops["exchange"]
     run.kernel_counts = ops["kernels"]

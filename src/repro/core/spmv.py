@@ -42,10 +42,10 @@ __all__ = ["SpmvPlan", "DistributedSpmv", "build_distributed",
 #: preference order (the regular ELL stream wins ties against formats that
 #: pay scan/scatter overheads; the dense-tile stream comes last — it only
 #: wins when the blocked structure makes it *strictly* cheaper).  The
-#: SINGLE definition: ``plan.KERNELS`` (selector/majority order) and
-#: ``program.PROGRAM_KERNELS`` (the ``lax.switch`` branch ids) are aliases
-#: of this tuple, so the three layers cannot drift.  New families are
-#: appended, never inserted, so lowered branch ids stay stable.
+#: SINGLE definition: ``plan.KERNELS`` (selector/majority order) aliases
+#: it, and ``program._FAMILIES`` (each family's device format) holds one
+#: entry per kernel in this order, so the layers cannot drift.  New
+#: families are appended, never inserted.
 PLAN_KERNELS = ("ell", "seg", "hyb", "split", "tile")
 
 #: Exchange policies a plan accepts (uniform or per-shard).  ``halo``

@@ -483,7 +483,7 @@ def test_remote_reads_decide_the_kernel_passes_4dev_subprocess():
     for name, collective in (("halo", "all_to_all"),
                              ("allgather", "all_gather")):
         assert res[name] == dict(
-            passes=2, rem_keys=12, tables=["row_remote", "send_idx"],
+            passes=2, rem_keys=5, tables=["row_remote", "send_idx"],
             collectives=[collective], scopes=two, matches=True,
             pipelined_equals_serial=True), name
     assert res["block_diagonal"] == dict(
@@ -573,12 +573,36 @@ def test_single_device_executor_matches_oracle(kernel, reordering):
         csr_matvec(A, x), atol=1e-4, rtol=1e-4)
 
 
+_FAMILY_KEYS = {
+    "ell": ("ell_data", "ell_cols"),
+    "hyb": ("ell_data", "ell_cols", "ovf_rows", "ovf_cols", "ovf_vals"),
+    "seg": ("seg_vals", "seg_cols", "seg_starts", "seg_pieces"),
+    "split": ("seg_vals", "seg_cols", "seg_starts", "seg_pieces"),
+    "tile": ("tile_data", "tile_xcol", "tile_brow"),
+}
+
+
+def _eqns_in(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs nested in it
+    (shard_map, jit, the ``lax.switch`` branches)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns_in(sub)
+
+
 @pytest.mark.parametrize("kernel", ["ell", "seg", "hyb", "split", "tile"])
 def test_one_shard_programs_run_one_kernel_pass(kernel):
     """One shard reads no remote x entry, so the step gets the local
     operand set alone and one pass: no remote set, exchange tables,
-    exchange, remote slice or combine, and the same answers."""
-    from repro.core.program import _SET_KEYS, _device_operands, \
+    exchange, remote slice or combine, and the same answers.  It runs
+    one family, so its set holds that family's formats alone, with no
+    ``kid``, and the step calls the family's pass with no ``cond``."""
+    import jax
+    from repro.core.program import _device_operands, _operand_keys, \
         build_program_step, gather_b, make_program_spmv_fn, scatter_x
     A = make_matrix("cop20k_A", scale=0.003)
     prog = lower(A, SpmvPlan(kernel=kernel, num_shards=1))
@@ -587,12 +611,15 @@ def test_one_shard_programs_run_one_kernel_pass(kernel):
     assert fn.passes == ops["passes"] == 1
     assert not any(k.startswith("rem_") for k in ops)
     assert "send_idx" not in ops and "row_remote" not in ops
-    assert fn.operand_bytes == ops["kid"].nbytes + sum(
-        ops["loc_" + k].nbytes for k in _SET_KEYS)
+    keys = tuple("loc_" + k for k in _FAMILY_KEYS[kernel])
+    assert _operand_keys(ops) == keys and "kid" not in ops
+    assert fn.operand_bytes == sum(ops[k].nbytes for k in keys)
     step, host_ops = build_program_step(prog, _mesh1())
     xs = np.zeros((1, prog.x_layout.padded_length()), np.float32)
+    jaxpr = jax.make_jaxpr(step)(*host_ops, xs).jaxpr
+    assert not any(e.primitive.name == "cond" for e in _eqns_in(jaxpr))
     text = step.lower(*host_ops, xs).as_text(debug_info=True)
-    assert "spmv.local" in text
+    assert f"spmv.local/{kernel}" in text
     for absent in ("spmv.exchange", "spmv.remote", "spmv.combine",
                    "all_gather", "all_to_all"):
         assert absent not in text, absent
@@ -609,17 +636,28 @@ def test_one_shard_programs_run_one_kernel_pass(kernel):
 
 def _scatter_updates_in(jaxpr) -> list:
     """The update counts of every scatter-add in ``jaxpr`` and the jaxprs
-    nested in it (shard_map, jit, the ``lax.switch`` branches)."""
-    found = []
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "scatter-add":
-            found.append(int(np.prod(eqn.invars[2].aval.shape)))
-        for v in eqn.params.values():
-            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
-                sub = getattr(sub, "jaxpr", sub)
-                if hasattr(sub, "eqns"):
-                    found += _scatter_updates_in(sub)
-    return found
+    nested in it."""
+    return [int(np.prod(e.invars[2].aval.shape)) for e in _eqns_in(jaxpr)
+            if e.primitive.name == "scatter-add"]
+
+
+def test_one_pass_program_stacks_the_lowered_stages(monkeypatch):
+    """A program with no remote reads stacks ``program.stages`` as
+    ``lower`` built them: building its operands converts no shard
+    again."""
+    import repro.core.program as program
+    calls = []
+    hyb_from_csr = program.kops.hyb_from_csr
+    monkeypatch.setattr(program.kops, "hyb_from_csr",
+                        lambda csr: calls.append(csr) or hyb_from_csr(csr))
+    A = make_matrix("cop20k_A", scale=0.003)
+    prog = lower(A, SpmvPlan(kernel="hyb", num_shards=1))
+    assert len(calls) == 1
+    ops = program._device_operands(prog)
+    assert ops["passes"] == 1 and len(calls) == 1
+    st = prog.stages[0]
+    np.testing.assert_array_equal(
+        ops["loc_ell_data"][0, : st.ell.data.shape[0]], st.ell.data)
 
 
 def test_one_shard_seg_step_scatters_pieces_not_slots():

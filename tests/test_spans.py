@@ -170,31 +170,42 @@ _LOWERED_STEP = textwrap.dedent("""
     from repro.data.matrices import make_matrix
 
     mesh = jax.make_mesh((S,), ("model",), axis_types=(AxisType.Auto,))
+    kernels = tuple(sys.argv[2].split(",")) if len(sys.argv) > 2 else None
     prog = lower(make_matrix("cop20k_A", scale=0.002),
-                 SpmvPlan(kernel="hyb", distribution="row", num_shards=S))
+                 SpmvPlan(kernel="hyb", distribution="row", num_shards=S,
+                          shard_kernels=kernels))
     step, ops = build_program_step(prog, mesh)
     xs = np.zeros((S, prog.x_layout.padded_length() // S), np.float32)
     sys.stdout.write(step.lower(*ops, xs).as_text(debug_info=True))
 """)
 
 
-@pytest.mark.parametrize("shards,present,absent", [
-    # one shard reads no remote entry: the local slice alone
-    (1, ("spmv.local", "spmv.local/cond/branch_1_fun/seg",
-         "spmv.local/cond/branch_2_fun/hyb"),
-     ("spmv.exchange", "spmv.remote", "spmv.combine")),
+@pytest.mark.parametrize("shards,kernels,present,absent", [
+    # one shard reads no remote entry: the local slice alone, and one
+    # family: its pass, with no switch over the others
+    (1, None, ("spmv.local", "spmv.local/hyb"),
+     ("spmv.exchange", "spmv.remote", "spmv.combine", "/cond/",
+      "spmv.local/seg")),
     # four shards of a banded matrix read their neighbours' entries
-    (4, ("spmv.exchange", "spmv.local", "spmv.remote", "spmv.combine",
-         "spmv.remote/cond/branch_2_fun/hyb",
-         "spmv.local/cond/branch_1_fun/seg"), ()),
-], ids=["one_shard", "remote_reads_4dev"])
-def test_the_device_step_names_its_slices_and_families(shards, present,
-                                                       absent):
+    (4, None, ("spmv.exchange", "spmv.local", "spmv.remote",
+               "spmv.combine", "spmv.remote/hyb", "spmv.local/hyb"),
+     ("/cond/",)),
+    # shards of two families switch over those two, in kernel order
+    (4, "hyb,seg,hyb,seg", ("spmv.local/cond/branch_0_fun/seg",
+                            "spmv.local/cond/branch_1_fun/hyb",
+                            "spmv.remote/cond/branch_1_fun/hyb"),
+     ("branch_2_fun",)),
+], ids=["one_shard", "remote_reads_4dev", "two_families_4dev"])
+def test_the_device_step_names_its_slices_and_families(shards, kernels,
+                                                       present, absent):
     """The lowered step's op metadata carries each slice it runs, the
     exchange and the combine where there are remote reads, and the
-    kernel family of each switch branch.  Lowered on ``shards`` virtual
-    devices in a subprocess, so they never leak into this session."""
-    r = subprocess.run([sys.executable, "-c", _LOWERED_STEP, str(shards)],
+    kernel family of each pass: of the one family a program runs, or of
+    each branch of the switch over the families it runs.  Lowered on
+    ``shards`` virtual devices in a subprocess, so they never leak into
+    this session."""
+    r = subprocess.run([sys.executable, "-c", _LOWERED_STEP, str(shards)]
+                       + ([kernels] if kernels else []),
                        capture_output=True, text=True, timeout=300,
                        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"})
     assert r.returncode == 0, r.stderr[-2000:]
