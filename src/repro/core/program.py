@@ -271,7 +271,10 @@ class _Family:
     shards); ``run(a, x, num_rows, num_splits, use_kernel=, interpret=)``,
     its device pass over one shard's set ``a`` and one vector; and
     ``scatter_updates(payload)``, the scatter-add updates that pass runs a
-    vector (the padding that evens the shards adds exact zeros, uncounted).
+    vector (the padding that evens the shards adds exact zeros, uncounted);
+    and ``block``, whether its jnp pass takes a multi-RHS (n, B) buffer
+    whole, with one gather of x a request, where the others run in a
+    device loop a vector at a time.
     """
     field: str
     keys: tuple
@@ -279,6 +282,7 @@ class _Family:
     stack: Callable
     run: Callable
     scatter_updates: Callable
+    block: bool = False
 
 
 _ELL_KEYS = ("ell_data", "ell_cols")
@@ -287,20 +291,25 @@ _CHUNK_KEYS = ("seg_vals", "seg_cols", "seg_starts", "seg_pieces")
 #: One entry per family of ``PLAN_KERNELS``.  ell and hyb share the ELL
 #: slab, seg and split the chunk slab.  Scatter updates: one per piece for
 #: seg and split (the carry fix-up), one per overflow entry for hyb, none
-#: for ell (rows sum along the lanes) and tile (whole tile rows).
+#: for ell (rows sum along the lanes) and tile (whole tile rows).  Only the
+#: chunk families take a block whole: a batched gather lays the B values
+#: of each index on 128 padded lanes, which the chunk pass bounds by
+#: walking its slab in groups (``kops.SEG_GROUP_BYTES``), and which on an
+#: ELL slab of audikw_1's size would need about 62 GB.
 _FAMILIES = {
     "ell": _Family("ell", _ELL_KEYS, _ell_payload, _stack_ell, _ell_pass,
                    lambda e: 0),
     "seg": _Family("seg", _CHUNK_KEYS,
                    lambda csr, ns: kops.seg_from_csr(csr), _stack_chunks,
-                   _seg_pass, lambda s: s.n_pieces),
+                   _seg_pass, lambda s: s.n_pieces, block=True),
     "hyb": _Family("ell", _ELL_KEYS + ("ovf_rows", "ovf_cols", "ovf_vals"),
                    lambda csr, ns: kops.hyb_from_csr(csr), _stack_ell,
                    _hyb_pass, lambda e: int(e.overflow_vals.size)),
     "split": _Family("split", _CHUNK_KEYS,
                      lambda csr, ns: kops.split_from_csr(
                          csr, _resolved_split_count(csr, ns)),
-                     _stack_chunks, _split_pass, lambda s: s.n_pieces),
+                     _stack_chunks, _split_pass, lambda s: s.n_pieces,
+                     block=True),
     "tile": _Family("tile", ("tile_data", "tile_xcol", "tile_brow"),
                     lambda csr, ns: kops.tile_from_csr(csr), _stack_tiles,
                     _tile_pass, lambda t: 0),
@@ -912,6 +921,12 @@ def kernel_interpret(platform: str) -> bool:
     return platform == "cpu"
 
 
+def _block_families(families, use_kernel: bool) -> dict:
+    """Each family -> whether the step hands it a multi-RHS buffer whole
+    (``_Family.block``); the Pallas passes take one vector at a time."""
+    return {f: _FAMILIES[f].block and not use_kernel for f in families}
+
+
 def _mesh_platform(mesh) -> str:
     return mesh.devices.flat[0].platform
 
@@ -949,6 +964,7 @@ def build_program_step(program: SpmvProgram, mesh, axis: str = "model", *,
     use_a2a = any(e == "halo" for e in program.plan.resolved_shard_exchanges())
     kind = program.x_layout.kind
     families, set_keys = ops["families"], ops["set_keys"]
+    block = _block_families(families, use_kernel)
 
     def _to_global(x_all):
         """(S, per[, B]) gathered shards -> global (padded) order."""
@@ -965,26 +981,23 @@ def build_program_step(program: SpmvProgram, mesh, axis: str = "model", *,
             family's pass, or a ``lax.switch`` over the program's families
             where it runs several, each under the family's name.
 
-            A multi-RHS buffer (n, B) runs one vector at a time in a device
-            loop, so each request reads the slice's operands B times.  A
-            batched gather keeps the B values of each index together on the
-            lanes, which the chip pads to 128.  XLA picks that layout even
-            for a vmapped gather with B leading, and the v5e step's
-            temporaries then reach about 64 times the one-vector step's
-            (7.6 GiB on cop20k_A with one shard and B = 8)."""
+            A multi-RHS buffer (n, B) goes whole to the chunk families'
+            jnp pass (``_Family.block``), which gathers x once a request;
+            the other families, and every Pallas pass, run one vector at a
+            time in a device loop, reading the slice's operands B times."""
             a = {k: op[prefix + k] for k in set_keys}
 
-            def vector_pass(x):
-                passes = [jax.named_scope(f)(partial(
-                    _FAMILIES[f].run, a, x, R, num_splits,
+            def family_pass(f):
+                run = jax.named_scope(f)(partial(
+                    _FAMILIES[f].run, a, num_rows=R, num_splits=num_splits,
                     use_kernel=use_kernel, interpret=interpret))
-                    for f in families]
-                if len(passes) == 1:
-                    return passes[0]()
-                return jax.lax.switch(op["kid"], passes)
-            if xv.ndim == 2:
-                return jax.lax.map(vector_pass, xv.T).T
-            return vector_pass(xv)
+                if xv.ndim == 2 and not block[f]:
+                    return lambda: jax.lax.map(run, xv.T).T
+                return lambda: run(xv)
+            passes = [family_pass(f) for f in families]
+            if len(passes) == 1:
+                return passes[0]()
+            return jax.lax.switch(op["kid"], passes)
 
         x_local = x_shard[0]                               # (per[, B])
         if ops["passes"] == 1:          # no remote reads: y is y_loc
@@ -1044,8 +1057,11 @@ def make_program_spmv_fn(program: SpmvProgram, mesh, axis: str = "model", *,
     it serves, ``f.operands`` its placed device operands,
     ``f.operand_bytes`` their bytes, ``f.passes`` the kernel passes it
     runs per vector, ``f.exchange`` the counters of what its second
-    pass adds and ``f.kernel_counts`` the scatter-add updates each shard
-    runs a vector (:func:`_device_operands`).
+    pass adds, ``f.kernel_counts`` the scatter-add updates each shard
+    runs a vector (:func:`_device_operands`), and ``f.operand_reads(B)``
+    the operand-set reads one request of B vectors costs a shard:
+    ``passes`` where every family of the program takes the block whole,
+    else ``passes * B``.
 
     The exchange prologue
     follows ``plan.resolved_shard_exchanges()``: uniform all-gather when
@@ -1096,6 +1112,8 @@ def make_program_spmv_fn(program: SpmvProgram, mesh, axis: str = "model", *,
     run.operands = operands
     run.operand_bytes = int(sum(a.nbytes for a in host_ops))
     run.passes = ops["passes"]
+    whole = all(_block_families(ops["families"], use_kernel).values())
+    run.operand_reads = lambda batch: ops["passes"] * (1 if whole else batch)
     run.exchange = ops["exchange"]
     run.kernel_counts = ops["kernels"]
     return run

@@ -23,6 +23,7 @@ from .spmv_split import split_combine as _split_combine_pallas, \
     split_psum as _split_psum_pallas
 from .spmv_tile import tile_contrib as _tile_contrib_pallas, \
     tile_walk_spmv as _tile_walk_pallas
+from .tiling import round_up
 from repro.core.partition import nnz_chunk_starts
 from repro.core.sparse_matrix import EllMatrix, SegMatrix, SplitMatrix, \
     TileMatrix, csr_to_tile, hyb_cap_width
@@ -36,6 +37,13 @@ __all__ = ["SEG_CHUNK", "ell_spmv_ref", "ell_spmv", "hyb_spmv", "hyb_from_csr",
 #: Default elements per segmented chunk (lane-aligned).  Single source of
 #: truth shared with the plan cost model's padding arithmetic.
 SEG_CHUNK = 512
+
+#: Bytes of gathered x one group of a multi-RHS chunk pass may hold.  The
+#: chip keeps the B values of each gathered index on the lanes, padded to
+#: 128, so the gather of a whole slab would grow with its chunk count
+#: (about 41 GB at B = 8 on 156k chunks); :func:`_seg_scan_block` walks
+#: the slab in groups of whole chunks that fit this budget.
+SEG_GROUP_BYTES = 1 << 30
 
 ell_spmv_ref = jax.jit(ref.ell_spmv_ref)
 bell_spmv_ref = jax.jit(ref.bell_spmv_ref)
@@ -193,11 +201,13 @@ def _segmented_scan(v, starts):
     ``starts`` lane, so each slot holds the sum of its own piece up to
     itself and nothing before the piece.  ``log2(L)`` steps; step ``s``
     adds the value ``s`` lanes to the left unless a start lies between
-    (Hillis-Steele, segmented)."""
+    (Hillis-Steele, segmented).  ``v`` may carry leading axes beyond
+    ``starts``' (C, L): the right-hand sides of a block."""
     s = 1
     while s < v.shape[-1]:
         pad = ((0, 0), (s, 0))
-        v = jnp.where(starts, v, v + jnp.pad(v[:, :-s], pad))
+        v = jnp.where(starts, v, v + jnp.pad(
+            v[..., :-s], ((0, 0),) * (v.ndim - 2) + pad))
         starts = starts | jnp.pad(starts[:, :-s], pad, constant_values=True)
         s *= 2
     return v
@@ -217,6 +227,72 @@ def _seg_scan_spmv(vals, cols, starts, piece_chunk, piece_lo, piece_hi,
     return jnp.zeros((num_rows,), dtype=scan.dtype).at[piece_row].add(part)
 
 
+def _seg_groups(num_chunks: int, chunk: int, batch: int) -> tuple:
+    """(chunks a group, groups) of :func:`_seg_scan_block` on a (C, L)
+    slab and B right-hand sides: as few groups as keep each one's
+    gathered x, B padded to whole 128-lane rows, within
+    :data:`SEG_GROUP_BYTES`, and the chunks spread evenly over them."""
+    cap = max(SEG_GROUP_BYTES // (chunk * round_up(batch, 128) * 4), 1)
+    groups = max(-(-num_chunks // cap), 1)
+    return -(-num_chunks // groups), groups
+
+
+#: Pieces :func:`_seg_scan_block` sums a window.  Wider windows compile
+#: far slower for the chip: at cop20k_A_synth's shapes, 1.4 s at 8,192,
+#: 3.3 s at 16,384 and 9.8 s at 24,576 (compiled for a described v5e).
+_PIECE_WINDOW = 1 << 13
+
+
+@functools.partial(jax.jit, static_argnames=("num_rows", "groups"))
+def _seg_scan_block(vals, cols, starts, piece_chunk, piece_lo, piece_hi,
+                    piece_row, X, num_rows: int, groups: tuple):
+    """:func:`_seg_scan_spmv` for an (N, B) block: one gather of X's
+    B-wide rows a chunk slot, B moved off the lanes so each (8, 128) tile
+    holds 8 vectors, the same scan on (B, C, L), then one gather and one
+    scatter of B values a piece into (R, B).  Each column runs the
+    one-vector pass's operations, in its order.
+
+    ``groups`` = (G, ng) from :func:`_seg_groups`: the slab is walked in
+    ng groups of G whole chunks, the last one shifted back to end at the
+    slab's end, so one request's temporaries stay within the byte budget
+    whatever the chunk count.  Pieces are ordered by chunk with padding
+    last, as :func:`seg_from_csr` and the executor's stacker build them,
+    so each group's pieces are one contiguous range; it is summed into
+    the carried (R, B) rows in windows of a fixed width."""
+    C, L = vals.shape
+    P = piece_row.shape[0]
+    y = jnp.zeros((num_rows, X.shape[1]), dtype=X.dtype)
+    if P == 0:
+        return y
+    G, ng = groups
+    W = min(P, _PIECE_WINDOW)
+    key = jnp.where(piece_lo <= piece_hi, piece_chunk, C)   # padding: C
+    bounds = jnp.searchsorted(key, jnp.minimum(jnp.arange(ng + 1) * G, C),
+                              method="scan_unrolled")
+
+    def group(g, y):
+        first = jnp.minimum(g * G, C - G)
+
+        def take(a):                    # the group's chunks of a slab
+            return jax.lax.dynamic_slice_in_dim(a, first, G, axis=0)
+        xs = jnp.moveaxis(jnp.take(X, take(cols), axis=0), -1, 0)
+        scan = _segmented_scan(take(vals) * xs, take(starts))   # (B, G, L)
+
+        def window(carry):
+            p, y = carry
+            i = p + jnp.arange(W)
+            live = i < bounds[g + 1]
+            i = jnp.minimum(i, P - 1)
+            part = scan[:, jnp.clip(piece_chunk[i] - first, 0, G - 1),
+                        piece_hi[i]]                            # (B, W)
+            part = jnp.where(live, part, jnp.zeros((), dtype=part.dtype))
+            rows = jnp.where(live, piece_row[i], num_rows)
+            return p + W, y.at[rows].add(part.T, mode="drop")
+        return jax.lax.while_loop(lambda c: c[0] < bounds[g + 1], window,
+                                  (bounds[g], y))[1]
+    return jax.lax.fori_loop(0, ng, group, y)
+
+
 def seg_spmv(seg: "SegMatrix | tuple", x, *, num_rows: int | None = None,
              use_kernel: bool = False, interpret: bool = False,
              tile_c: int = 8):
@@ -227,10 +303,11 @@ def seg_spmv(seg: "SegMatrix | tuple", x, *, num_rows: int | None = None,
     ``starts`` from :func:`piece_starts`).  Both paths scan the products
     within each chunk and sum rows with one scatter update per (chunk,
     row) piece, not per stored slot.  The default scans in jnp, restarting
-    at each piece (:func:`_seg_scan_spmv`); ``use_kernel=True`` runs the
-    Pallas per-chunk prefix-sum kernel (``interpret=True`` on CPU) and
-    the prefix-difference fix-up (:func:`_seg_fixup`).  ``x`` may be (N,)
-    or a multi-RHS block (N, B), one column at a time.
+    at each piece (:func:`_seg_scan_spmv`), and takes a multi-RHS block
+    (N, B) whole, one gather of x a request (:func:`_seg_scan_block`);
+    ``use_kernel=True`` runs the Pallas per-chunk prefix-sum kernel
+    (``interpret=True`` on CPU) and the prefix-difference fix-up
+    (:func:`_seg_fixup`), one column of a block at a time.
     :func:`seg_spmv_ref`, which scatter-adds every slot, is the oracle.
     """
     if isinstance(seg, SegMatrix):
@@ -254,7 +331,11 @@ def seg_spmv(seg: "SegMatrix | tuple", x, *, num_rows: int | None = None,
                                 interpret=interpret)
         return _seg_fixup(psum, p_chunk, p_lo, p_hi, p_row, num_rows)
     x = jnp.asarray(x)
-    if x.ndim == 2:                     # multi-RHS: one column at a time
+    if x.ndim == 2 and not use_kernel:
+        return _seg_scan_block(vals, cols, starts, p_chunk, p_lo, p_hi,
+                               p_row, x, num_rows=num_rows,
+                               groups=_seg_groups(*vals.shape, x.shape[1]))
+    if x.ndim == 2:                     # Pallas: one column at a time
         return jax.vmap(one, in_axes=1, out_axes=1)(x)
     return one(x)
 

@@ -207,9 +207,13 @@ class IngestedMatrix:
     device_fn: object | None = None
     # Span counters (``repro.core.spans``): the seconds and calls of each
     # phase of this tenant's ingest, and of each stage of its served
-    # device blocks, the latter under ``counter_lock``.
+    # device blocks, the latter under ``counter_lock``; under it too, the
+    # served device blocks and the operand-set reads they cost a shard
+    # (``device_fn.operand_reads``).
     ingest_phases_s: dict = dataclasses.field(default_factory=dict)
     stages_s: dict = dataclasses.field(default_factory=dict)
+    device_blocks: int = 0
+    operand_reads: int = 0
     counter_lock: threading.Lock = dataclasses.field(
         default_factory=threading.Lock)
 
@@ -219,7 +223,8 @@ def _device_block(m: IngestedMatrix, fn, x: np.ndarray) -> np.ndarray:
     span counted into the tenant's ``stages_s``: the permute and split of
     x (``spmv.scatter_x``), its copy to the device and the step's
     dispatch (``spmv.put``), the step itself (``spmv.wait``), and the copy
-    back with the unpadding and unpermute (``spmv.gather_b``)."""
+    back with the unpadding and unpermute (``spmv.gather_b``); then the
+    block and its operand-set reads are counted."""
     import jax
 
     into, lock = m.stages_s, m.counter_lock
@@ -230,7 +235,12 @@ def _device_block(m: IngestedMatrix, fn, x: np.ndarray) -> np.ndarray:
     with span("spmv.wait", into, lock):
         y = jax.block_until_ready(y)
     with span("spmv.gather_b", into, lock):
-        return gather_b(fn.program, y)
+        y = gather_b(fn.program, y)
+    with lock:
+        m.device_blocks += 1
+        m.operand_reads += fn.operand_reads(
+            1 if np.ndim(x) == 1 else np.shape(x)[1])
+    return y
 
 
 class SparseMatrixEngine:
@@ -617,5 +627,8 @@ class SparseMatrixEngine:
             s["ingest_phases_s"] = dict(m.ingest_phases_s)
             with m.counter_lock:
                 s["stages_s"] = dict(m.stages_s)
+                if m.device_fn is not None:
+                    s["kernels"].update(operand_reads=m.operand_reads,
+                                        requests=m.device_blocks)
             out[n] = s
         return out

@@ -4,6 +4,8 @@ against an independent count made from the CSR alone.
 ``scatter_updates`` is, per shard, the scatter-add updates one vector's
 step runs, summed over its kernel passes: a (row, chunk) piece each for
 seg and split, an overflow entry each for hyb, none for ell and tile.
+``operand_reads`` sums the operand-set reads of the served requests on a
+shard, counted in ``requests``.
 One shard is served in-process on one CPU device; a four-shard program
 with remote reads, whose step runs two passes, on four virtual CPU
 devices in a subprocess."""
@@ -60,6 +62,35 @@ def test_one_shard_counts_its_pieces_or_overflow(kernel):
         assert want > 0                    # the matrix really overflows
 
 
+@pytest.mark.parametrize("kernel", ["seg", "split", "hyb", "ell", "tile"])
+def test_one_shard_counts_operand_reads_per_request(kernel):
+    """``operand_reads``: the operand-set reads of the served requests on
+    a shard, ``requests`` beside it.  A vector costs one read; an (n, B)
+    block one where the family takes it whole (seg, split), B where its
+    pass loops over the vectors (hyb, ell, tile)."""
+    import jax
+    from jax.sharding import AxisType
+    from repro.core.spmv import SpmvPlan
+    from repro.data.matrices import powerlaw
+    from repro.serve.router import SparseMatrixEngine
+    A = powerlaw(1024, 8_000, seed=2)
+    mesh = jax.make_mesh((1,), ("model",), axis_types=(AxisType.Auto,),
+                         devices=jax.devices()[:1])
+    eng = SparseMatrixEngine(mesh=mesh)
+    eng.ingest("t", A, SpmvPlan(kernel=kernel, num_shards=1))
+    fn = eng.device_fn("t")
+    B = 8
+    per_block = 1 if kernel in ("seg", "split") else B
+    assert (fn.operand_reads(1), fn.operand_reads(B)) == (1, per_block)
+    k = eng.stats()["t"]["kernels"]
+    assert (k["operand_reads"], k["requests"]) == (0, 0)
+    rng = np.random.default_rng(4)
+    eng.spmv("t", rng.standard_normal(A.ncols))
+    eng.spmv("t", rng.standard_normal((A.ncols, B)))
+    k = eng.stats()["t"]["kernels"]
+    assert (k["operand_reads"], k["requests"]) == (1 + per_block, 2)
+
+
 SCRIPT = textwrap.dedent("""
     import json, sys
     from pathlib import Path
@@ -97,8 +128,12 @@ SCRIPT = textwrap.dedent("""
         x = np.random.default_rng(7).standard_normal(A.ncols) \\
             .astype(np.float32)
         y = np.asarray(eng.spmv(name, x))
+        reads = [eng.stats()[name]["kernels"]["operand_reads"]]
+        eng.spmv(name, np.stack([x] * 3, axis=1))
+        k = eng.stats()[name]["kernels"]
         out[name] = dict(
             passes=s["device_passes"], kernels=list(prog.shard_kernels()),
+            reads=reads + [k["operand_reads"], k["requests"]],
             counts=s["kernels"]["scatter_updates"], want=want,
             matches=bool(np.allclose(y, execute(prog, x), atol=1e-3,
                                      rtol=1e-4)
@@ -117,10 +152,15 @@ def test_four_shards_count_both_passes_4dev_subprocess():
                             "--xla_force_host_platform_device_count=4"})
     assert r.returncode == 0, r.stderr[-3000:]
     res = json.loads(r.stdout.strip().splitlines()[-1])
-    for name, kernels in (("seg", ["seg"] * 4),
-                          ("mixed", ["split", "hyb", "seg", "ell"])):
+    # operand reads after one vector, then after a block of 3 besides:
+    # two passes read once each a request where every family takes the
+    # block whole, three times each where hyb and ell loop over it
+    for name, kernels, reads in (("seg", ["seg"] * 4, [2, 4, 2]),
+                                 ("mixed", ["split", "hyb", "seg", "ell"],
+                                  [2, 8, 2])):
         got = res[name]
         assert got["passes"] == 2 and got["kernels"] == kernels, got
+        assert got["reads"] == reads, got
         assert got["counts"] == got["want"], got
         assert got["matches"], got
     assert res["mixed"]["counts"][3] == 0          # ell scatters nothing

@@ -51,6 +51,12 @@ def _np_slab_oracle(vals, cols, rows, x, num_rows):
     return y
 
 
+def _shrink_group_budget(monkeypatch, chunk: int, chunks: int) -> None:
+    """Let one group of the multi-RHS chunk pass hold ``chunks`` chunks
+    of ``chunk`` slots (for B <= 128), so a small slab spans several."""
+    monkeypatch.setattr(ops, "SEG_GROUP_BYTES", chunks * chunk * 128 * 4)
+
+
 def rand_problem(M, N, nnz, seed=0, dtype=np.float32):
     rng = np.random.default_rng(seed)
     A = csr_from_coo(rng.integers(0, M, nnz), rng.integers(0, N, nnz),
@@ -346,20 +352,43 @@ class TestSegKernel:
         assert ye.shape == (16,) and not ye.any()
 
     @pytest.mark.parametrize("path", PATHS)
-    def test_batched_matches_per_vector(self, path):
-        """Multi-RHS (N, B) runs one column at a time: every column
-        equals its per-vector run bitwise."""
+    @pytest.mark.parametrize("B", [2, 8])
+    def test_batched_matches_per_vector(self, path, B, monkeypatch):
+        """Multi-RHS (N, B): every column equals its per-vector run
+        bitwise.  The jnp path takes the block whole, in one group of
+        chunks under the default byte budget and in several, the last
+        one partial, under a budget of three chunks."""
         A = powerlaw(512, 4000, seed=9)
-        X = np.random.default_rng(9).standard_normal((512, 3)) \
+        X = np.random.default_rng(9).standard_normal((512, B)) \
             .astype(np.float32)
-        seg = ops.seg_from_csr(A)
-        Y = np.asarray(ops.seg_spmv(seg, jnp.asarray(X), **PATHS[path]))
-        assert Y.shape == (512, 3)
-        for b in range(3):
-            np.testing.assert_array_equal(Y[:, b], np.asarray(
-                ops.seg_spmv(seg, jnp.asarray(X[:, b]), **PATHS[path])))
-        np.testing.assert_allclose(Y, csr_to_dense(A) @ X,
-                                   rtol=1e-4, atol=1e-4)
+        seg = ops.seg_from_csr(A, chunk=128)
+        for groups in (1, 11)[: 2 if path == "jnp" else 1]:
+            if groups > 1:
+                _shrink_group_budget(monkeypatch, seg.chunk, 3)
+            G, ng = ops._seg_groups(seg.num_chunks, seg.chunk, B)
+            assert ng == groups and (ng == 1 or seg.num_chunks % G)
+            Y = np.asarray(ops.seg_spmv(seg, jnp.asarray(X), **PATHS[path]))
+            assert Y.shape == (512, B)
+            for b in range(B):
+                np.testing.assert_array_equal(Y[:, b], np.asarray(
+                    ops.seg_spmv(seg, jnp.asarray(X[:, b]), **PATHS[path])))
+            np.testing.assert_allclose(Y, csr_to_dense(A) @ X,
+                                       rtol=1e-4, atol=1e-4)
+
+    @pytest.mark.parametrize("B, chunks, want", [
+        (8, 5160, (2580, 2)),          # cop20k_A_synth: two groups
+        (8, 156_136, (4004, 39)),      # audikw_1's stream in seg chunks
+        (1, 5160, (2580, 2)),          # B pads to one 128-lane row ...
+        (129, 5160, (1720, 3)),        # ... and to two past 128
+    ])
+    def test_groups_keep_the_gathered_x_within_the_budget(self, B, chunks,
+                                                          want):
+        """The block pass's groups: as few as keep each one's gather of
+        B-wide rows, padded to whole 128-lane rows, within the budget,
+        with the chunks spread evenly."""
+        G, ng = ops._seg_groups(chunks, 512, B)
+        assert (G, ng) == want and G * ng >= chunks > G * (ng - 1)
+        assert G * 512 * (-(-B // 128) * 128) * 4 <= ops.SEG_GROUP_BYTES
 
     def test_served_path_holds_each_row_to_its_own_terms(self):
         """A short row of small terms right after a row of large ones in
@@ -584,17 +613,30 @@ class TestSplitKernel:
         np.testing.assert_array_equal(y, csr_to_dense(A) @ x)
 
     @pytest.mark.parametrize("path", PATHS)
-    def test_flat_path_batched_matches_per_vector(self, path):
+    @pytest.mark.parametrize("B", [2, 8])
+    def test_flat_path_batched_matches_per_vector(self, path, B,
+                                                  monkeypatch):
+        """As the seg pass's: the jnp path takes the block whole, in one
+        group and in several with a partial last one; each column equals
+        its per-vector run bitwise."""
         A = powerlaw_tail(512, 2 * 2 * 512, n_monster=2, seed=5)
-        X = np.random.default_rng(5).standard_normal((512, 3)) \
+        X = np.random.default_rng(5).standard_normal((512, B)) \
             .astype(np.float32)
-        spl = ops.split_from_csr(A, 4)
+        spl = ops.split_from_csr(A, 4, chunk=128)
         flat = self._flat(spl)
         kw = dict(num_rows=512, num_splits=spl.num_splits, **PATHS[path])
-        Y = np.asarray(ops.split_flat_spmv(*flat, jnp.asarray(X), **kw))
-        for b in range(3):
-            np.testing.assert_array_equal(Y[:, b], np.asarray(
-                ops.split_flat_spmv(*flat, jnp.asarray(X[:, b]), **kw)))
+        C = flat[0].shape[0]
+        for groups in (1, 7)[: 2 if path == "jnp" else 1]:
+            if groups > 1:
+                _shrink_group_budget(monkeypatch, 128, 3)
+            G, ng = ops._seg_groups(C, 128, B)
+            assert ng == groups and (ng == 1 or C % G)
+            Y = np.asarray(ops.split_flat_spmv(*flat, jnp.asarray(X), **kw))
+            for b in range(B):
+                np.testing.assert_array_equal(Y[:, b], np.asarray(
+                    ops.split_flat_spmv(*flat, jnp.asarray(X[:, b]), **kw)))
+            np.testing.assert_allclose(Y, csr_matvec(A, X.astype(np.float64)),
+                                       rtol=1e-4, atol=1e-3)
 
 
 class TestAlignedBlocks:

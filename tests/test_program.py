@@ -685,8 +685,70 @@ def test_one_shard_seg_step_scatters_pieces_not_slots():
     assert fn.kernel_counts == dict(scatter_updates=[n_pieces])
 
 
+def _x_gathers(jaxpr, per: int) -> list:
+    """The operand shapes of the gathers of the shard's x buffer, (per,)
+    or (per, B), in ``jaxpr`` and the jaxprs nested in it."""
+    return [e.invars[0].aval.shape for e in _eqns_in(jaxpr)
+            if e.primitive.name == "gather"
+            and e.invars[0].aval.shape[:1] == (per,)]
+
+
+@pytest.mark.parametrize("kernel", ["ell", "seg", "hyb", "split", "tile"])
+def test_block_step_gathers_x_once_in_chunk_families(kernel):
+    """The (n, B) step of a seg or split program hands the block whole to
+    the chunk pass: no loop over B, and one gather of x's B-wide rows.
+    The ell, hyb and tile steps still loop over the vectors, gathering
+    (n,) x in the loop, and every (n,) step gathers (n,) x with no loop."""
+    import jax
+    from repro.core.program import build_program_step
+    A = make_matrix("cop20k_A", scale=0.003)
+    prog = lower(A, SpmvPlan(kernel=kernel, num_shards=1))
+    step, host_ops = build_program_step(prog, _mesh1())
+    per, B = prog.x_layout.padded_length(), 8
+    one = jax.make_jaxpr(step)(*host_ops, np.zeros((1, per), np.float32))
+    assert not any(e.primitive.name in ("scan", "while")
+                   for e in _eqns_in(one.jaxpr))
+    assert set(_x_gathers(one.jaxpr, per)) == {(per,)}
+    block = jax.make_jaxpr(step)(*host_ops,
+                                 np.zeros((1, per, B), np.float32)).jaxpr
+    loops_over_b = [e for e in _eqns_in(block) if e.primitive.name == "scan"
+                    and e.params["length"] == B]
+    if kernel in ("seg", "split"):
+        assert not loops_over_b
+        assert _x_gathers(block, per) == [(per, B)]
+    else:
+        assert len(loops_over_b) == 1
+        assert set(_x_gathers(block, per)) == {(per,)}
+
+
+@pytest.mark.parametrize("kernel", ["seg", "split"])
+@pytest.mark.parametrize("B", [2, 8])
+def test_one_shard_block_step_columns_bitwise(kernel, B, monkeypatch):
+    """A one-shard seg or split program's (n, B) step, in one group of
+    chunks and in several (a budget of two chunks), returns each column
+    of the block bitwise as the (n,) step returns that vector."""
+    from repro.core.program import _device_operands, gather_b, \
+        make_program_spmv_fn, scatter_x
+    from repro.kernels import ops as kops
+    A = make_matrix("cop20k_A", scale=0.003)
+    prog = lower(A, SpmvPlan(kernel=kernel, num_shards=1))
+    C, L = _device_operands(prog)["loc_seg_vals"].shape[1:]
+    X = np.random.default_rng(B).standard_normal((A.ncols, B))
+    for budget in (kops.SEG_GROUP_BYTES, 2 * L * 128 * 4):
+        monkeypatch.setattr(kops, "SEG_GROUP_BYTES", budget)
+        assert kops._seg_groups(C, L, B)[1] == (1 if budget > 2**20
+                                                else -(-C // 2))
+        fn = make_program_spmv_fn(prog, _mesh1())
+        Y = gather_b(prog, fn(scatter_x(prog, X)))
+        for b in range(B):
+            np.testing.assert_array_equal(
+                Y[:, b], gather_b(prog, fn(scatter_x(prog, X[:, b]))))
+        np.testing.assert_allclose(Y, csr_matvec(A, X), atol=1e-4,
+                                   rtol=1e-4)
+
+
 _SUBPROC_SEG4 = textwrap.dedent("""
-    import os
+    import os, sys
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import json
     import jax, numpy as np
@@ -694,36 +756,50 @@ _SUBPROC_SEG4 = textwrap.dedent("""
     from repro.core.sparse_matrix import csr_matvec
     from repro.core.spmv import SpmvPlan
     from repro.data.matrices import make_matrix
+    from repro.kernels import ops
     from jax.sharding import AxisType
 
+    # a group holds two chunks: each pass's slab spans several
+    ops.SEG_GROUP_BYTES = 2 * ops.SEG_CHUNK * 128 * 4
+    shard_kernels = json.loads(sys.argv[1])
     mesh = jax.make_mesh((4,), ("model",), axis_types=(AxisType.Auto,))
     A = make_matrix("cop20k_A", scale=0.003)
     prog = lower(A, SpmvPlan(num_shards=4, kernel="seg", exchange="halo",
-                             distribution="nonzero"))
+                             distribution="nonzero",
+                             shard_kernels=shard_kernels))
     fn = make_program_spmv_fn(prog, mesh)
-    X = np.random.default_rng(6).standard_normal((A.ncols, 3)) \\
-        .astype(np.float32)
-    Y = np.asarray(execute(prog, X, backend="shard_map", mesh=mesh))
-    cols = [np.asarray(execute(prog, X[:, b], backend="shard_map",
-                               mesh=mesh)) for b in range(3)]
-    print(json.dumps(dict(
-        passes=fn.passes,
-        matches=bool(np.allclose(Y, execute(prog, X), atol=1e-4, rtol=1e-4)
-                     and np.allclose(Y, csr_matvec(A, X), atol=1e-4,
-                                     rtol=1e-4)),
-        columns_bitwise=all(np.array_equal(Y[:, b], cols[b])
-                            for b in range(3)))))
+    out = dict(passes=fn.passes, kernels=list(prog.shard_kernels()),
+               matches=True, columns_bitwise=True)
+    for B in (2, 8):
+        X = np.random.default_rng(6 + B).standard_normal((A.ncols, B)) \\
+            .astype(np.float32)
+        Y = np.asarray(execute(prog, X, backend="shard_map", mesh=mesh))
+        cols = [np.asarray(execute(prog, X[:, b], backend="shard_map",
+                                   mesh=mesh)) for b in range(B)]
+        out["matches"] &= bool(
+            np.allclose(Y, execute(prog, X), atol=1e-4, rtol=1e-4)
+            and np.allclose(Y, csr_matvec(A, X), atol=1e-4, rtol=1e-4))
+        out["columns_bitwise"] &= all(np.array_equal(Y[:, b], cols[b])
+                                      for b in range(B))
+    print(json.dumps(out))
 """)
 
 
 @pytest.mark.slow
-def test_four_shard_two_pass_seg_matches_numpy_4dev_subprocess():
-    """The served seg pass in both kernel passes of a sharded program:
-    answers match the numpy backend and the float64 product, and the
-    columns of a block equal their per-vector runs bitwise."""
-    r = subprocess.run([sys.executable, "-c", _SUBPROC_SEG4],
+@pytest.mark.parametrize("shard_kernels", [None, ["seg", "hyb", "seg",
+                                                  "hyb"]])
+def test_four_shard_two_pass_seg_matches_numpy_4dev_subprocess(
+        shard_kernels):
+    """The served seg pass in both kernel passes of a sharded program, on
+    its own and beside hyb shards behind the step's switch: answers match
+    the numpy backend and the float64 product, and the columns of (n, 2)
+    and (n, 8) blocks, walked in several groups of chunks, equal their
+    per-vector runs bitwise."""
+    r = subprocess.run([sys.executable, "-c", _SUBPROC_SEG4,
+                        json.dumps(shard_kernels)],
                        capture_output=True, text=True, timeout=600,
                        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"})
     assert r.returncode == 0, r.stderr[-2000:]
     res = json.loads(r.stdout.strip().splitlines()[-1])
-    assert res == dict(passes=2, matches=True, columns_bitwise=True)
+    assert res == dict(passes=2, kernels=shard_kernels or ["seg"] * 4,
+                       matches=True, columns_bitwise=True)

@@ -11,6 +11,7 @@ The topology is described inside a module fixture, never at import: the
 TPU library may be loaded by one process at a time, and only the worker
 that runs this file loads it.
 """
+import functools
 import os
 
 import numpy as np
@@ -111,6 +112,30 @@ def test_batched_ell_compiles_for_v5e(one_chip):
                       _spec(one_chip, (COP_N, 128), jnp.int32),
                       _spec(one_chip, (COP_N, 8)))
     assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("chunks, n, pieces", [
+    (5160, COP_N, 124_930),                             # cop20k_A_synth
+    (AUDIKW_CHUNKS, AUDIKW_N, AUDIKW_N + AUDIKW_CHUNKS),  # audikw_1's size
+], ids=["cop20k_A", "audikw_1"])
+def test_block_chunk_pass_temporaries_stay_bounded(one_chip, chunks, n,
+                                                   pieces):
+    """The multi-RHS seg pass on an (n, 8) block walks its slab in groups
+    of chunks, so its temporaries stay within the group budget and a
+    margin whatever the chunk count: a gather of B-wide rows over the
+    whole of audikw_1's slab, on lanes padded to 128, would need about
+    41 GB."""
+    B, L = 8, ops.SEG_CHUNK
+    fn = functools.partial(ops._seg_scan_block, num_rows=n,
+                           groups=ops._seg_groups(chunks, L, B))
+    specs = [_spec(one_chip, (chunks, L)),
+             _spec(one_chip, (chunks, L), jnp.int32),
+             _spec(one_chip, (chunks, L), jnp.bool_)] + \
+        [_spec(one_chip, (pieces,), jnp.int32)] * 4 + \
+        [_spec(one_chip, (n, B))]
+    compiled, _ = _compile(fn, *specs)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < min(ops.SEG_GROUP_BYTES * 3 // 2, 2 * 2**30), temp
 
 
 def _step_specs(mesh, operands, x_shape):
